@@ -26,7 +26,6 @@ describing the reference — instead of reference ``file:line``.
 
 __version__ = "0.1.0"
 
-from distributed_tensorflow_tpu import compat as _compat  # noqa: F401  (shims)
 from distributed_tensorflow_tpu.parallel.mesh import (  # noqa: F401
     MeshSpec,
     build_mesh,

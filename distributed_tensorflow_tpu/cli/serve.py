@@ -281,7 +281,9 @@ def _selftest(client, make_payload, n: int) -> int:
 
 def main(argv: list[str] | None = None):
     from distributed_tensorflow_tpu.cli.train import PRESETS
+    from distributed_tensorflow_tpu.runtime import enable_compile_cache
 
+    enable_compile_cache()
     parser = argparse.ArgumentParser(
         description="serve a trained checkpoint (dynamic-batching inference)"
     )

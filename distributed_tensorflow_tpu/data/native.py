@@ -10,14 +10,18 @@ ring — deterministic by construction (per-ticket RNG, in-order staging),
 unlike the reference's racy async readers.
 
 ``NativePipeline`` builds the shared library on first use (g++ is in the
-image); if the toolchain is unavailable the caller falls back to the numpy
-path (``native_available()`` gates it).
+image) and again whenever ``native/data_pipeline.cpp`` or its Makefile
+differ from what the library on disk was built from; if the toolchain is
+unavailable the caller falls back to the numpy path (``native_available()``
+gates it, and cli/train.py logs which pipeline a run used).
 """
 
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import logging
+import os
 import subprocess
 import threading
 from pathlib import Path
@@ -28,6 +32,9 @@ logger = logging.getLogger(__name__)
 
 _NATIVE_DIR = Path(__file__).resolve().parents[2] / "native"
 _LIB_PATH = _NATIVE_DIR / "libdata_pipeline.so"
+# Digest of the sources the library on disk was built from. File times say
+# nothing after a copy or a checkout, so staleness is decided by content.
+_STAMP_PATH = _NATIVE_DIR / "libdata_pipeline.so.sha256"
 # _load() is reached both from the main thread (native_available probes)
 # and from prefetch feeder threads first touching a NativePipeline; the
 # lock keeps the lazy check-then-build-then-publish atomic so two threads
@@ -37,6 +44,32 @@ _lib = None
 _build_failed = False
 
 
+def _source_digest() -> str:
+    h = hashlib.sha256()
+    for name in ("data_pipeline.cpp", "Makefile"):
+        h.update((_NATIVE_DIR / name).read_bytes())
+    return h.hexdigest()
+
+
+def _build(digest: str) -> None:
+    """Compile next to the target and rename into place: another process
+    (an xdist worker, a second trainer) may be loading the library now."""
+    tmp = _LIB_PATH.with_name(f".{_LIB_PATH.name}.{os.getpid()}")
+    try:
+        subprocess.run(
+            ["make", "-C", str(_NATIVE_DIR), "-B", f"OUT={tmp.name}"],
+            check=True,
+            capture_output=True,
+            text=True,
+        )
+        os.replace(tmp, _LIB_PATH)
+    finally:
+        tmp.unlink(missing_ok=True)
+    stamp_tmp = _STAMP_PATH.with_name(f".{_STAMP_PATH.name}.{os.getpid()}")
+    stamp_tmp.write_text(digest + "\n")
+    os.replace(stamp_tmp, _STAMP_PATH)
+
+
 def _load() -> ctypes.CDLL | None:
     global _lib, _build_failed
     with _LOAD_LOCK:
@@ -44,20 +77,17 @@ def _load() -> ctypes.CDLL | None:
             return _lib
         if _build_failed:
             return None
-        src = _NATIVE_DIR / "data_pipeline.cpp"
-        if not _LIB_PATH.exists() or (
-            src.exists() and src.stat().st_mtime > _LIB_PATH.stat().st_mtime
-        ):
+        digest = _source_digest()
+        built_from = (
+            _STAMP_PATH.read_text().strip() if _STAMP_PATH.exists() else ""
+        )
+        if not _LIB_PATH.exists() or built_from != digest:
             try:
-                subprocess.run(
-                    ["make", "-C", str(_NATIVE_DIR), "-B"],
-                    check=True,
-                    capture_output=True,
-                    text=True,
-                )
+                _build(digest)
             except (subprocess.CalledProcessError, FileNotFoundError) as e:
                 logger.warning(
-                    "native pipeline build failed, using numpy path: %s", e
+                    "native pipeline build failed, using numpy path: %s\n%s",
+                    e, getattr(e, "stderr", "") or "",
                 )
                 _build_failed = True
                 return None

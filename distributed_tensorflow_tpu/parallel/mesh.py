@@ -103,16 +103,21 @@ def initialize_runtime(
 
     This is the entire replacement for the reference's per-role server
     bootstrap (SURVEY.md §3a: ``tf.train.Server(cluster, "ps", k);
-    server.join()``): on TPU pods the coordinator/process topology comes from
-    the slice metadata automatically, so zero arguments are needed; explicit
-    arguments are accepted for CPU/GPU multi-process testing.
+    server.join()``): on a TPU pod whose launcher exports the slice's host
+    list, jax works out coordinator and process topology itself and zero
+    arguments are needed; explicit arguments serve every other launcher and
+    CPU/GPU multi-process testing.
 
     Must be called before anything touches the XLA backend (first ``jit`` /
     ``jax.devices()``), exactly like ``jax.distributed.initialize`` itself.
-    With explicit arguments, failures propagate (a misconfigured cluster must
-    not silently fall back to single-process). With no arguments, cluster
-    auto-detection runs and single-host environments with no cluster metadata
-    fall back to single-process mode.
+
+    ``jax.distributed.initialize`` runs only for a declared cluster (any
+    explicit argument) or a detected one (:func:`_cluster_env_present`), and
+    whatever it raises propagates: a misconfigured cluster must not degrade
+    to N independent single-process jobs. A single process with neither
+    makes no call at all — with no arguments jax's cluster detection asks
+    the cloud metadata server, which a machine without one answers only
+    after minutes of retries.
 
     There is no ``server.join()`` analog because there are no passive
     processes — every host executes the compiled SPMD program.
@@ -120,28 +125,25 @@ def initialize_runtime(
     global _runtime_initialized
     if _runtime_initialized:
         return
-    explicit = coordinator_address is not None or num_processes is not None
-    try:
+    explicit = any(
+        a is not None for a in (coordinator_address, num_processes, process_id)
+    )
+    if explicit or _cluster_env_present():
         jax.distributed.initialize(
             coordinator_address=coordinator_address,
             num_processes=num_processes,
             process_id=process_id,
         )
-    except Exception as e:
-        if explicit or _cluster_env_present():
-            # A declared or detected cluster that fails to initialize must
-            # never silently degrade to N independent single-process jobs.
-            raise
-        logger.info("single-process runtime (no cluster metadata): %s", e)
+    else:
+        logger.info("single-process runtime (no cluster declared or detected)")
     _runtime_initialized = True
 
 
 def _cluster_env_present() -> bool:
     """True only for genuinely multi-host environment markers.
 
-    Single-host TPU VMs (and tunneled dev environments) legitimately set
-    ``TPU_WORKER_HOSTNAMES=localhost`` — a one-entry host list is not a
-    cluster, and an init failure there must fall back to single-process.
+    Single-host TPU VMs legitimately set ``TPU_WORKER_HOSTNAMES=localhost``
+    — a one-entry host list is not a cluster, and needs no coordinator.
     """
     import os
 

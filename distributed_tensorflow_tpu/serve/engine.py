@@ -1241,6 +1241,13 @@ class CausalLMEngine(_AotEngine):
                 cfg.num_heads, cfg.hidden_size // cfg.num_heads,
             )
             self._pool_blocks = n_blocks
+            # Orders every dispatch that DONATES the pool (insert/import,
+            # decode-loop thread) against the one that reads it from
+            # another thread (export): a read dispatched while its buffer
+            # was being donated hit deleted-array errors or never
+            # completed, and one dispatched before the publish of a block
+            # it had matched shipped that block's stale bytes.
+            self._pool_lock = threading.Condition()
             self._pool_k = self._kv_zeros(pool_shape, self._cache_sharding)
             self._pool_v = self._kv_zeros(pool_shape, self._cache_sharding)
             self.memory.register(
@@ -2046,13 +2053,19 @@ class CausalLMEngine(_AotEngine):
         for j, (bid, bix) in enumerate(blocks):
             ids[j] = int(bid)
             pos[j] = int(bix)
-        pk, pv, ck, cv = self._insert_compiled(
-            self._pool_k, self._pool_v, self._cache_k, self._cache_v,
+        args = (
             jax.device_put(np.int32(slot), self._rep),
             jax.device_put(ids, self._rep),
             jax.device_put(pos, self._rep),
         )
-        self._pool_k, self._pool_v = pk, pv
+        with self._pool_lock:
+            pk, pv, ck, cv = self._insert_compiled(
+                self._pool_k, self._pool_v, self._cache_k, self._cache_v,
+                *args,
+            )
+            self._pool_k, self._pool_v = pk, pv
+            self.prefix_cache.mark_published(bid for bid, _ in blocks)
+            self._pool_lock.notify_all()
         self._cache_k, self._cache_v = ck, cv
 
     # -- disaggregated-serving page transfer (serve/disagg.py) ----------
@@ -2068,12 +2081,11 @@ class CausalLMEngine(_AotEngine):
         Safe OFF the decode-loop thread, unlike every dispatch method: it
         never swaps the engine's device-state refs, and the caller holds
         a ``KVBlockPool.match`` pin, so the gathered blocks hold the
-        prompt's bytes for the duration. The one cross-thread hazard is
-        the pool ref itself: a concurrent publish DONATES the buffer this
-        thread just read, and a dispatch that loses that race raises
-        jax's deleted-array error — re-read the swapped-in ref and
-        retry (bounded; the pin means any ref's content is equally
-        correct)."""
+        prompt's bytes for the duration. ``_pool_lock`` orders the read
+        against a concurrent publish, which donates the pool buffer and
+        rebinds the ref on the loop thread, and the gather waits for the
+        publish of any block the caller matched while it was only
+        indexed."""
         if self._export_compiled is None:
             raise RuntimeError(
                 "engine built without kv_transfer=True (no pool-export "
@@ -2087,22 +2099,16 @@ class CausalLMEngine(_AotEngine):
         idx = np.zeros((M,), np.int32)
         idx[: len(blocks)] = blocks
         jdx = jax.device_put(idx, self._rep)
-        for attempt in range(5):
-            pk, pv = self._pool_k, self._pool_v
-            try:
-                return self._export_compiled(pk, pv, jdx)
-            # jax surfaces the dead-buffer dispatch as RuntimeError from
-            # the python call path and ValueError (INVALID_ARGUMENT) from
-            # the C++ fast path — match the message, not the type.
-            except (RuntimeError, ValueError) as e:
-                dead = "deleted" in str(e) or "donated" in str(e)
-                if not dead or attempt == 4:
-                    raise
-                # A publish is mid-swap on the loop thread: the donation
-                # lands before the ref swap, so an immediate re-read can
-                # still see the dead ref. Back off past the swap window.
-                time.sleep(0.002 * (attempt + 1))
-        raise AssertionError("unreachable")
+        with self._pool_lock:
+            if not self._pool_lock.wait_for(
+                lambda: not self.prefix_cache.unpublished(blocks),
+                timeout=30.0,
+            ):
+                raise RuntimeError(
+                    f"blocks {list(blocks)} are indexed but their pages "
+                    "were never published to the device pool"
+                )
+            return self._export_compiled(self._pool_k, self._pool_v, jdx)
 
     def import_prefix_pages(
         self, blocks: list[tuple[int, int]], pages_k, pages_v
@@ -2134,13 +2140,17 @@ class CausalLMEngine(_AotEngine):
                     f"chain index {cix} outside the {M}-lane page stage"
                 )
             ids[int(cix)] = int(bid)
-        pk, pv = self._import_compiled(
-            self._pool_k, self._pool_v,
+        args = (
             jax.device_put(pages_k, self._cache_sharding),
             jax.device_put(pages_v, self._cache_sharding),
             jax.device_put(ids, self._rep),
         )
-        self._pool_k, self._pool_v = pk, pv
+        with self._pool_lock:
+            self._pool_k, self._pool_v = self._import_compiled(
+                self._pool_k, self._pool_v, *args
+            )
+            self.prefix_cache.mark_published(bid for bid, _ in blocks)
+            self._pool_lock.notify_all()
 
     def page_meta(self) -> dict:
         """Static page-geometry digest the wire format stamps into its

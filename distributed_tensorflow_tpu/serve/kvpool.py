@@ -28,6 +28,12 @@ Design contracts (tests/test_kvpool.py pins them):
   returned chain; the caller releases after the gather is DISPATCHED
   (device stream order then keeps the pages alive for the gather even if
   they are evicted and rewritten by a later insert).
+- **Indexed is not yet published.** A block is visible to ``match`` from
+  :meth:`insert` on, but its page holds the tokens' K/V only once the
+  engine has dispatched the copy and called :meth:`mark_published`. The
+  decode loop's own dispatches follow that copy in stream order; a reader
+  on ANOTHER thread (the disagg page export) must wait on
+  :meth:`unpublished` first.
 - **LRU leaf eviction.** Allocation under a full pool evicts the
   least-recently-used refcount-0 LEAF — leaf-first keeps the trie
   prefix-closed (an interior page never outlives its children), and
@@ -86,7 +92,8 @@ class KVBlockPool:
 
     # Watched by obs.sanitizer.sanitize_races (tests/test_serve_decode.py
     # soak); every access must be ordered by self._lock.
-    _RACETRACE_ATTRS = ("_free", "_by_block", "_ticks", "_evictions")
+    _RACETRACE_ATTRS = ("_free", "_by_block", "_ticks", "_evictions",
+                        "_unpublished")
 
     def __init__(self, n_blocks: int, block_tokens: int,
                  bytes_per_block: int = 0, dtype: str = "float32"):
@@ -110,6 +117,9 @@ class KVBlockPool:
         self._by_block: dict[int, _TrieNode] = {}
         self._ticks = 0
         self._evictions = 0
+        # Blocks allocated by insert/index whose page copy the engine has
+        # not dispatched yet (see mark_published).
+        self._unpublished: set[int] = set()
         # Flight-recorder sink for prefix_evict events; the continuous
         # batcher swaps in its recorder when one is enabled. Recording is
         # a leaf-lock append (pool _lock -> recorder lock, never out).
@@ -197,6 +207,7 @@ class KVBlockPool:
                     child = _TrieNode(key, block, node)
                     node.children[key] = child
                     self._by_block[block] = child
+                    self._unpublished.add(block)
                     out.append((block, b))
                 child.tick = tick
                 node = child
@@ -230,6 +241,7 @@ class KVBlockPool:
                     child = _TrieNode(key, block, node)
                     node.children[key] = child
                     self._by_block[block] = child
+                    self._unpublished.add(block)
                     out.append((block, b))
                 child.tick = tick
                 covered = b + 1
@@ -259,9 +271,23 @@ class KVBlockPool:
                     break
                 del n.parent.children[n.key]
                 del self._by_block[n.block]
+                self._unpublished.discard(n.block)
                 self._free.append(n.block)
                 freed += 1
             return freed
+
+    def mark_published(self, blocks) -> None:
+        """The engine has dispatched the page copy for ``blocks`` (ids from
+        :meth:`insert` / :meth:`index`): every later dispatch reads the
+        tokens' K/V from them."""
+        with self._lock:
+            self._unpublished.difference_update(int(b) for b in blocks)
+
+    def unpublished(self, blocks) -> bool:
+        """True while any of ``blocks`` is indexed but its page copy is not
+        dispatched — an off-loop-thread reader must not gather it yet."""
+        with self._lock:
+            return any(int(b) in self._unpublished for b in blocks)
 
     def _alloc_locked(self) -> int | None:
         if self._free:
@@ -276,6 +302,7 @@ class KVBlockPool:
             return None  # everything pinned or interior: cannot evict
         del victim.parent.children[victim.key]
         del self._by_block[victim.block]
+        self._unpublished.discard(victim.block)
         self._evictions += 1
         self.recorder.record("prefix_evict", block=victim.block,
                              tick=victim.tick)

@@ -80,6 +80,7 @@ from distributed_tensorflow_tpu.obs.export import (
 )
 from distributed_tensorflow_tpu.obs.fleet import ReplicaSupervisor
 from distributed_tensorflow_tpu.obs.flightrec import NULL_RECORDER
+from distributed_tensorflow_tpu.runtime import require_chip_per_process
 
 logger = logging.getLogger(__name__)
 
@@ -341,17 +342,19 @@ class Router:
     def _launch(self, r: Replica) -> None:
         """(Re)launch one replica process; caller holds NO lock (Popen
         can take a while).  Replica stdout/err tees into ``log_dir`` when
-        configured so a crashed replica leaves a readable post-mortem."""
+        configured so a crashed replica leaves a readable post-mortem;
+        without one its stderr is the router's own — why a replica died at
+        start-up is never thrown away."""
         if r.cmd is None:
             raise ValueError(f"replica {r.name} is adopted (no cmd)")
         if self._log_dir is not None:
             self._log_dir.mkdir(parents=True, exist_ok=True)
             if r._log_fh is None or r._log_fh.closed:
                 r._log_fh = (self._log_dir / f"{r.name}.log").open("ab")
-            out = r._log_fh
+            out = err = r._log_fh
         else:
-            out = subprocess.DEVNULL
-        r.proc = subprocess.Popen(r.cmd, stdout=out, stderr=out)
+            out, err = subprocess.DEVNULL, None
+        r.proc = subprocess.Popen(r.cmd, stdout=out, stderr=err)
         r.started_at = self._clock()
         self.recorder.record(
             "router_spawn", replica=r.name, pid=r.proc.pid,
@@ -362,6 +365,11 @@ class Router:
 
     def start(self) -> "Router":
         """Spawn every owned replica and start the poll thread."""
+        owned = sum(1 for r in self.replicas if r.cmd is not None)
+        if owned:
+            require_chip_per_process(
+                owned, f"a router that owns {owned} replica processes"
+            )
         for r in self.replicas:
             if r.cmd is not None and r.proc is None:
                 self._launch(r)

@@ -23,6 +23,7 @@ TPU-first details:
 
 from __future__ import annotations
 
+import json
 import logging
 import math
 import time
@@ -58,6 +59,22 @@ class NonFiniteLossError(RuntimeError):
         )
         self.step = step
         self.loss = loss
+
+
+def _batch_layout(batch) -> dict:
+    """Per leaf: the global shape, one device's shard of it, and how many
+    devices hold a shard — where the batch really lives, not where the
+    loader was asked to put it."""
+    layout = {}
+    for path, leaf in jax.tree_util.tree_leaves_with_path(batch):
+        sharding = getattr(leaf, "sharding", None)
+        if sharding is not None:
+            layout[jax.tree_util.keystr(path, simple=True, separator="/")] = {
+                "shape": list(leaf.shape),
+                "shard": list(sharding.shard_shape(leaf.shape)),
+                "devices": len(sharding.device_set),
+            }
+    return layout
 
 
 def fit(
@@ -170,6 +187,8 @@ def fit(
     with tracer.span("host_wait", "train", step=start_step):
         batch = next(it)
     feed_metrics.observe_wait(time.perf_counter() - t_fetch)
+    if jax.process_index() == 0:
+        logger.info("batch_layout: %s", json.dumps(_batch_layout(batch)))
     for step in range(start_step, num_steps):
         if should_stop is not None and should_stop():
             logger.info("stop requested before step %d; leaving the loop", step)

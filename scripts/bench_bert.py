@@ -34,7 +34,7 @@ import jax.numpy as jnp
 import numpy as np
 import optax
 
-PEAK = 197e12  # v5e bf16 (bench.py chip table)
+from bench import chip_peak_flops, require_tpu
 
 
 def train_flops_per_token(cfg, L: int) -> float:
@@ -44,9 +44,10 @@ def train_flops_per_token(cfg, L: int) -> float:
     return 3.0 * fwd
 
 
-def bench_config(
-    L: int, per_chip_batch: int, n_long: int = 40, attn_impl: str = "dense"
-) -> dict:
+def build_step(L: int, per_chip_batch: int, attn_impl: str = "dense"):
+    """The benched program: BERT-base's production train step with one
+    resident synthetic batch. Returns ``(step, state, batch, rng, cfg)``;
+    ``step(state, batch, rng) -> (state, metrics)`` donates the state."""
     from distributed_tensorflow_tpu.models.bert import (
         BertForPreTraining,
         bert_base,
@@ -57,13 +58,8 @@ def bench_config(
     from distributed_tensorflow_tpu.train import create_train_state, make_train_step
     from distributed_tensorflow_tpu.train.step import place_state
 
+    gb = per_chip_batch * len(require_tpu())
     mesh = build_mesh({"data": -1})
-    n = len(jax.devices())
-    on_tpu = jax.devices()[0].platform == "tpu"
-    if not on_tpu:
-        per_chip_batch, n_long = 4, 3
-    gb = per_chip_batch * n
-
     cfg = bert_base(dtype=jnp.bfloat16, max_position=max(512, L), attn_impl=attn_impl)
     model = BertForPreTraining(cfg)
     rng0 = np.random.default_rng(0)
@@ -103,14 +99,24 @@ def bench_config(
     # measured r5, L=512 b=48) generating ~100M dropout bits in software.
     from distributed_tensorflow_tpu.train import make_rng
 
-    rng = make_rng(0)
+    return step, state, batch, make_rng(0), cfg
+
+
+def bench_config(
+    L: int, per_chip_batch: int, n_long: int = 40, attn_impl: str = "dense"
+) -> dict:
+    devices = require_tpu()
+    peak = chip_peak_flops(devices[0])  # an unknown chip fails before the run
+    step, state, batch, rng, cfg = build_step(L, per_chip_batch, attn_impl)
+    n = len(devices)
+    gb = per_chip_batch * n
 
     def window(k):
         nonlocal state
         t0 = time.perf_counter()
         for _ in range(k):
             state, metrics = step(state, batch, rng)
-        float(metrics["loss"])
+        jax.block_until_ready((state, metrics))
         return time.perf_counter() - t0
 
     window(3)  # compile + warm
@@ -121,7 +127,7 @@ def bench_config(
     spread = (longs[-1] - longs[0]) / longs[reps // 2]
 
     tokens_per_sec_chip = gb * L / per_step / n
-    mfu = tokens_per_sec_chip * train_flops_per_token(cfg, L) / PEAK
+    mfu = tokens_per_sec_chip * train_flops_per_token(cfg, L) / peak
     return {
         "L": L,
         "attn": attn_impl,
@@ -134,6 +140,9 @@ def bench_config(
 
 
 def main():
+    from distributed_tensorflow_tpu.runtime import enable_compile_cache
+
+    enable_compile_cache()
     results = [
         bench_config(128, 64, attn_impl="auto"),   # auto -> dense at 128
         bench_config(512, 24, attn_impl="auto"),   # auto -> flash at 512
@@ -165,7 +174,8 @@ def driver_line():
                 "unit": f"tokens/sec/chip (bf16, L=512, b={r['per_chip_batch']}/chip, "
                 f"flash attn, AdamW+clip1.0, rbg dropout rng, {dev.device_kind}, "
                 f"mfu={r['mfu']:.3f}, median windows, spread={r['spread']:.1%}, "
-                f"peak=197T; conv context: resnet50 mfu~0.17 structural plateau "
+                f"peak={chip_peak_flops(dev) / 1e12:.0f}T; "
+                f"conv context: resnet50 mfu~0.17 structural plateau "
                 f"via BENCH_WORKLOAD=resnet50, docs/PERF.md)",
                 "vs_baseline": round(r["mfu"] / 0.55, 4),
             }

@@ -3194,6 +3194,14 @@ def _run_migrate_drills(args) -> dict:
     pace_spec = (f"seed=5,slow_decode_step={geo['pace_steps']},"
                  f"slow_step_s={geo['slow_s']}")
 
+    # Two processes on the device: this one builds engines A and B, the
+    # subprocess replica builds V.
+    from distributed_tensorflow_tpu.runtime import require_chip_per_process
+
+    require_chip_per_process(
+        2, "serve_bench --migrate (two in-process engines plus one "
+        "subprocess replica)"
+    )
     # The subprocess replica warms its AOT grid while we build ours.
     me = os.path.abspath(__file__)
     vport = _free_ports(1)[0]
@@ -3201,9 +3209,7 @@ def _run_migrate_drills(args) -> dict:
             "--replica-tag", "migrate-v1", "--replica-fault-plan", pace_spec]
     if args.quick:
         vcmd.append("--quick")
-    vproc = subprocess.Popen(
-        vcmd, stdout=subprocess.DEVNULL, stderr=subprocess.DEVNULL
-    )
+    vproc = subprocess.Popen(vcmd, stdout=subprocess.DEVNULL)
 
     cfg = CausalLMConfig(
         vocab_size=64, hidden_size=geo["hidden"],

@@ -30,15 +30,7 @@ def main() -> int:
     # threefry_partitionable matches conftest so the pp/ep rehearsals'
     # trajectories are comparable against the launcher's in-process runs.
     jax.config.update("jax_platforms", "cpu")
-    try:
-        jax.config.update("jax_num_cpu_devices", 4)
-    except AttributeError:  # jax < 0.5: same fallback as tests/conftest.py
-        import os
-
-        os.environ["XLA_FLAGS"] = (
-            os.environ.get("XLA_FLAGS", "")
-            + " --xla_force_host_platform_device_count=4"
-        ).strip()
+    jax.config.update("jax_num_cpu_devices", 4)
     jax.config.update("jax_threefry_partitionable", True)
 
     import jax.numpy as jnp
@@ -64,17 +56,13 @@ def main() -> int:
     if mode == "chaos":
         # Like "straggler": beacons/checkpoints/dumps are the coordination-
         # free channels under test, so no JAX cluster — each host trains on
-        # its own local mesh (the CPU backend can't form cross-process
-        # clusters on jax < 0.5 anyway).
+        # its own local mesh.
         return _chaos_body(proc_id, sys.argv[5])
 
     if mode == "straggler":
         # Beacons are collective-free by design — the processes share only
         # the beacon directory, never a JAX cluster — so this mode skips
-        # initialize_runtime and runs each host on its own local mesh. It
-        # keeps working where the CPU backend can't form a cross-process
-        # cluster (jax < 0.5: "Multiprocess computations aren't
-        # implemented on the CPU backend").
+        # initialize_runtime and runs each host on its own local mesh.
         return _straggler_body(proc_id, sys.argv[5])
 
     initialize_runtime(

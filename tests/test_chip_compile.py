@@ -1,0 +1,121 @@
+"""The flash-attention kernels compile for a TPU v5e at production shapes.
+
+Interpret mode, which every other kernel test runs in, accepts what the
+chip's compiler refuses: a slice off the (8, 128) tiling, more scoped VMEM
+than a kernel may take. The TPU compiler is installed here and compiles for
+a chip that is described, not attached — so these tests cost no chip time
+and guard the kernels of the main path on every PR. A compile that passes is
+not a chip run: results and times come from chip_smoke.py.
+
+One file, on purpose: only one process at a time may load the TPU library,
+and an xdist worker keeps it until it exits. The topology is described
+inside a fixture, never at import, so every worker collects the same tests
+and only the one that runs this file loads the library.
+"""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from distributed_tensorflow_tpu.ops.flash_attention import (
+    _DEFAULT_BLOCK_K,
+    _DEFAULT_BLOCK_Q,
+    _flat_auto,
+    flash_attention,
+)
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+
+    had = os.environ.get("TPU_LOG_DIR")
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else it logs to /tmp
+    try:
+        try:
+            desc = topologies.get_topology_desc(
+                platform="tpu", topology_name="v5e:2x2"
+            )
+        except Exception as e:  # noqa: BLE001 — whatever keeps libtpu out
+            pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+        yield desc
+    finally:
+        if had is None:
+            os.environ.pop("TPU_LOG_DIR", None)
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(autouse=True)
+def no_persistent_cache():
+    """A compile for a described chip can be written to the persistent
+    cache but not read back without the chip; keep it out of these tests."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def _compile_fwd_bwd(one_chip, shape, dtype, packing):
+    """Compile forward + all three gradients of the kernel, compiled mode."""
+
+    def loss(q, k, v, mask):
+        o = flash_attention(q, k, v, mask, interpret=False, packing=packing)
+        return jnp.sum(o.astype(jnp.float32) ** 2)
+
+    def run(q, k, v, mask):
+        o = flash_attention(q, k, v, mask, interpret=False, packing=packing)
+        return o, jax.grad(loss, argnums=(0, 1, 2))(q, k, v, mask)
+
+    qkv = jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    mask = jax.ShapeDtypeStruct(shape[:2], jnp.bool_, sharding=one_chip)
+    return jax.jit(run).lower(qkv, qkv, qkv, mask).compile()
+
+
+# (B, L, H, D), dtype, packing asked for, packing the auto rule must pick.
+CASES = {
+    # The r5 production geometry: what chip_smoke.py's kernel phase runs.
+    "flat-bf16-L512": ((24, 512, 12, 64), "bfloat16", "flat", "flat"),
+    "bh-bf16-L512": ((24, 512, 12, 64), "bfloat16", "bh", "flat"),
+    # Past the packed path's VMEM budget: the auto rule must leave it, and
+    # the q/k loops run over 512-blocks with whole-sequence K/V resident.
+    "auto-bf16-L2048": ((4, 2048, 12, 64), "bfloat16", None, "bh"),
+    # f32 K/V streams are twice the bf16 residency (ADVICE.md, VMEM estimate).
+    "auto-f32-L1024": ((4, 1024, 12, 64), "float32", None, "bh"),
+    # The largest geometries the auto rule still sends to the packed path:
+    # the estimate says they fit, the compiler has to agree.
+    "auto-bf16-L1024": ((2, 1024, 12, 64), "bfloat16", None, "flat"),
+    "auto-f32-L512": ((2, 512, 12, 64), "float32", None, "flat"),
+}
+
+
+@pytest.mark.parametrize("case", sorted(CASES))
+def test_flash_attention_compiles_for_v5e(one_chip, case):
+    shape, dtype, packing, auto = CASES[case]
+    dtype = jnp.dtype(dtype)
+    _, l, h, d = shape
+    picked = _flat_auto(
+        h, d, min(_DEFAULT_BLOCK_Q, l), min(_DEFAULT_BLOCK_K, l),
+        False, l, dtype.itemsize,
+    )
+    assert ("flat" if picked else "bh") == auto
+    compiled = _compile_fwd_bwd(one_chip, shape, dtype, packing)
+    # Forward, dQ and dK/dV kernels, each a Mosaic custom call.
+    assert compiled.as_text().count("tpu_custom_call") >= 3
+
+
+def test_explicit_flat_past_the_vmem_budget_is_refused_before_the_compiler(
+    one_chip,
+):
+    with pytest.raises(ValueError, match="VMEM"):
+        _compile_fwd_bwd(one_chip, (4, 2048, 12, 64), jnp.bfloat16, "flat")

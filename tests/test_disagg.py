@@ -389,7 +389,10 @@ def test_export_import_round_trip_pages_bit_exact(role_pair):
         pk, pv = eng.export_prefix_pages(m.blocks)
         n = len(m.blocks)
         host_k = np.asarray(jax.device_get(pk))[:, :n]
-        src = np.asarray(jax.device_get(eng._pool_k))[:, m.blocks]
+        # A publish on the batcher thread donates the pool buffer and
+        # rebinds the ref; _pool_lock is what orders a read against it.
+        with eng._pool_lock:
+            src = np.asarray(jax.device_get(eng._pool_k))[:, m.blocks]
         assert host_k.tobytes() == src.tobytes()
         ids = [int(t) for t in prompt[: n * pool.block_tokens]]
         buf = serialize_chain(ids, host_k,

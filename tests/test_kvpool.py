@@ -148,3 +148,37 @@ def test_concurrent_match_insert_release_is_consistent():
 def test_match_returns_prefixmatch_type():
     pool = KVBlockPool(2, 4)
     assert isinstance(pool.match(list(range(9))), PrefixMatch)
+
+
+def test_indexed_blocks_are_unpublished_until_the_engine_says_so():
+    """insert/index make a block matchable at once; its page holds the
+    tokens only after the engine's copy is dispatched. A reader on another
+    thread (the disagg page export) waits on exactly this."""
+    pool = KVBlockPool(n_blocks=3, block_tokens=2)
+    new = pool.insert([1, 2, 3, 4])
+    ids = [b for b, _ in new]
+    assert len(ids) == 2 and pool.unpublished(ids)
+    pool.mark_published(ids[:1])
+    assert not pool.unpublished(ids[:1]) and pool.unpublished(ids)
+    pool.mark_published(ids)
+    assert not pool.unpublished(ids)
+    # Already cached: nothing new to publish.
+    assert pool.insert([1, 2, 3, 4]) == []
+    assert not pool.unpublished(ids)
+    more, covered = pool.index([1, 2, 9, 9])
+    assert covered == 2 and pool.unpublished([b for b, _ in more])
+
+
+def test_freed_blocks_leave_the_unpublished_set():
+    pool = KVBlockPool(n_blocks=2, block_tokens=2)
+    (a, _), (b, _) = pool.insert([1, 2, 3, 4])
+    # forget: the undo path of a publish that failed.
+    assert pool.forget([1, 2, 3, 4]) == 2
+    assert not pool.unpublished([a, b])
+    # eviction: the leaf's page goes to another chain and must be
+    # published again under its new meaning.
+    (c, _), (d, _) = pool.insert([5, 6, 7, 8])
+    pool.mark_published([c, d])
+    ((e, _),) = pool.insert([9, 9])  # full pool: evicts the leaf, block d
+    assert e == d and pool.unpublished([e])
+    assert not pool.unpublished([c])

