@@ -614,18 +614,22 @@ class BertForPreTraining(nn.Module):
         )
 
     def _heads(self, hidden, pooled):
-        h = self.mlm_ln(nn.gelu(self.mlm_transform(hidden), approximate=True))
-        # Tied decoder: logits against the word-embedding table. Logits KEEP
-        # the compute dtype: at BERT geometry the [B, L, V] tensor is the
-        # single biggest array in the step (1.5 GB bf16 at L=512 b=48), and
-        # the r5 trace showed the old f32 upcast doubling every loss-side
-        # pass over it (3.0 GB reads in the CE reduce, the argmax, and the
-        # bwd softmax recompute — scripts/bert_breakdown.py). _mlm_stats
-        # does its reductions in f32 on the fly; bf16 storage costs no
-        # stability (max is exact in bf16, exp/sum accumulate in f32).
-        mlm_logits = self.bert.embeddings.word.attend(h) + self.mlm_bias.astype(
-            self.cfg.dtype
-        )
+        with jax.named_scope("mlm_head"):
+            h = self.mlm_ln(
+                nn.gelu(self.mlm_transform(hidden), approximate=True)
+            )
+            # Tied decoder: logits against the word-embedding table. Logits
+            # KEEP the compute dtype: at BERT geometry the [B, L, V] tensor
+            # is the single biggest array in the step (1.5 GB bf16 at L=512
+            # b=48), and the r5 trace showed the old f32 upcast doubling
+            # every loss-side pass over it (3.0 GB reads in the CE reduce,
+            # the argmax, and the bwd softmax recompute — docs/PERF.md r5).
+            # _mlm_stats does its reductions in f32 on the fly; bf16 storage
+            # costs no stability (max is exact in bf16, exp/sum accumulate
+            # in f32).
+            mlm_logits = self.bert.embeddings.word.attend(
+                h
+            ) + self.mlm_bias.astype(self.cfg.dtype)
         nsp_logits = self.nsp_head(pooled)
         return mlm_logits, nsp_logits.astype(jnp.float32)
 
@@ -659,7 +663,7 @@ def _mlm_stats(mlm_logits, batch, seq_axis):
     backward emits the softmax cotangent in storage dtype. Versus upcasting
     the [B, L, V] logits to f32 first, every pass over the step's biggest
     tensor moves half the bytes (measured 6.8 ms for the old f32 CE reduce
-    alone, scripts/bert_breakdown.py). Accuracy reuses the already-computed
+    alone, docs/PERF.md r5). Accuracy reuses the already-computed
     row max instead of a second full argmax pass over [B, L, V]: a masked
     position counts correct iff its target logit equals the row max
     (ties — measure-zero in f32, rare in bf16 — count correct)."""
@@ -845,7 +849,8 @@ def make_bert_pretraining_loss(model: BertForPreTraining):
             # (the nn.scan encoder) — jnp.mean handles both uniformly.
             aux_leaves = jax.tree.leaves(mods["intermediates"])
             moe_aux = sum(jnp.mean(a) for a in aux_leaves) / len(aux_leaves)
-        num, den, correct = _mlm_stats(mlm_logits, batch, seq_axis)
+        with jax.named_scope("mlm_head"):
+            num, den, correct = _mlm_stats(mlm_logits, batch, seq_axis)
         den = jnp.maximum(den, 1.0)
         mlm_loss = num / den
         nsp_loss = optax.softmax_cross_entropy_with_integer_labels(

@@ -72,13 +72,15 @@ def _layer_cache(cache, i):
 
 def _stack_cache(layers):
     """Re-stack per-layer cache returns, preserving the quantized pytree
-    structure when present."""
-    if isinstance(layers[0], dict):
-        return {
-            "q": jnp.stack([c["q"] for c in layers]),
-            "s": jnp.stack([c["s"] for c in layers]),
-        }
-    return jnp.stack(layers)
+    structure when present. Part of ``kv_write``: the step's new slot table
+    is the stack of the per-layer tables its scatters produced."""
+    with jax.named_scope("kv_write"):
+        if isinstance(layers[0], dict):
+            return {
+                "q": jnp.stack([c["q"] for c in layers]),
+                "s": jnp.stack([c["s"] for c in layers]),
+            }
+        return jnp.stack(layers)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -141,25 +143,26 @@ def _cached_attention(q, k_cache, v_cache, position, k_scale=None,
     after the QK^T product and the v-scale folds into the softmax weights
     before the context product, so the dense cache is never materialized.
     """
-    scale = q.shape[-1] ** -0.5
-    kc = k_cache if k_scale is None else k_cache.astype(jnp.float32)
-    s = jnp.einsum(
-        "shd,slhd->shl", q, kc, preferred_element_type=jnp.float32
-    )
-    if k_scale is not None:
-        s = s * k_scale[:, None, :]
-    s = s * scale
-    valid = jnp.arange(k_cache.shape[1])[None, :] <= position[:, None]
-    s = jnp.where(valid[:, None, :], s, _MASK_VALUE)
-    p = jax.nn.softmax(s, axis=-1) * valid[:, None, :]
-    vc = v_cache
-    if v_scale is not None:
-        p = p * v_scale[:, None, :]
-        vc = v_cache.astype(jnp.float32)
-    return jnp.einsum(
-        "shl,slhd->shd", p.astype(vc.dtype), vc,
-        preferred_element_type=jnp.float32,
-    ).astype(q.dtype)
+    with jax.named_scope("cached_attention"):
+        scale = q.shape[-1] ** -0.5
+        kc = k_cache if k_scale is None else k_cache.astype(jnp.float32)
+        s = jnp.einsum(
+            "shd,slhd->shl", q, kc, preferred_element_type=jnp.float32
+        )
+        if k_scale is not None:
+            s = s * k_scale[:, None, :]
+        s = s * scale
+        valid = jnp.arange(k_cache.shape[1])[None, :] <= position[:, None]
+        s = jnp.where(valid[:, None, :], s, _MASK_VALUE)
+        p = jax.nn.softmax(s, axis=-1) * valid[:, None, :]
+        vc = v_cache
+        if v_scale is not None:
+            p = p * v_scale[:, None, :]
+            vc = v_cache.astype(jnp.float32)
+        return jnp.einsum(
+            "shl,slhd->shd", p.astype(vc.dtype), vc,
+            preferred_element_type=jnp.float32,
+        ).astype(q.dtype)
 
 
 def _chunk_attention(q, k_cache, v_cache, position, k_scale=None,
@@ -250,28 +253,30 @@ class CausalSelfAttention(nn.Module):
         if isinstance(k_cache, dict):
             # int8 KV mode: quantize the new token per slot at the write,
             # attend with the factored per-position scales.
-            qk, sk = quantize_kv(k)
-            qv, sv = quantize_kv(v)
-            k_cache = {
-                "q": k_cache["q"].at[idx, position].set(qk, mode="drop"),
-                "s": k_cache["s"].at[idx, position].set(sk, mode="drop"),
-            }
-            v_cache = {
-                "q": v_cache["q"].at[idx, position].set(qv, mode="drop"),
-                "s": v_cache["s"].at[idx, position].set(sv, mode="drop"),
-            }
+            with jax.named_scope("kv_write"):
+                qk, sk = quantize_kv(k)
+                qv, sv = quantize_kv(v)
+                k_cache = {
+                    "q": k_cache["q"].at[idx, position].set(qk, mode="drop"),
+                    "s": k_cache["s"].at[idx, position].set(sk, mode="drop"),
+                }
+                v_cache = {
+                    "q": v_cache["q"].at[idx, position].set(qv, mode="drop"),
+                    "s": v_cache["s"].at[idx, position].set(sv, mode="drop"),
+                }
             ctx = _cached_attention(
                 q, k_cache["q"], v_cache["q"],
                 jnp.minimum(position, k_cache["q"].shape[1] - 1),
                 k_scale=k_cache["s"], v_scale=v_cache["s"],
             )
             return self._finish(x, ctx), k_cache, v_cache
-        k_cache = k_cache.at[idx, position].set(
-            k.astype(k_cache.dtype), mode="drop"
-        )
-        v_cache = v_cache.at[idx, position].set(
-            v.astype(v_cache.dtype), mode="drop"
-        )
+        with jax.named_scope("kv_write"):
+            k_cache = k_cache.at[idx, position].set(
+                k.astype(k_cache.dtype), mode="drop"
+            )
+            v_cache = v_cache.at[idx, position].set(
+                v.astype(v_cache.dtype), mode="drop"
+            )
         ctx = _cached_attention(
             q, k_cache, v_cache,
             jnp.minimum(position, k_cache.shape[1] - 1),
@@ -402,8 +407,9 @@ class CausalLM(nn.Module):
     def _head(self, h):
         # Tied decoder against the embedding table (BertForPreTraining's
         # _heads recipe): transform -> LN -> attend + bias.
-        h = self.lm_ln(nn.gelu(self.lm_transform(h), approximate=True))
-        return self.word.attend(h) + self.lm_bias.astype(self.cfg.dtype)
+        with jax.named_scope("lm_head"):
+            h = self.lm_ln(nn.gelu(self.lm_transform(h), approximate=True))
+            return self.word.attend(h) + self.lm_bias.astype(self.cfg.dtype)
 
     def __call__(self, input_ids, attention_mask):
         l = input_ids.shape[1]
@@ -482,15 +488,16 @@ def sample_tokens(logits, temperature, seed, step):
     the identical token stream it would draw solo (the determinism contract
     tests/test_serve_decode.py pins).
     """
-    greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+    with jax.named_scope("sample"):
+        greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
 
-    def one(row, t, s, c):
-        key = jax.random.fold_in(jax.random.PRNGKey(s), c)
-        scaled = row.astype(jnp.float32) / jnp.maximum(t, 1e-6)
-        return jax.random.categorical(key, scaled).astype(jnp.int32)
+        def one(row, t, s, c):
+            key = jax.random.fold_in(jax.random.PRNGKey(s), c)
+            scaled = row.astype(jnp.float32) / jnp.maximum(t, 1e-6)
+            return jax.random.categorical(key, scaled).astype(jnp.int32)
 
-    sampled = jax.vmap(one)(logits, temperature, seed, step)
-    return jnp.where(temperature > 0.0, sampled, greedy)
+        sampled = jax.vmap(one)(logits, temperature, seed, step)
+        return jnp.where(temperature > 0.0, sampled, greedy)
 
 
 def causal_param_specs(params, model_axis: str | None = "model"):

@@ -20,11 +20,21 @@ spends its wall time between the counters. Three pieces:
   loads — ``ph: "X"`` complete events with microsecond ``ts``/``dur``,
   ``ph: "i"`` instants, real ``pid``/``tid``.
 
+- **One clock with the device**: an enabled tracer's scoped span is also
+  a ``jax.profiler.TraceAnnotation``, so whenever a profiler session is
+  open (``POST /profilez``, ``cli.train --profile-dir``, the benchmark's
+  ``--trace 1``) the span lies on a host line of the same xplane as the
+  device ops, and :meth:`Tracer.step` marks a loop's steps for it. With
+  no session open an annotation is a flag test. The classes are looked up
+  in ``sys.modules`` and only where jax is loaded: this module never
+  imports jax and works without it. ``record``/``instant`` carry stamps
+  taken elsewhere and stay ring-only.
+
 Overhead contract (the "always-on-capable" requirement): a DISABLED
 tracer is a branch and a return at every call site — ``span()`` hands
 back a shared no-op context manager, ``record``/``instant`` return on
-the first line, nothing allocates. An ENABLED tracer costs one small
-object + one deque append per span; the buffer is bounded
+the first line, nothing allocates. An ENABLED tracer costs two small
+objects + one deque append per span; the buffer is bounded
 (``buffer_size``), so a serving process tracing forever holds a fixed
 window of recent spans, never an unbounded log.
 """
@@ -34,6 +44,7 @@ from __future__ import annotations
 import itertools
 import json
 import os
+import sys
 import threading
 import time
 from pathlib import Path
@@ -86,29 +97,57 @@ class _NullSpan:
 
 NULL_SPAN = _NullSpan()
 
+def _profiler_class(name: str):
+    """``jax.profiler.<name>`` where this process has loaded jax, else
+    ``None``. Looked up in ``sys.modules`` each time (three dictionary
+    reads), never imported and never cached."""
+    profiler = getattr(sys.modules.get("jax"), "profiler", None)
+    return getattr(profiler, name, None)
+
+
+def _annotation_keys(args: dict) -> dict:
+    """What a profiler annotation can carry of a span's keys: ints and
+    strings (bools are ints; floats, lists and None stay in the ring)."""
+    return {k: v for k, v in args.items() if isinstance(v, (int, str))}
+
 
 class _ScopedSpan:
-    """Context manager for an open span; pops the thread-local stack and
-    commits to the ring buffer on exit."""
+    """Context manager for an open span: the ring's :class:`Span` plus, in
+    a process with jax, the profiler annotation of the same name. Pops the
+    thread-local stack and commits to the ring buffer on exit."""
 
-    __slots__ = ("_tracer", "_span")
+    __slots__ = ("_tracer", "_span", "_annotation")
 
     def __init__(self, tracer, span):
         self._tracer = tracer
         self._span = span
+        self._annotation = None
 
     def set(self, **args) -> None:
         """Attach args to the open span (e.g. the chosen tier, row count)."""
         if self._span.args is None:
             self._span.args = {}
         self._span.args.update(args)
+        if self._annotation is not None:
+            self._annotation.set_metadata(**_annotation_keys(args))
 
     def __enter__(self):
-        self._tracer._stack().append(self._span)
+        span = self._span
+        self._tracer._stack().append(span)
+        annotation = _profiler_class("TraceAnnotation")
+        if annotation is not None:
+            keys = _annotation_keys(
+                {"step": span.step, "request_id": span.request_id,
+                 **(span.args or {})}
+            )
+            self._annotation = annotation(span.name, **keys)
+            self._annotation.__enter__()
         return self
 
     def __exit__(self, *exc):
         span = self._span
+        if self._annotation is not None:
+            self._annotation.__exit__(*exc)
         span.t1 = time.monotonic()
         stack = self._tracer._stack()
         if stack and stack[-1] is span:
@@ -176,6 +215,14 @@ class Tracer:
             next(self._ids), parent.span_id if parent else None,
             request_id, step, args or None,
         ))
+
+    def step(self, name: str, step_num: int):
+        """The profiler's step marker around one step of a loop
+        (``jax.profiler.StepTraceAnnotation``): a capture groups what lies
+        inside it by step. Profiler only, nothing enters the ring. On a
+        disabled tracer, or without jax, the shared :data:`NULL_SPAN`."""
+        marker = _profiler_class("StepTraceAnnotation") if self.enabled else None
+        return marker(name, step_num=step_num) if marker else NULL_SPAN
 
     def record(self, name: str, t0: float, t1: float, *, cat: str = "",
                request_id=None, step=None, tid=None, args=None) -> None:

@@ -1,7 +1,7 @@
 """1x1 convolution with Pallas backward kernels — the ResNet-50 hot path.
 
 Why this exists (r3 perf frontier, VERDICT r2 Missing #1): the
-scripts/hlo_breakdown.py trace of the b=128 ResNet-50 step shows XLA:TPU's
+r3 trace of the b=128 ResNet-50 step (docs/PERF.md) shows XLA:TPU's
 *backward* machinery for 1x1 convolutions running at 8–25 TF/s and
 ~80–160 GB/s — 4–5x below this chip's measured ~650 GB/s streaming bandwidth
 (scripts/roofline.py), 16.7 ms of dgrad + 11.2 ms of wgrad in a 46.4 ms step.

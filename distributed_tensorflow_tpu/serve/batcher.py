@@ -804,7 +804,7 @@ class ContinuousBatcher:
     long-prompt admission. The final chunk samples the first token
     (``t_first``/``ttft`` semantics unchanged) and publishes the finished
     prefix pages back to the pool; chunk dispatches ride a batch-level
-    ``prefill_chunk`` phase/span while per-request phases keep the same
+    ``prefill_chunk`` phase while per-request phases keep the same
     contiguous taxonomy (the ``prefill`` phase simply covers every chunk).
 
     On a SPECULATIVE engine (``spec_tokens > 0``, exposing ``verify`` and
@@ -1349,7 +1349,7 @@ class ContinuousBatcher:
         slots keep riding the plain pipelined decode step — speculation
         only ever trades pipelining for verify width when the drafter
         actually has a proposal."""
-        metrics = self.metrics
+        metrics, tracer = self.metrics, self.tracer
         with self._cv:
             while True:
                 if (
@@ -1398,401 +1398,415 @@ class ContinuousBatcher:
                     metrics.queue_depth.set(0)
                     metrics.slots_active.set(self._n_active)
                     return ("export", req, exported, queued, adopts_q)
-                # Chain adoptions drain first — a popped adoption's pool
-                # insert + page import runs before the NEXT pass's trie
-                # matches, so admissions planned after this pass can hit
-                # the transferred chain.
-                adopts = []
-                while self._adoptions:
-                    adopts.append(self._adoptions.popleft())
-                # Migrated streams claim free slots BEFORE fresh
-                # admissions — they are the oldest work in the house, and
-                # their slot-import dispatch precedes everything else this
-                # pass plans, so the decode step planned below can already
-                # include them.
-                stream_rows = []
-                while self._stream_adopts:
-                    free_ix = next(
-                        (i for i, s in enumerate(self._slots)
-                         if s is None),
-                        None,
+                with tracer.span("batcher.plan", "serve") as plan:
+                    work = self._plan_locked(plan)
+                if work is not None:
+                    return work
+                with tracer.span("batcher.idle", "serve"):
+                    self._cv.wait()
+
+    def _plan_locked(self, plan):
+        """One planning pass of :meth:`_take_work`, ``_cv`` held: the
+        ``("work", ...)`` tuple, or None when there is nothing to dispatch
+        yet. ``plan`` is the pass's open ``batcher.plan`` span."""
+        metrics = self.metrics
+        # Chain adoptions drain first — a popped adoption's pool
+        # insert + page import runs before the NEXT pass's trie
+        # matches, so admissions planned after this pass can hit
+        # the transferred chain.
+        adopts = []
+        while self._adoptions:
+            adopts.append(self._adoptions.popleft())
+        # Migrated streams claim free slots BEFORE fresh
+        # admissions — they are the oldest work in the house, and
+        # their slot-import dispatch precedes everything else this
+        # pass plans, so the decode step planned below can already
+        # include them.
+        stream_rows = []
+        while self._stream_adopts:
+            free_ix = next(
+                (i for i, s in enumerate(self._slots)
+                 if s is None),
+                None,
+            )
+            if free_ix is None:
+                break
+            state, pk, pv, fut = self._stream_adopts.popleft()
+            now = time.monotonic()
+            pend = _Pending(state.replay_payload(),
+                            state.request_id)
+            pend.future = fut
+            pend.t_taken = now
+            slot = _Slot(pend, next(self._gens), pend.payload,
+                         self._default_max_new)
+            # Mid-generation occupant: its pages land via the
+            # slot-import cell (no prefill), so the next decode
+            # step continues at the stream's absolute position.
+            slot.length = state.length
+            slot.n_dispatched = len(slot.tokens)
+            slot.t_first = now
+            slot.t_last_tok = now
+            if self._spec_k:
+                # Fresh SlotSpec: spec state resets cleanly on
+                # migration (drafting history is rebuilt from
+                # prompt + accumulated tokens, EMA starts over).
+                slot.spec = SlotSpec(self._spec_cfg)
+                slot.prompt_ids = [
+                    int(t) for t in state.input_ids
+                ]
+            slot.slot_id = free_ix
+            self._slots[free_ix] = slot
+            self._n_active += 1
+            stream_rows.append((free_ix, slot, pk, pv))
+        if stream_rows:
+            metrics.slots_active.set(self._n_active)
+        # -------------------------------------- priority preemption
+        # MARK: when a queued deadline holder would miss its
+        # deadline waiting for a natural slot free, pick a strictly
+        # lower-priority occupant per uncovered urgent waiter and
+        # flag it. A marked victim takes no further chunk/verify/
+        # decode dispatches (see _steppable); it PARKS below once
+        # its in-flight steps settle. Already-marked and exempt
+        # slots count as arriving capacity, so one waiter never
+        # marks the whole table.
+        if self.config.preempt and self._queue:
+            free_n = sum(1 for s in self._slots if s is None)
+            marked_n = sum(
+                1 for s in self._slots
+                if s is not None and s.preempting
+            )
+            now = time.monotonic()
+            margin = self.config.preempt_margin_ms / 1e3
+            urgent = sorted(
+                (q for q in self._queue
+                 if q.deadline_abs is not None
+                 and now + margin >= q.deadline_abs),
+                key=lambda q: (q.priority, q.deadline_abs,
+                               q.t_enqueue),
+            )
+            need = len(urgent) - free_n - marked_n
+            for w in urgent:
+                if need <= 0:
+                    break
+                victim = None
+                for s in self._slots:
+                    if (
+                        s is None
+                        or s.preempting
+                        or s.preempt_exempt
+                        or s.pending.priority <= w.priority
+                    ):
+                        continue
+                    # Lowest-urgency class first; within it, the
+                    # occupant with the least generated progress
+                    # (cheapest park + re-prefill round trip).
+                    if victim is None or (
+                        s.pending.priority,
+                        -len(s.tokens),
+                    ) > (
+                        victim.pending.priority,
+                        -len(victim.tokens),
+                    ):
+                        victim = s
+                if victim is None:
+                    continue
+                victim.preempting = True
+                need -= 1
+        # PARK: settle-and-evict every marked victim whose steps
+        # have landed. The victim's client future survives — its
+        # _Pending re-enqueues with the generated tokens as
+        # resume_tokens (the PR 18 replay contract: bit-identical
+        # by (seed, absolute position) sampling) — and, when the
+        # prefix pool can hold the full settled sequence, the
+        # slot's KV lane publishes into parked pool pages first so
+        # the resume's re-prefill is a near-pure cache hit. A pool
+        # too full to cover the whole parked sequence ABORTS the
+        # preemption instead (the victim finishes; it is never
+        # lost) — re-prefilling against garbage or half-parked
+        # pages is how bit-parity dies.
+        park_rows = []
+        if self.config.preempt:
+            for i, s in enumerate(self._slots):
+                if s is None or not s.preempting:
+                    continue
+                if s.prefilling:
+                    # Mid-prefill victims park page-less NOW: any
+                    # in-flight chunk's completion drops on the
+                    # gen tag, nothing generated is lost (tokens
+                    # == the resume prefix it arrived with), and
+                    # the pinned prefix match unpins below.
+                    settled = True
+                else:
+                    settled = (
+                        not s.verifying
+                        and s.n_dispatched == len(s.tokens)
                     )
-                    if free_ix is None:
-                        break
-                    state, pk, pv, fut = self._stream_adopts.popleft()
-                    now = time.monotonic()
-                    pend = _Pending(state.replay_payload(),
-                                    state.request_id)
-                    pend.future = fut
-                    pend.t_taken = now
-                    slot = _Slot(pend, next(self._gens), pend.payload,
-                                 self._default_max_new)
-                    # Mid-generation occupant: its pages land via the
-                    # slot-import cell (no prefill), so the next decode
-                    # step continues at the stream's absolute position.
-                    slot.length = state.length
-                    slot.n_dispatched = len(slot.tokens)
-                    slot.t_first = now
-                    slot.t_last_tok = now
-                    if self._spec_k:
-                        # Fresh SlotSpec: spec state resets cleanly on
-                        # migration (drafting history is rebuilt from
-                        # prompt + accumulated tokens, EMA starts over).
-                        slot.spec = SlotSpec(self._spec_cfg)
-                        slot.prompt_ids = [
-                            int(t) for t in state.input_ids
-                        ]
-                    slot.slot_id = free_ix
-                    self._slots[free_ix] = slot
-                    self._n_active += 1
-                    stream_rows.append((free_ix, slot, pk, pv))
-                if stream_rows:
-                    metrics.slots_active.set(self._n_active)
-                # -------------------------------------- priority preemption
-                # MARK: when a queued deadline holder would miss its
-                # deadline waiting for a natural slot free, pick a strictly
-                # lower-priority occupant per uncovered urgent waiter and
-                # flag it. A marked victim takes no further chunk/verify/
-                # decode dispatches (see _steppable); it PARKS below once
-                # its in-flight steps settle. Already-marked and exempt
-                # slots count as arriving capacity, so one waiter never
-                # marks the whole table.
-                if self.config.preempt and self._queue:
-                    free_n = sum(1 for s in self._slots if s is None)
-                    marked_n = sum(
-                        1 for s in self._slots
-                        if s is not None and s.preempting
-                    )
-                    now = time.monotonic()
-                    margin = self.config.preempt_margin_ms / 1e3
-                    urgent = sorted(
-                        (q for q in self._queue
-                         if q.deadline_abs is not None
-                         and now + margin >= q.deadline_abs),
-                        key=lambda q: (q.priority, q.deadline_abs,
-                                       q.t_enqueue),
-                    )
-                    need = len(urgent) - free_n - marked_n
-                    for w in urgent:
-                        if need <= 0:
-                            break
-                        victim = None
-                        for s in self._slots:
-                            if (
-                                s is None
-                                or s.preempting
-                                or s.preempt_exempt
-                                or s.pending.priority <= w.priority
-                            ):
-                                continue
-                            # Lowest-urgency class first; within it, the
-                            # occupant with the least generated progress
-                            # (cheapest park + re-prefill round trip).
-                            if victim is None or (
-                                s.pending.priority,
-                                -len(s.tokens),
-                            ) > (
-                                victim.pending.priority,
-                                -len(victim.tokens),
-                            ):
-                                victim = s
-                        if victim is None:
-                            continue
-                        victim.preempting = True
-                        need -= 1
-                # PARK: settle-and-evict every marked victim whose steps
-                # have landed. The victim's client future survives — its
-                # _Pending re-enqueues with the generated tokens as
-                # resume_tokens (the PR 18 replay contract: bit-identical
-                # by (seed, absolute position) sampling) — and, when the
-                # prefix pool can hold the full settled sequence, the
-                # slot's KV lane publishes into parked pool pages first so
-                # the resume's re-prefill is a near-pure cache hit. A pool
-                # too full to cover the whole parked sequence ABORTS the
-                # preemption instead (the victim finishes; it is never
-                # lost) — re-prefilling against garbage or half-parked
-                # pages is how bit-parity dies.
-                park_rows = []
-                if self.config.preempt:
-                    for i, s in enumerate(self._slots):
-                        if s is None or not s.preempting:
-                            continue
-                        if s.prefilling:
-                            # Mid-prefill victims park page-less NOW: any
-                            # in-flight chunk's completion drops on the
-                            # gen tag, nothing generated is lost (tokens
-                            # == the resume prefix it arrived with), and
-                            # the pinned prefix match unpins below.
-                            settled = True
+                if not settled:
+                    continue
+                p = s.pending
+                reason, new_blocks = "pageless", []
+                if (
+                    not s.prefilling
+                    and s.tokens
+                    and self._pool is not None
+                    and callable(getattr(
+                        self._engine, "insert_prefix", None
+                    ))
+                ):
+                    # Settled lane covers positions 0..length-1
+                    # (the newest token's KV is written by the
+                    # step that was never dispatched).
+                    key = (
+                        s.full_prompt + s.tokens[len(s.resume):]
+                    )[: s.length]
+                    cap = getattr(self._engine, "_max_chain", None)
+                    if cap is not None:
+                        key = key[: cap * self._pool.block_tokens]
+                    want = len(key) // self._pool.block_tokens
+                    if want > 0:
+                        # Lock order _cv -> pool, same as the
+                        # admission trie match.
+                        new_blocks, covered = self._pool.index(key)
+                        if covered >= want:
+                            reason = "paged"
                         else:
-                            settled = (
-                                not s.verifying
-                                and s.n_dispatched == len(s.tokens)
+                            # Park-pool-full: whatever prefix DID
+                            # index still gets its page copy below
+                            # (it is valid data the pool now
+                            # advertises), but the victim keeps
+                            # its slot and finishes. Exempt, so
+                            # the next pass marks someone else.
+                            s.preempting = False
+                            s.preempt_exempt = True
+                            self._preempt_aborted += 1
+                            park_rows.append(
+                                ("abort", i, s, "park_full",
+                                 new_blocks)
                             )
-                        if not settled:
                             continue
-                        p = s.pending
-                        reason, new_blocks = "pageless", []
-                        if (
-                            not s.prefilling
-                            and s.tokens
-                            and self._pool is not None
-                            and callable(getattr(
-                                self._engine, "insert_prefix", None
-                            ))
-                        ):
-                            # Settled lane covers positions 0..length-1
-                            # (the newest token's KV is written by the
-                            # step that was never dispatched).
-                            key = (
-                                s.full_prompt + s.tokens[len(s.resume):]
-                            )[: s.length]
-                            cap = getattr(self._engine, "_max_chain", None)
-                            if cap is not None:
-                                key = key[: cap * self._pool.block_tokens]
-                            want = len(key) // self._pool.block_tokens
-                            if want > 0:
-                                # Lock order _cv -> pool, same as the
-                                # admission trie match.
-                                new_blocks, covered = self._pool.index(key)
-                                if covered >= want:
-                                    reason = "paged"
-                                else:
-                                    # Park-pool-full: whatever prefix DID
-                                    # index still gets its page copy below
-                                    # (it is valid data the pool now
-                                    # advertises), but the victim keeps
-                                    # its slot and finishes. Exempt, so
-                                    # the next pass marks someone else.
-                                    s.preempting = False
-                                    s.preempt_exempt = True
-                                    self._preempt_aborted += 1
-                                    park_rows.append(
-                                        ("abort", i, s, "park_full",
-                                         new_blocks)
-                                    )
-                                    continue
-                        if reason == "pageless" and not self._chunked:
-                            # Monolithic prefill buckets the resumed
-                            # prompt (original + every generated token);
-                            # an un-bucketable resume cannot replay here.
-                            try:
-                                self._engine.bucket_for(
-                                    s.prompt_len + len(s.tokens)
-                                )
-                            except Exception:  # noqa: BLE001
-                                s.preempting = False
-                                s.preempt_exempt = True
-                                self._preempt_aborted += 1
-                                park_rows.append(
-                                    ("abort", i, s, "bucket_overflow", [])
-                                )
-                                continue
-                        pl = dict(p.payload)
-                        if s.tokens:
-                            pl["resume_tokens"] = [int(t) for t in s.tokens]
-                        p.payload = pl
-                        p.preempted += 1
-                        self._slots[i] = None
-                        self._n_active -= 1
-                        if self._pool is not None and s.chain is not None:
-                            self._pool.release(s.chain)  # idempotent unpin
-                        self._queue.append(p)
-                        self._count += 1
-                        self._class_delta(p.priority, +1)
-                        self._preempt_parked += 1
-                        park_rows.append(("park", i, s, reason, new_blocks))
-                    if park_rows:
-                        metrics.queue_depth.set(self._count)
-                        metrics.slots_active.set(self._n_active)
-                admissions = []
-                free = [
-                    i for i, s in enumerate(self._slots) if s is None
-                ]
-                may_admit = self._queue and free and (
-                    self._admission == "continuous" or self._n_active == 0
+                if reason == "pageless" and not self._chunked:
+                    # Monolithic prefill buckets the resumed
+                    # prompt (original + every generated token);
+                    # an un-bucketable resume cannot replay here.
+                    try:
+                        self._engine.bucket_for(
+                            s.prompt_len + len(s.tokens)
+                        )
+                    except Exception:  # noqa: BLE001
+                        s.preempting = False
+                        s.preempt_exempt = True
+                        self._preempt_aborted += 1
+                        park_rows.append(
+                            ("abort", i, s, "bucket_overflow", [])
+                        )
+                        continue
+                pl = dict(p.payload)
+                if s.tokens:
+                    pl["resume_tokens"] = [int(t) for t in s.tokens]
+                p.payload = pl
+                p.preempted += 1
+                self._slots[i] = None
+                self._n_active -= 1
+                if self._pool is not None and s.chain is not None:
+                    self._pool.release(s.chain)  # idempotent unpin
+                self._queue.append(p)
+                self._count += 1
+                self._class_delta(p.priority, +1)
+                self._preempt_parked += 1
+                park_rows.append(("park", i, s, reason, new_blocks))
+            if park_rows:
+                metrics.queue_depth.set(self._count)
+                metrics.slots_active.set(self._n_active)
+        admissions = []
+        free = [
+            i for i, s in enumerate(self._slots) if s is None
+        ]
+        may_admit = self._queue and free and (
+            self._admission == "continuous" or self._n_active == 0
+        )
+        if may_admit:
+            now = time.monotonic()
+            for slot_id in free[: min(len(self._queue),
+                                      self._admit_cap)]:
+                p = self._pop_next_locked()
+                self._count -= 1
+                if p.preempted:
+                    self._preempt_resumed += 1
+                p.t_taken = now  # queue_wait phase ends here
+                slot = _Slot(
+                    p, next(self._gens), p.payload,
+                    self._default_max_new,
                 )
-                if may_admit:
-                    now = time.monotonic()
-                    for slot_id in free[: min(len(self._queue),
-                                              self._admit_cap)]:
-                        p = self._pop_next_locked()
-                        self._count -= 1
-                        if p.preempted:
-                            self._preempt_resumed += 1
-                        p.t_taken = now  # queue_wait phase ends here
-                        slot = _Slot(
-                            p, next(self._gens), p.payload,
-                            self._default_max_new,
-                        )
-                        if self._chunked:
-                            slot.prefilling = True
-                            if self._pool is not None:
-                                # Lock order _cv -> pool (never reversed);
-                                # the match pins its chain until the
-                                # gather chunk dispatches. A resumed
-                                # stream matches on its FULL effective
-                                # prompt (original + resume tokens).
-                                m = self._pool.match(slot.full_prompt)
-                                slot.chain = m
-                                slot.cached_len = m.cached_len
-                                metrics.prefix_lookups.inc()
-                                if m.cached_len:
-                                    metrics.prefix_hits.inc()
-                                    metrics.prefix_tokens_saved.inc(
-                                        m.cached_len
-                                    )
-                            slot.chunk_pos = slot.cached_len
-                        else:
-                            # Prefill's first sampled token (resumed
-                            # tokens are pre-seeded, not dispatched).
-                            slot.n_dispatched = len(slot.tokens) + 1
-                        if self._spec_k:
-                            slot.spec = SlotSpec(self._spec_cfg)
-                            slot.prompt_ids = [
-                                int(t) for t in p.payload["input_ids"]
-                            ]
-                        slot.slot_id = slot_id
-                        self._slots[slot_id] = slot
-                        self._n_active += 1
-                        admissions.append((slot_id, slot))
-                    metrics.queue_depth.set(self._count)
-                    metrics.slots_active.set(self._n_active)
-                chunk_rows = None
                 if self._chunked:
-                    planned = []
-                    for i, s in enumerate(self._slots):
-                        if s is None or not s.prefilling:
-                            continue
-                        if len(planned) >= self._admit_cap:
-                            break
-                        start = s.chunk_pos
-                        n = min(self._chunk_size, s.admit_len - start)
-                        s.chunk_pos = start + n
-                        final = s.chunk_pos >= s.admit_len
-                        first = start == s.cached_len
-                        if final:
-                            s.prefilling = False
-                            # First token rides the final chunk (resumed
-                            # tokens are pre-seeded, not dispatched).
-                            s.n_dispatched = len(s.tokens) + 1
-                        planned.append(
-                            (i, s, start, n, first, final)
-                        )
-                    if planned:
-                        chunk_rows = planned
-                verify = None
-                spec_plain: set[int] = set()
+                    slot.prefilling = True
+                    if self._pool is not None:
+                        # Lock order _cv -> pool (never reversed);
+                        # the match pins its chain until the
+                        # gather chunk dispatches. A resumed
+                        # stream matches on its FULL effective
+                        # prompt (original + resume tokens).
+                        m = self._pool.match(slot.full_prompt)
+                        slot.chain = m
+                        slot.cached_len = m.cached_len
+                        metrics.prefix_lookups.inc()
+                        if m.cached_len:
+                            metrics.prefix_hits.inc()
+                            metrics.prefix_tokens_saved.inc(
+                                m.cached_len
+                            )
+                    slot.chunk_pos = slot.cached_len
+                else:
+                    # Prefill's first sampled token (resumed
+                    # tokens are pre-seeded, not dispatched).
+                    slot.n_dispatched = len(slot.tokens) + 1
                 if self._spec_k:
-                    # One verify batch over every speculating slot.
-                    # Drafting happens here under _cv (the drafter is a
-                    # pure function of slot state). A slot whose draft
-                    # comes up EMPTY takes a plain (pipelined) decode row
-                    # this step instead — a k=0 verify would just be a
-                    # non-overlapped decode step — and the missed
-                    # opportunity feeds the acceptance EMA so undraftable
-                    # streams back off entirely WITHOUT ever paying the
-                    # drain stall: only a slot with a draft actually
-                    # worth verifying waits for its in-flight plain
-                    # steps to land (and re-drafts against the full
-                    # history once they have).
-                    vrows = []
-                    for i, s in enumerate(self._slots):
-                        if (
-                            not self._steppable(s)
-                            or s.spec is None
-                            or not s.spec.speculating
-                            # Prefill token still in flight: drafts anchor
-                            # on the GENERATED history (the match that
-                            # matters most appears right after the first
-                            # token), so don't burn the step on a
-                            # prompt-only draft — wait the one fetch.
-                            or not s.tokens
-                        ):
-                            continue
-                        # Never draft past the generation budget (the
-                        # verified token always emits, so at most
-                        # max_new - emitted - 1 drafts can matter) or
-                        # the cache (positions length..length+d must
-                        # stay writable).
-                        cap = min(
-                            s.max_new - len(s.tokens) - 1,
-                            self._cache_len - 1 - s.length,
-                        )
-                        d = s.spec.propose(s.prompt_ids + s.tokens, cap)
-                        if not d:
-                            flip = s.spec.record(0, 0)
-                            if flip is not None:
-                                self._plan_events.append((
-                                    s.pending.request_id, i, flip,
-                                    s.spec.ema,
-                                ))
-                            spec_plain.add(i)
-                            continue
-                        if s.n_dispatched != len(s.tokens):
-                            # Draft in hand but plain steps still in
-                            # flight: stall one pass to drain (history
-                            # is missing the in-flight tokens, so the
-                            # draft re-proposes once they land).
-                            continue
-                        vrows.append((i, s, d))
-                    if vrows:
-                        n = len(self._slots)
-                        drafts = [[0] * self._spec_k for _ in range(n)]
-                        vlengths = [0] * n
-                        n_input = [0] * n
-                        vtemps = [0.0] * n
-                        vseeds = [0] * n
-                        vtags = []
-                        for i, s, d in vrows:
-                            drafts[i][: len(d)] = [int(t) for t in d]
-                            vlengths[i] = s.length
-                            n_input[i] = len(d) + 1
-                            vtemps[i] = s.temperature
-                            vseeds[i] = s.seed
-                            s.draft = d
-                            s.verifying = True  # length advances at FETCH
-                            vtags.append((i, s.gen))
-                        verify = (
-                            drafts, vlengths, n_input, vtemps, vseeds, vtags
-                        )
-                step = None
-                rows = [
-                    (i, s) for i, s in enumerate(self._slots)
-                    if self._steppable(s)
-                    # Spec-mode slots route through verify (a probe-due
-                    # backed-off slot drains here too) unless this step's
-                    # draft came up empty; backed-off and empty-draft
-                    # slots ride the pipelined plain path.
-                    and (
-                        s.spec is None
-                        or not s.spec.speculating
-                        or i in spec_plain
-                    )
-                ]
-                if rows:
-                    n = len(self._slots)
-                    lengths = [0] * n
-                    active = [False] * n
-                    temps = [0.0] * n
-                    seeds = [0] * n
-                    tags = []
-                    for i, s in rows:
-                        lengths[i] = s.length
-                        active[i] = True
-                        temps[i] = s.temperature
-                        seeds[i] = s.seed
-                        s.length += 1         # advances at dispatch: steps
-                        s.n_dispatched += 1   # pipeline without the fetch
-                        tags.append((i, s.gen))
-                        if s.spec is not None:
-                            s.spec.note_plain_step()  # probe clock
-                    step = (lengths, active, temps, seeds, tags)
-                if (admissions or chunk_rows or step or verify or adopts
-                        or stream_rows or park_rows):
-                    return ("work", admissions, chunk_rows, step, verify,
-                            adopts, stream_rows, park_rows)
-                self._cv.wait()
+                    slot.spec = SlotSpec(self._spec_cfg)
+                    slot.prompt_ids = [
+                        int(t) for t in p.payload["input_ids"]
+                    ]
+                slot.slot_id = slot_id
+                self._slots[slot_id] = slot
+                self._n_active += 1
+                admissions.append((slot_id, slot))
+            metrics.queue_depth.set(self._count)
+            metrics.slots_active.set(self._n_active)
+        chunk_rows = None
+        if self._chunked:
+            planned = []
+            for i, s in enumerate(self._slots):
+                if s is None or not s.prefilling:
+                    continue
+                if len(planned) >= self._admit_cap:
+                    break
+                start = s.chunk_pos
+                n = min(self._chunk_size, s.admit_len - start)
+                s.chunk_pos = start + n
+                final = s.chunk_pos >= s.admit_len
+                first = start == s.cached_len
+                if final:
+                    s.prefilling = False
+                    # First token rides the final chunk (resumed
+                    # tokens are pre-seeded, not dispatched).
+                    s.n_dispatched = len(s.tokens) + 1
+                planned.append(
+                    (i, s, start, n, first, final)
+                )
+            if planned:
+                chunk_rows = planned
+        verify = None
+        spec_plain: set[int] = set()
+        if self._spec_k:
+            # One verify batch over every speculating slot.
+            # Drafting happens here under _cv (the drafter is a
+            # pure function of slot state). A slot whose draft
+            # comes up EMPTY takes a plain (pipelined) decode row
+            # this step instead — a k=0 verify would just be a
+            # non-overlapped decode step — and the missed
+            # opportunity feeds the acceptance EMA so undraftable
+            # streams back off entirely WITHOUT ever paying the
+            # drain stall: only a slot with a draft actually
+            # worth verifying waits for its in-flight plain
+            # steps to land (and re-drafts against the full
+            # history once they have).
+            vrows = []
+            for i, s in enumerate(self._slots):
+                if (
+                    not self._steppable(s)
+                    or s.spec is None
+                    or not s.spec.speculating
+                    # Prefill token still in flight: drafts anchor
+                    # on the GENERATED history (the match that
+                    # matters most appears right after the first
+                    # token), so don't burn the step on a
+                    # prompt-only draft — wait the one fetch.
+                    or not s.tokens
+                ):
+                    continue
+                # Never draft past the generation budget (the
+                # verified token always emits, so at most
+                # max_new - emitted - 1 drafts can matter) or
+                # the cache (positions length..length+d must
+                # stay writable).
+                cap = min(
+                    s.max_new - len(s.tokens) - 1,
+                    self._cache_len - 1 - s.length,
+                )
+                d = s.spec.propose(s.prompt_ids + s.tokens, cap)
+                if not d:
+                    flip = s.spec.record(0, 0)
+                    if flip is not None:
+                        self._plan_events.append((
+                            s.pending.request_id, i, flip,
+                            s.spec.ema,
+                        ))
+                    spec_plain.add(i)
+                    continue
+                if s.n_dispatched != len(s.tokens):
+                    # Draft in hand but plain steps still in
+                    # flight: stall one pass to drain (history
+                    # is missing the in-flight tokens, so the
+                    # draft re-proposes once they land).
+                    continue
+                vrows.append((i, s, d))
+            if vrows:
+                n = len(self._slots)
+                drafts = [[0] * self._spec_k for _ in range(n)]
+                vlengths = [0] * n
+                n_input = [0] * n
+                vtemps = [0.0] * n
+                vseeds = [0] * n
+                vtags = []
+                for i, s, d in vrows:
+                    drafts[i][: len(d)] = [int(t) for t in d]
+                    vlengths[i] = s.length
+                    n_input[i] = len(d) + 1
+                    vtemps[i] = s.temperature
+                    vseeds[i] = s.seed
+                    s.draft = d
+                    s.verifying = True  # length advances at FETCH
+                    vtags.append((i, s.gen))
+                verify = (
+                    drafts, vlengths, n_input, vtemps, vseeds, vtags
+                )
+        step = None
+        rows = [
+            (i, s) for i, s in enumerate(self._slots)
+            if self._steppable(s)
+            # Spec-mode slots route through verify (a probe-due
+            # backed-off slot drains here too) unless this step's
+            # draft came up empty; backed-off and empty-draft
+            # slots ride the pipelined plain path.
+            and (
+                s.spec is None
+                or not s.spec.speculating
+                or i in spec_plain
+            )
+        ]
+        if rows:
+            n = len(self._slots)
+            lengths = [0] * n
+            active = [False] * n
+            temps = [0.0] * n
+            seeds = [0] * n
+            tags = []
+            for i, s in rows:
+                lengths[i] = s.length
+                active[i] = True
+                temps[i] = s.temperature
+                seeds[i] = s.seed
+                s.length += 1         # advances at dispatch: steps
+                s.n_dispatched += 1   # pipeline without the fetch
+                tags.append((i, s.gen))
+                if s.spec is not None:
+                    s.spec.note_plain_step()  # probe clock
+            step = (lengths, active, temps, seeds, tags)
+        if (admissions or chunk_rows or step or verify or adopts
+                or stream_rows or park_rows):
+            plan.set(admitted=len(admissions), rows=len(rows),
+                     queued=self._count)
+            return ("work", admissions, chunk_rows, step, verify,
+                    adopts, stream_rows, park_rows)
+        return None
 
     def _fail_slots(self, tagged: list[tuple[int, int]],
                     exc: BaseException) -> None:
@@ -1838,7 +1852,7 @@ class ContinuousBatcher:
         self.recorder.trigger("engine_failure")
 
     def _loop(self):
-        engine = self._engine
+        engine, tracer = self._engine, self.tracer
         while True:
             work = self._take_work()
             if work is None:
@@ -1963,18 +1977,23 @@ class ContinuousBatcher:
                                 slot=i, cached_tokens=s.cached_len,
                             )
             if admissions and not self._chunked:
-                self._inflight_sem.acquire()
+                with tracer.span("batcher.sem_wait", "serve", kind="prefill"):
+                    self._inflight_sem.acquire()
                 tags = [(i, s.gen) for i, s in admissions]
                 try:
-                    handle = engine.prefill([
-                        {
-                            "slot": i,
-                            "input_ids": s.full_prompt,
-                            "temperature": s.temperature,
-                            "seed": s.seed,
-                        }
-                        for i, s in admissions
-                    ])
+                    with tracer.span("engine.prefill_dispatch", "serve",
+                                     rows=len(admissions)) as span:
+                        handle = engine.prefill([
+                            {
+                                "slot": i,
+                                "input_ids": s.full_prompt,
+                                "temperature": s.temperature,
+                                "seed": s.seed,
+                            }
+                            for i, s in admissions
+                        ])
+                        # ("prefill", tier, bucket) on a grid engine
+                        span.set(bucket=getattr(handle, "key", (0,))[-1])
                 except Exception as e:  # noqa: BLE001 — fail the rows, not the server
                     # Fail ONLY the admitted rows; the step planned below
                     # still dispatches (its bookkeeping already advanced,
@@ -1989,25 +2008,28 @@ class ContinuousBatcher:
                         ("prefill", tags, handle, time.monotonic())
                     )
             if chunk_rows:
-                self._inflight_sem.acquire()
+                with tracer.span("batcher.sem_wait", "serve", kind="chunk"):
+                    self._inflight_sem.acquire()
                 tags = [(i, s.gen) for i, s, *_ in chunk_rows]
                 try:
-                    handle = engine.prefill_chunks([
-                        {
-                            "slot": i,
-                            "input_ids": s.full_prompt,
-                            "start": start,
-                            "n_tokens": n,
-                            "length": s.admit_len,
-                            "chain": (
-                                s.chain.blocks
-                                if first and s.chain is not None else ()
-                            ),
-                            "temperature": s.temperature,
-                            "seed": s.seed,
-                        }
-                        for i, s, start, n, first, final in chunk_rows
-                    ])
+                    with tracer.span("engine.chunk_dispatch", "serve",
+                                     rows=len(chunk_rows)):
+                        handle = engine.prefill_chunks([
+                            {
+                                "slot": i,
+                                "input_ids": s.full_prompt,
+                                "start": start,
+                                "n_tokens": n,
+                                "length": s.admit_len,
+                                "chain": (
+                                    s.chain.blocks
+                                    if first and s.chain is not None else ()
+                                ),
+                                "temperature": s.temperature,
+                                "seed": s.seed,
+                            }
+                            for i, s, start, n, first, final in chunk_rows
+                        ])
                 except Exception as e:  # noqa: BLE001
                     self._inflight_sem.release()
                     self._fail_slots(tags, e)
@@ -2062,11 +2084,14 @@ class ContinuousBatcher:
                 # rows are parked (verifying=True) and would wedge if a
                 # decode failure's `continue` skipped their dispatch.
                 drafts, vlengths, n_input, vtemps, vseeds, vtags = verify
-                self._inflight_sem.acquire()
+                with tracer.span("batcher.sem_wait", "serve", kind="verify"):
+                    self._inflight_sem.acquire()
                 try:
-                    handle = engine.verify(
-                        drafts, vlengths, n_input, vtemps, vseeds
-                    )
+                    with tracer.span("engine.verify_dispatch", "serve",
+                                     rows=len(vtags)):
+                        handle = engine.verify(
+                            drafts, vlengths, n_input, vtemps, vseeds
+                        )
                 except Exception as e:  # noqa: BLE001
                     self._inflight_sem.release()
                     self._fail_slots(vtags, e)
@@ -2092,9 +2117,12 @@ class ContinuousBatcher:
                     except Exception as e:  # noqa: BLE001 — injected: fail the step's slots
                         self._fail_slots(tags, e)
                         continue
-                self._inflight_sem.acquire()
+                with tracer.span("batcher.sem_wait", "serve", kind="decode"):
+                    self._inflight_sem.acquire()
                 try:
-                    handle = engine.decode(lengths, active, temps, seeds)
+                    with tracer.span("engine.decode_dispatch", "serve",
+                                     rows=len(tags), slots=len(active)):
+                        handle = engine.decode(lengths, active, temps, seeds)
                 except Exception as e:  # noqa: BLE001
                     self._inflight_sem.release()
                     self._fail_slots(tags, e)
@@ -2251,14 +2279,15 @@ class ContinuousBatcher:
                 )
 
     def _completion_loop(self):
-        engine, metrics = self._engine, self.metrics
+        engine, metrics, tracer = self._engine, self.metrics, self.tracer
         while True:
             item = self._completion.get()
             if item is None:
                 return
             kind, tags, handle, t_disp = item
             try:
-                tok = engine.fetch_step(handle)
+                with tracer.span("engine.fetch", "serve", kind=kind):
+                    tok = engine.fetch_step(handle)
             except Exception as e:  # noqa: BLE001
                 self._fail_slots(
                     [(t[0], t[1]) for t in tags] if kind == "chunk"
@@ -2270,187 +2299,185 @@ class ContinuousBatcher:
                 self._inflight_sem.release()
                 continue
             t_got = getattr(handle, "t_got", 0.0) or time.monotonic()
-            finished: list[_Slot] = []
-            itls: list[float] = []
-            ttfts: list[float] = []
-            n_tokens = 0
-            slot_steps = 0
-            drafted = accepted = v_rejects = 0
-            spec_events: list[tuple[str, int, str, float]] = []
-            with self._cv:
-                if kind == "prefill":
-                    for r, (slot_id, gen) in enumerate(tags):
-                        s = self._slots[slot_id]
-                        if s is None or s.gen != gen:
-                            continue
-                        s.t_first = t_got
-                        ttfts.append(t_got - s.pending.t_enqueue)
-                        n_tokens += 1
-                        self._append_token(
-                            slot_id, s, int(tok[r]), t_got, finished
-                        )
-                elif kind == "chunk":
-                    # Only rows whose chunk completed the prompt carry a
-                    # sampled first token; mid-prompt rows' lanes are
-                    # garbage by design and nothing reads them.
-                    for r, (slot_id, gen, final) in enumerate(tags):
-                        if not final:
-                            continue
-                        s = self._slots[slot_id]
-                        if s is None or s.gen != gen:
-                            continue
-                        s.t_first = t_got
-                        ttfts.append(t_got - s.pending.t_enqueue)
-                        n_tokens += 1
-                        self._append_token(
-                            slot_id, s, int(tok[r]), t_got, finished
-                        )
-                elif kind == "verify":
-                    # tok is the [slots, k+1] verified-token matrix. The
-                    # acceptance rule (longest exact-match prefix) is
-                    # recomputed host-side from the slot's own draft; it
-                    # agrees with the device's cumprod-match by
-                    # construction, so the device last_token stays
-                    # coherent without a round-trip.
-                    for slot_id, gen in tags:
-                        s = self._slots[slot_id]
-                        if s is None or s.gen != gen:
-                            continue
-                        slot_steps += 1
-                        s.verifying = False
-                        d = s.draft or []
-                        s.draft = None
-                        m = 0
-                        for t in d:
-                            if int(tok[slot_id, m]) == int(t):
-                                m += 1
-                            else:
-                                break
-                        drafted += len(d)
-                        accepted += m
-                        if m < len(d):
-                            v_rejects += 1
-                        flip = s.spec.record(len(d), m)
-                        if flip is not None:
-                            spec_events.append((
-                                s.pending.request_id, slot_id, flip,
-                                s.spec.ema,
-                            ))
-                        # Rollback is free: host length advances only past
-                        # the accepted run; the k-m rejected K/V entries
-                        # sit beyond `length`, masked dead, and the slot's
-                        # next real tokens overwrite them.
-                        s.length += m + 1
-                        # ITL stays per TOKEN: the emitted run splits the
-                        # step's wall interval into m+1 equal samples.
-                        dt = (t_got - s.t_last_tok) / (m + 1)
-                        for j in range(m + 1):
-                            itls.append(dt)
-                            n_tokens += 1
-                            self._append_token(
-                                slot_id, s, int(tok[slot_id, j]), t_got,
-                                finished,
-                            )
-                            if self._slots[slot_id] is not s:
-                                break  # eos/max_new mid-run: surplus drops
-                        if self._slots[slot_id] is s:
-                            s.n_dispatched = len(s.tokens)
-                else:
-                    for slot_id, gen in tags:
-                        s = self._slots[slot_id]
-                        if s is None or s.gen != gen:
-                            continue
-                        slot_steps += 1
-                        itls.append(t_got - s.t_last_tok)
-                        n_tokens += 1
-                        self._append_token(
-                            slot_id, s, int(tok[slot_id]), t_got, finished
-                        )
-                if kind in ("decode", "verify"):
-                    # tokens_per_step is per SLOT-step (a decode/verify
-                    # execution of one live slot lane), so a plain engine
-                    # reads exactly 1.0 and the ratio isolates the
-                    # speculation win from batch occupancy.
-                    self._steps += slot_steps
-                    self._tokens_emitted += n_tokens
-                    if kind == "verify":
-                        self._spec_drafted += drafted
-                        self._spec_accepted += accepted
-                        self._spec_rejects += v_rejects
-                self._n_inflight -= 1
-                metrics.in_flight.set(self._n_inflight)
-                metrics.slots_active.set(self._n_active)
-                self._cv.notify_all()
-            self._inflight_sem.release()
-            # Metric recording outside _cv (instruments self-lock), before
-            # futures resolve so a joiner sees its own samples.
-            if kind == "decode":
-                metrics.decode_steps.inc()
-                if self.tracer.enabled:
-                    self.tracer.record(
-                        "decode_step", t_disp, t_got, cat="serve",
-                        args={"rows": len(itls)},
-                    )
-                if itls:
-                    metrics.observe_phase_batch(
-                        "decode_step", itls, self._layout, t_got
-                    )
-                    for dt in itls:
-                        metrics.itl.observe(dt)
-            elif kind == "verify":
-                # Same per-token taxonomy as decode_step: the itls list
-                # already carries one sample per EMITTED token (each an
-                # equal split of its slot's step interval), so phase-sum
-                # == wall still holds and ITL percentiles show the
-                # speculation win directly.
-                if self.tracer.enabled:
-                    self.tracer.record(
-                        "verify_step", t_disp, t_got, cat="serve",
-                        args={"rows": len(tags), "drafted": drafted,
-                              "accepted": accepted},
-                    )
-                if itls:
-                    metrics.observe_phase_batch(
-                        "verify_step", itls, self._layout, t_got
-                    )
-                    for dt in itls:
-                        metrics.itl.observe(dt)
-                if drafted:
-                    metrics.draft_tokens.inc(drafted)
-                    metrics.accepted_tokens.inc(accepted)
-                    if metrics.windowed:
-                        metrics.drafted_w.add(float(drafted), t_got)
-                        metrics.accepted_w.add(float(accepted), t_got)
-                if v_rejects:
-                    metrics.spec_rejects.inc(v_rejects)
-                for req_id, slot_id, flip, ema in spec_events:
-                    self.recorder.record(
-                        "spec_backoff", req_id, slot=slot_id,
-                        engaged=(flip == "engage"),
-                        acceptance=round(ema, 4),
+            with tracer.span("batcher.deliver", "serve") as span:
+                span.set(**self._deliver_step(kind, tags, tok, t_disp, t_got))
+
+    def _deliver_step(self, kind, tags, tok, t_disp, t_got) -> dict:
+        """Completion thread: hand one fetched step's tokens to their slots
+        (under ``_cv``), then record metrics and resolve finished futures
+        outside it. Returns the counts the ``batcher.deliver`` span
+        carries."""
+        metrics = self.metrics
+        finished: list[_Slot] = []
+        itls: list[float] = []
+        ttfts: list[float] = []
+        n_tokens = 0
+        slot_steps = 0
+        drafted = accepted = v_rejects = 0
+        spec_events: list[tuple[str, int, str, float]] = []
+        with self._cv:
+            if kind == "prefill":
+                for r, (slot_id, gen) in enumerate(tags):
+                    s = self._slots[slot_id]
+                    if s is None or s.gen != gen:
+                        continue
+                    s.t_first = t_got
+                    ttfts.append(t_got - s.pending.t_enqueue)
+                    n_tokens += 1
+                    self._append_token(
+                        slot_id, s, int(tok[r]), t_got, finished
                     )
             elif kind == "chunk":
-                # Batch-level span/phase twin of decode_step: one sample
-                # per chunk dispatch. Per-request phases stay the
-                # contiguous queue_wait -> prefill -> decode (a request's
-                # prefill span covers all its chunks), so phase-sum ==
-                # wall latency still holds by construction.
-                metrics.observe_phase_batch(
-                    "prefill_chunk", [t_got - t_disp], self._layout, t_got
-                )
-                if self.tracer.enabled:
-                    self.tracer.record(
-                        "prefill_chunk", t_disp, t_got, cat="serve",
-                        args={"rows": len(tags)},
+                # Only rows whose chunk completed the prompt carry a
+                # sampled first token; mid-prompt rows' lanes are
+                # garbage by design and nothing reads them.
+                for r, (slot_id, gen, final) in enumerate(tags):
+                    if not final:
+                        continue
+                    s = self._slots[slot_id]
+                    if s is None or s.gen != gen:
+                        continue
+                    s.t_first = t_got
+                    ttfts.append(t_got - s.pending.t_enqueue)
+                    n_tokens += 1
+                    self._append_token(
+                        slot_id, s, int(tok[r]), t_got, finished
                     )
-            for dt in ttfts:
-                metrics.ttft.observe(dt)
-            if n_tokens:
-                metrics.tokens.inc(n_tokens)
+            elif kind == "verify":
+                # tok is the [slots, k+1] verified-token matrix. The
+                # acceptance rule (longest exact-match prefix) is
+                # recomputed host-side from the slot's own draft; it
+                # agrees with the device's cumprod-match by
+                # construction, so the device last_token stays
+                # coherent without a round-trip.
+                for slot_id, gen in tags:
+                    s = self._slots[slot_id]
+                    if s is None or s.gen != gen:
+                        continue
+                    slot_steps += 1
+                    s.verifying = False
+                    d = s.draft or []
+                    s.draft = None
+                    m = 0
+                    for t in d:
+                        if int(tok[slot_id, m]) == int(t):
+                            m += 1
+                        else:
+                            break
+                    drafted += len(d)
+                    accepted += m
+                    if m < len(d):
+                        v_rejects += 1
+                    flip = s.spec.record(len(d), m)
+                    if flip is not None:
+                        spec_events.append((
+                            s.pending.request_id, slot_id, flip,
+                            s.spec.ema,
+                        ))
+                    # Rollback is free: host length advances only past
+                    # the accepted run; the k-m rejected K/V entries
+                    # sit beyond `length`, masked dead, and the slot's
+                    # next real tokens overwrite them.
+                    s.length += m + 1
+                    # ITL stays per TOKEN: the emitted run splits the
+                    # step's wall interval into m+1 equal samples.
+                    dt = (t_got - s.t_last_tok) / (m + 1)
+                    for j in range(m + 1):
+                        itls.append(dt)
+                        n_tokens += 1
+                        self._append_token(
+                            slot_id, s, int(tok[slot_id, j]), t_got,
+                            finished,
+                        )
+                        if self._slots[slot_id] is not s:
+                            break  # eos/max_new mid-run: surplus drops
+                    if self._slots[slot_id] is s:
+                        s.n_dispatched = len(s.tokens)
+            else:
+                for slot_id, gen in tags:
+                    s = self._slots[slot_id]
+                    if s is None or s.gen != gen:
+                        continue
+                    slot_steps += 1
+                    itls.append(t_got - s.t_last_tok)
+                    n_tokens += 1
+                    self._append_token(
+                        slot_id, s, int(tok[slot_id]), t_got, finished
+                    )
+            if kind in ("decode", "verify"):
+                # tokens_per_step is per SLOT-step (a decode/verify
+                # execution of one live slot lane), so a plain engine
+                # reads exactly 1.0 and the ratio isolates the
+                # speculation win from batch occupancy.
+                self._steps += slot_steps
+                self._tokens_emitted += n_tokens
+                if kind == "verify":
+                    self._spec_drafted += drafted
+                    self._spec_accepted += accepted
+                    self._spec_rejects += v_rejects
+            self._n_inflight -= 1
+            metrics.in_flight.set(self._n_inflight)
+            metrics.slots_active.set(self._n_active)
+            self._cv.notify_all()
+        self._inflight_sem.release()
+        # Metric recording outside _cv (instruments self-lock), before
+        # futures resolve so a joiner sees its own samples.
+        if kind == "decode":
+            metrics.decode_steps.inc()
+            if itls:
+                metrics.observe_phase_batch(
+                    "decode_step", itls, self._layout, t_got
+                )
+                for dt in itls:
+                    metrics.itl.observe(dt)
+        elif kind == "verify":
+            # Same per-token taxonomy as decode_step: the itls list
+            # already carries one sample per EMITTED token (each an
+            # equal split of its slot's step interval), so phase-sum
+            # == wall still holds and ITL percentiles show the
+            # speculation win directly.
+            if itls:
+                metrics.observe_phase_batch(
+                    "verify_step", itls, self._layout, t_got
+                )
+                for dt in itls:
+                    metrics.itl.observe(dt)
+            if drafted:
+                metrics.draft_tokens.inc(drafted)
+                metrics.accepted_tokens.inc(accepted)
                 if metrics.windowed:
-                    metrics.tokens_w.add(float(n_tokens), t_got)
-            if finished:
-                self._resolve(finished, t_got)
+                    metrics.drafted_w.add(float(drafted), t_got)
+                    metrics.accepted_w.add(float(accepted), t_got)
+            if v_rejects:
+                metrics.spec_rejects.inc(v_rejects)
+            for req_id, slot_id, flip, ema in spec_events:
+                self.recorder.record(
+                    "spec_backoff", req_id, slot=slot_id,
+                    engaged=(flip == "engage"),
+                    acceptance=round(ema, 4),
+                )
+        elif kind == "chunk":
+            # Batch-level phase twin of decode_step: one sample per
+            # chunk dispatch (its span is engine.chunk_dispatch).
+            # Per-request phases stay the contiguous queue_wait ->
+            # prefill -> decode (a request's prefill span covers all
+            # its chunks), so phase-sum == wall latency still holds by
+            # construction.
+            metrics.observe_phase_batch(
+                "prefill_chunk", [t_got - t_disp], self._layout, t_got
+            )
+        for dt in ttfts:
+            metrics.ttft.observe(dt)
+        if n_tokens:
+            metrics.tokens.inc(n_tokens)
+            if metrics.windowed:
+                metrics.tokens_w.add(float(n_tokens), t_got)
+        if finished:
+            self._resolve(finished, t_got)
+        counts = {"tokens": n_tokens, "finished": len(finished)}
+        if kind == "verify":
+            counts.update(drafted=drafted, accepted=accepted)
+        return counts
 
     def close(self, drain: bool = True, join_timeout_s: float = 30.0) -> None:
         """Stop the decode loop. ``drain=True`` admits + finishes what's

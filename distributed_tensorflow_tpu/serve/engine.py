@@ -766,8 +766,9 @@ def _make_causal_prefill(model):
                 fresh.astype(cache.dtype), mode="drop"
             )
 
-        ck = scatter(ck, k)
-        cv = scatter(cv, v)
+        with jax.named_scope("kv_write"):
+            ck = scatter(ck, k)
+            cv = scatter(cv, v)
         last = last.at[slots].set(tok, mode="drop")
         return ck, cv, last, tok
 

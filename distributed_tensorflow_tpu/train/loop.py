@@ -123,7 +123,12 @@ def fit(
     log cadence (the only points the loop blocks on device values), plus
     ``checkpoint_save`` and ``eval`` spans — each carrying its ``step``
     correlation key. Disabled (the default) it is a no-op context manager
-    per call site, cheap enough to leave in the hot loop.
+    per call site, cheap enough to leave in the hot loop. Enabled, each
+    span is also a profiler annotation (obs/trace.py), and every dispatch
+    sits inside ``tracer.step("train", step)`` (a ``StepTraceAnnotation``),
+    so a capture (``--profile-dir`` with ``--trace-dir``) shows the loop's
+    phases on the host lines of the same trace as the device ops, grouped
+    by step.
 
     ``timeline`` (obs/fleet.py :class:`StepTimeline`) records every step's
     wall / host-wait / dispatch durations into windowed series and runs the
@@ -198,7 +203,10 @@ def fit(
         )
         t_iter = time.perf_counter()
         wait_s = 0.0
-        with tracer.span("dispatch", "train", step=step):
+        # The step marker groups a capture by step; the span inside it is
+        # the loop's own dispatch time. Both are no-ops on a disabled tracer.
+        with tracer.step("train", step), \
+                tracer.span("dispatch", "train", step=step):
             state, metrics = train_step(state, batch, rng)
         if poison and poison_step is None:
             # Injected nonfinite_loss: poison the METRIC (what the guard

@@ -289,53 +289,54 @@ def make_train_step(
         metrics = dict(metrics)
         metrics["loss"] = loss
 
-        for shard_axis in ("model", "pipeline", "expert"):
-            if shard_axis not in mesh.axis_names:
-                continue
-            # Param-sharded-axis grad contract (mirrors the seq contract
-            # below, but per-leaf; applies to tensor AND pipeline
-            # parallelism): forward psums over the axis (row-parallel TP
-            # outputs; the pipeline's last-stage output broadcast) transpose
-            # to psums (check_vma=False), so every grad path through the
-            # sharded branches carries one factor of t = |axis|. Sharded
-            # leaves hold their LOCAL slice's grad — scale it 1/t;
-            # replicated leaves hold t x their local partial — pmean sums
-            # the partials and removes the factor in one collective.
-            # Verified against unsharded models in tests/test_bert_tp.py
-            # and tests/test_pipeline.py.
-            t = mesh.shape[shard_axis]
+        with jax.named_scope("grad_reduce"):
+            for shard_axis in ("model", "pipeline", "expert"):
+                if shard_axis not in mesh.axis_names:
+                    continue
+                # Param-sharded-axis grad contract (mirrors the seq contract
+                # below, but per-leaf; applies to tensor AND pipeline
+                # parallelism): forward psums over the axis (row-parallel TP
+                # outputs; the pipeline's last-stage output broadcast) transpose
+                # to psums (check_vma=False), so every grad path through the
+                # sharded branches carries one factor of t = |axis|. Sharded
+                # leaves hold their LOCAL slice's grad — scale it 1/t;
+                # replicated leaves hold t x their local partial — pmean sums
+                # the partials and removes the factor in one collective.
+                # Verified against unsharded models in tests/test_bert_tp.py
+                # and tests/test_pipeline.py.
+                t = mesh.shape[shard_axis]
 
-            def _fix(g, spec, axis=shard_axis, t=t):
-                if axis in _spec_axes(spec):
-                    return g / t
-                return lax.pmean(g, axis)
+                def _fix(g, spec, axis=shard_axis, t=t):
+                    if axis in _spec_axes(spec):
+                        return g / t
+                    return lax.pmean(g, axis)
 
-            if param_specs is None:
-                grads = jax.tree.map(
-                    lambda g, axis=shard_axis: lax.pmean(g, axis), grads
-                )
-            else:
-                grads = jax.tree.map(_fix, grads, param_specs)
-        if "seq" in mesh.axis_names:
-            # Sequence-parallel contract: the loss_fn must return the
-            # *global* scalar on every seq shard (psum its numerator/
-            # denominator over "seq" — see models/bert.py). Under shard_map
-            # without replication tracking (check_vma=False), psum transposes
-            # to psum, so each shard's backward already carries the global
-            # cotangent and every param-grad path picks up exactly one factor
-            # of the ring size — whether the path crosses a loss psum
-            # (partitioned compute) or is shard-replicated (post-psum heads).
-            # pmean removes that uniform factor exactly; verified against the
-            # dense model in tests/test_bert.py.
-            grads = coll.pmean_tree(grads, "seq")
-        if dp_axes:
-            # THE sync point: one fused AllReduce over ICI replaces the
-            # reference's entire ps round-trip / NCCL ring (SURVEY.md §3b/3d).
-            grads = coll.pmean_tree(grads, dp_axes)
-        if metric_axes:
-            metrics = coll.pmean_tree(metrics, metric_axes)
-            if model_state:
-                model_state = coll.pmean_tree(model_state, metric_axes)
+                if param_specs is None:
+                    grads = jax.tree.map(
+                        lambda g, axis=shard_axis: lax.pmean(g, axis), grads
+                    )
+                else:
+                    grads = jax.tree.map(_fix, grads, param_specs)
+            if "seq" in mesh.axis_names:
+                # Sequence-parallel contract: the loss_fn must return the
+                # *global* scalar on every seq shard (psum its numerator/
+                # denominator over "seq" — see models/bert.py). Under shard_map
+                # without replication tracking (check_vma=False), psum transposes
+                # to psum, so each shard's backward already carries the global
+                # cotangent and every param-grad path picks up exactly one factor
+                # of the ring size — whether the path crosses a loss psum
+                # (partitioned compute) or is shard-replicated (post-psum heads).
+                # pmean removes that uniform factor exactly; verified against the
+                # dense model in tests/test_bert.py.
+                grads = coll.pmean_tree(grads, "seq")
+            if dp_axes:
+                # THE sync point: one fused AllReduce over ICI replaces the
+                # reference's entire ps round-trip / NCCL ring (SURVEY.md §3b/3d).
+                grads = coll.pmean_tree(grads, dp_axes)
+            if metric_axes:
+                metrics = coll.pmean_tree(metrics, metric_axes)
+                if model_state:
+                    model_state = coll.pmean_tree(model_state, metric_axes)
 
         new_buffer, new_index = state.grad_buffer, state.buffer_index
         if mode == "stale":
@@ -359,35 +360,39 @@ def make_train_step(
             grads = apply_grads
             metrics["staleness"] = jnp.asarray(staleness, jnp.float32)
 
-        shard_axes = tuple(
-            a for a in ("model", "pipeline", "expert") if a in mesh.axis_names
-        )
-        if param_specs is not None and shard_axes:
-            # Sharded leaves hold only this shard's slice: psum their
-            # squared norms over the sharding axes so grad_norm is the
-            # GLOBAL norm on every shard (out_specs=P() would otherwise
-            # surface one shard's partial value).
-            def _sq(g, spec):
-                s = jnp.sum(jnp.square(g.astype(jnp.float32)))
-                axes = _spec_axes(spec)
-                for ax in shard_axes:
-                    if ax in axes:
-                        s = lax.psum(s, ax)
-                return s
+        with jax.named_scope("clip"):
+            shard_axes = tuple(
+                a for a in ("model", "pipeline", "expert") if a in mesh.axis_names
+            )
+            if param_specs is not None and shard_axes:
+                # Sharded leaves hold only this shard's slice: psum their
+                # squared norms over the sharding axes so grad_norm is the
+                # GLOBAL norm on every shard (out_specs=P() would otherwise
+                # surface one shard's partial value).
+                def _sq(g, spec):
+                    s = jnp.sum(jnp.square(g.astype(jnp.float32)))
+                    axes = _spec_axes(spec)
+                    for ax in shard_axes:
+                        if ax in axes:
+                            s = lax.psum(s, ax)
+                    return s
 
-            total = sum(jax.tree.leaves(jax.tree.map(_sq, grads, param_specs)))
-            grad_norm = jnp.sqrt(total)
-        else:
-            grad_norm = coll.global_norm(grads)
-        if clip_norm > 0:
-            # Spec-aware global-norm clipping (see the docstring): one scale,
-            # identical on every shard, from the true global norm. Same
-            # trust-ratio form as optax.clip_by_global_norm.
-            scale = clip_norm / jnp.maximum(grad_norm, clip_norm)
-            grads = jax.tree.map(lambda g: g * scale.astype(g.dtype), grads)
+                total = sum(jax.tree.leaves(jax.tree.map(_sq, grads, param_specs)))
+                grad_norm = jnp.sqrt(total)
+            else:
+                grad_norm = coll.global_norm(grads)
+            if clip_norm > 0:
+                # Spec-aware global-norm clipping (see the docstring): one scale,
+                # identical on every shard, from the true global norm. Same
+                # trust-ratio form as optax.clip_by_global_norm.
+                scale = clip_norm / jnp.maximum(grad_norm, clip_norm)
+                grads = jax.tree.map(lambda g: g * scale.astype(g.dtype), grads)
 
-        updates, opt_state = tx.update(grads, state.opt_state, state.params)
-        params = optax.apply_updates(state.params, updates)
+        with jax.named_scope("optimizer"):
+            updates, opt_state = tx.update(
+                grads, state.opt_state, state.params
+            )
+            params = optax.apply_updates(state.params, updates)
         metrics["grad_norm"] = grad_norm
 
         new_state = TrainState(

@@ -4,7 +4,7 @@ For each hot 1x1-conv shape from the b=128 ResNet-50 trace, times the
 Pallas dgrad/wgrad kernels with the RTT-cancelling on-device-loop harness
 from scripts/roofline.py and prints achieved GB/s against the chip's
 measured ~650 GB/s streaming ceiling. The XLA-side comparison numbers come
-from the in-step trace (scripts/hlo_breakdown.py) — do NOT time
+from the in-step trace (docs/PERF.md r3) — do NOT time
 vjp-of-conv inside an on-device loop here: the conv closure's operands
 become program constants baked into the executable at compile time
 (minutes per program; docs/PERF.md methodology note).

@@ -1,5 +1,7 @@
 """BERT with the Pallas flash-attention kernel ≡ dense BERT."""
 
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -37,3 +39,48 @@ def test_bert_flash_equals_dense():
         np.asarray(mlm_f), np.asarray(mlm_d), atol=1e-4
     )
     np.testing.assert_allclose(np.asarray(nsp_f), np.asarray(nsp_d), atol=1e-4)
+
+
+def test_train_step_carries_the_scope_names_the_metrics_select():
+    """The names benchmarks/layer_metrics/*.json select on the device are
+    pinned: each part of the step under its scope, the three flash kernels
+    apart, and the jitted step still called per_device_step."""
+    import optax
+
+    from distributed_tensorflow_tpu.data.text import bert_batch_specs
+    from distributed_tensorflow_tpu.models.bert import make_bert_pretraining_loss
+    from distributed_tensorflow_tpu.parallel.mesh import build_mesh
+    from distributed_tensorflow_tpu.train import (
+        create_train_state,
+        make_rng,
+        make_train_step,
+    )
+
+    model = BertForPreTraining(_cfg(attn_impl="flash"))
+    b, l = 8, 32
+    ids = jnp.zeros((b, l), jnp.int32)
+    batch = {
+        "input_ids": ids, "attention_mask": jnp.ones((b, l), bool),
+        "token_type_ids": ids, "mlm_targets": ids,
+        "nsp_label": jnp.zeros((b,), jnp.int32),
+    }
+    params = model.init(
+        jax.random.key(0), ids, batch["attention_mask"], ids, train=False
+    )["params"]
+    tx = optax.adamw(1e-4)
+    mesh = build_mesh({"data": -1})
+    step = make_train_step(
+        make_bert_pretraining_loss(model), tx, mesh,
+        batch_spec=bert_batch_specs(mesh), clip_norm=1.0,
+    )
+    text = step.lower(
+        create_train_state(params, tx, {}), batch, make_rng(0)
+    ).as_text(debug_info=True)
+    assert "@jit_per_device_step" in text
+    for scope in ("flash_fwd", "flash_dq", "flash_dkv", "mlm_head",
+                  "grad_reduce", "clip", "optimizer"):
+        assert re.search(rf'[/"]{scope}/', text), scope
+    # the kernels keep `attention` innermost (the TPU compiler names the
+    # custom call after it), and the head's backward carries its scope too
+    assert "/flash_dkv/attention/pallas_call" in text
+    assert re.search(r"transpose\(jvp\(BertForPreTraining\)\)/[^\"]*mlm_head/", text)

@@ -114,6 +114,36 @@ def test_flash_attention_compiles_for_v5e(one_chip, case):
     assert compiled.as_text().count("tpu_custom_call") >= 3
 
 
+@pytest.mark.parametrize("packing", ["flat", "bh"])
+def test_flash_custom_calls_keep_their_name_and_carry_their_scope(
+    one_chip, packing
+):
+    """benchmarks/layer_metrics/kernel.flash_ms selects the three kernels
+    by the instruction name the TPU compiler gives them (``%attention.N``,
+    after the innermost scope); kernel.flash_{fwd,dq,dkv}_ms tell them
+    apart by the scope in ``op_name``. Both must hold in the compiled
+    program, for both kernel families."""
+    import re
+
+    compiled = _compile_fwd_bwd(one_chip, (4, 512, 12, 64), jnp.bfloat16, packing)
+    calls = [
+        line.strip() for line in compiled.as_text().splitlines()
+        if "tpu_custom_call" in line and " custom-call(" in line
+    ]
+    old = re.compile(r"^%attention[.0-9]* = .* custom-call\(")
+    assert all(old.search(c) for c in calls), calls
+    scopes = sorted(
+        re.search(
+            r'op_name="[^"]*[/(](flash_\w+?)\)*/attention/pallas_call"', c
+        ).group(1)
+        for c in calls
+    )
+    # _compile_fwd_bwd runs the forward twice: once alone, once under grad
+    # (where jax writes the outermost scope as jvp(flash_fwd))
+    assert sorted(set(scopes)) == ["flash_dkv", "flash_dq", "flash_fwd"]
+    assert scopes.count("flash_dq") == scopes.count("flash_dkv") == 1
+
+
 def test_explicit_flat_past_the_vmem_budget_is_refused_before_the_compiler(
     one_chip,
 ):

@@ -184,6 +184,124 @@ def test_seeded_sampling_is_deterministic(decode_engine):
     assert len(runs[0]) == 6
 
 
+# --------------------------------------- the decode loop's scoped spans
+
+#: where the decode loop's host time goes (serve/batcher.py, PERF.md §3)
+_LOOP_SPANS = ("batcher.idle", "batcher.plan", "batcher.sem_wait",
+               "engine.prefill_dispatch", "engine.decode_dispatch")
+_FETCH_SPANS = ("engine.fetch", "batcher.deliver")
+
+
+class _LiveLaneSpy:
+    """The engine, plus a note of the live lanes of every decode step."""
+
+    def __init__(self, engine):
+        self._engine = engine
+        self.live = []
+
+    def __getattr__(self, name):
+        return getattr(self._engine, name)
+
+    def decode(self, lengths, active, temps, seeds):
+        self.live.append(int(np.sum(active)))
+        return self._engine.decode(lengths, active, temps, seeds)
+
+
+@pytest.fixture(scope="module")
+def traced_run(decode_engine):
+    """Seven mixed requests through three slots with an enabled tracer:
+    ``(spans by name, spy engine, futures, metrics snapshot)``."""
+    from distributed_tensorflow_tpu.obs.trace import Tracer
+
+    rng = np.random.default_rng(1)
+    spy, tracer, m = _LiveLaneSpy(decode_engine), Tracer(1 << 14), ServeMetrics()
+    with ContinuousBatcher(
+        spy, BatcherConfig(max_batch=2, max_queue=32), metrics=m,
+        tracer=tracer,
+    ) as b:
+        futs = [
+            b.submit({
+                "input_ids": rng.integers(5, 64, size=int(rng.integers(3, 14))),
+                "max_new_tokens": int(rng.integers(2, 9)),
+            })
+            for _ in range(7)
+        ]
+        for f in futs:
+            f.result(timeout=120)
+    spans = {}
+    for sp in tracer.drain():
+        spans.setdefault(sp.name, []).append(sp)
+    return spans, spy, futs, m.snapshot()
+
+
+def test_every_decode_step_has_its_scoped_spans(traced_run):
+    spans, spy, _futs, snap = traced_run
+    steps = snap["decode_steps"]
+    assert steps == len(spy.live) > 0
+    assert len(spans["engine.decode_dispatch"]) == steps
+    assert sum(s.args["kind"] == "decode" for s in spans["engine.fetch"]) == steps
+    # one semaphore wait a dispatch, one delivery a fetch, and a planning
+    # pass that returned the step's rows before every dispatch
+    n_prefill = len(spans["engine.prefill_dispatch"])
+    assert len(spans["batcher.sem_wait"]) == steps + n_prefill
+    assert len(spans["batcher.deliver"]) == len(spans["engine.fetch"])
+    planned = [s for s in spans["batcher.plan"] if s.args and s.args["rows"]]
+    assert len(planned) == steps
+    assert sum(s.args["tokens"] for s in spans["batcher.deliver"]) == snap["tokens"]
+    assert sum(s.args["finished"] for s in spans["batcher.deliver"]) == 7
+    assert sum(s.args["admitted"] for s in spans["batcher.plan"] if s.args) == 7
+    assert {s.args["bucket"] for s in spans["engine.prefill_dispatch"]} <= {8, 16}
+    # the after-the-fact intervals they replace are gone
+    assert "decode_step" not in spans
+
+
+def test_scoped_spans_of_one_thread_never_overlap(traced_run):
+    spans, *_ = traced_run
+    for names in (_LOOP_SPANS, _FETCH_SPANS):
+        mine = sorted(
+            (s for n in names for s in spans.get(n, ())), key=lambda s: s.t0
+        )
+        assert len({s.tid for s in mine}) == 1  # one thread does this work
+        for a, b in zip(mine, mine[1:]):
+            assert a.t1 <= b.t0, (a.name, b.name)
+
+
+def test_decode_dispatch_rows_are_the_live_lanes(traced_run):
+    spans, spy, *_ = traced_run
+    dispatched = sorted(spans["engine.decode_dispatch"], key=lambda s: s.t0)
+    assert [s.args["rows"] for s in dispatched] == spy.live
+    assert {s.args["slots"] for s in dispatched} == {3}
+    planned = sorted(
+        (s for s in spans["batcher.plan"] if s.args and s.args["rows"]),
+        key=lambda s: s.t0,
+    )
+    assert [s.args["rows"] for s in planned] == spy.live
+
+
+def test_request_phases_keep_their_three_keys(traced_run):
+    spans, _spy, futs, _snap = traced_run
+    for f in futs:
+        assert set(f.phases) == {"queue_wait", "prefill", "decode"}
+        assert abs(sum(f.phases.values()) - f.latency_s) < 1e-9
+    for name in ("request", "queue_wait", "prefill", "decode"):
+        assert len(spans[name]) == 7  # the per-request record()s stand
+
+
+@pytest.mark.parametrize("cell", ["decode", "prefill"])
+def test_grid_cells_carry_the_scope_names_the_metrics_select(decode_engine, cell):
+    """What benchmarks/layer_metrics/engine.*.json select survives the
+    compiler: the module's name and the scopes in its ops' op_name."""
+    if cell == "decode":
+        text = decode_engine._decode_compiled.as_text()
+        scopes = ("kv_write", "cached_attention", "lm_head", "sample")
+    else:
+        text = next(iter(decode_engine._prefill_compiled.values())).as_text()
+        scopes = ("kv_write", "lm_head", "sample")
+    assert text.startswith(f"HloModule jit_{cell}_fn")
+    for scope in scopes:
+        assert f"/{scope}/" in text, scope
+
+
 def test_engine_validate_rejects_oversized(decode_engine):
     from distributed_tensorflow_tpu.serve import RequestError
 
