@@ -24,7 +24,7 @@ Three forwards share one param tree:
   position, write the new K/V at ``position``, attend positions
   ``<= position``. Shapes are fixed by the slot count, so slot
   assignment/reuse never retraces (the "fixed pool of per-slot cache
-  pages" contract).
+  pages" contract). It writes by select, not by scatter (below).
 - ``prefill_chunk(input_ids [B, C], positions [B, C], k_cache, v_cache) ->
   (logits [B, C, V], k_cache', v_cache')`` — a CHUNK of each row's prompt
   at arbitrary ABSOLUTE positions against per-row caches ``[nl, B, Lc, h,
@@ -46,6 +46,37 @@ Numerics: both attention paths accumulate scores and context in f32 with
 the same masking convention (fully-masked rows -> exactly 0), so a token
 decoded step-by-step matches the full forward's argmax at the same
 position — tests/test_serve_decode.py pins greedy parity exactly.
+
+Why decode_step writes by select. On the TPU the slot table ``[nl, S, L, h,
+d]`` lives with the cache POSITION minor-most (layout ``{2,4,3,1,0}``:
+``d x L`` tiles without padding, ``h x d`` would not), which is the layout
+the attention einsums read. The ``scatter`` and ``dynamic-update-slice``
+emitters want ``{4,3,..}`` instead, so the compiler brackets every such
+write with two copies of whatever table it writes. Compiled for a described
+v5e at the serving benchmark's geometry (bf16, 128 slots, cache 384, tables
+donated; ``memory_analysis().temp_size_in_bytes``):
+
+- per layer ``table[i].at[idx, position].set(..)``, then re-stack (the
+  spelling until PR 28): 48 copies of a layer table a step, 2 slicing
+  fusions, 24 scatters, 24 re-stacking updates — 3.55 GB;
+- one stacked scatter ``table.at[:, idx, position].set(..)`` at the end: the
+  whole table copied there and back — 2.45 GB; the same for a loop of
+  per-slot ``dynamic_update_slice``, rolled or unrolled — 2.45 GB;
+- the stacked table carried through the layers with ``.at[i, idx,
+  position].set(..)``: the whole program flips layout, 24 full-table
+  scatters — 7.26 GB;
+- what is here: each layer attends ``where(position_hit, new_row,
+  table[i])`` (slice and select fuse into the attention loop; no layer
+  table exists), and the ``[nl, S, h, d]`` of new rows are written once, by
+  one select over the stacked table that aliases its donated operand —
+  0.026 GB, no table-sized copy, slice, scatter or update (int8 KV: 0.028).
+
+The select passes over the whole table to write ``nl x S`` rows; that one
+pass is what the layout costs, and PERF.md (PR 28) has its time on the chip.
+The operand values are those of write-then-attend, bit for bit
+(tests/test_decode_kv_write.py); tests/test_chip_compile.py keeps the
+compiled program free of the copies. ``prefill_chunk`` / ``verify_step``
+still slice, scatter and re-stack, and have the copies by construction.
 """
 
 from __future__ import annotations
@@ -71,9 +102,10 @@ def _layer_cache(cache, i):
 
 
 def _stack_cache(layers):
-    """Re-stack per-layer cache returns, preserving the quantized pytree
-    structure when present. Part of ``kv_write``: the step's new slot table
-    is the stack of the per-layer tables its scatters produced."""
+    """Stack per-layer cache returns, preserving the quantized pytree
+    structure when present. Part of ``kv_write``: the per-layer tables the
+    scatters of ``prefill_chunk`` produced become the new slot table, and the
+    per-layer rows of ``decode_step`` the ``[nl, S, h, d]`` it writes."""
     with jax.named_scope("kv_write"):
         if isinstance(layers[0], dict):
             return {
@@ -81,6 +113,23 @@ def _stack_cache(layers):
                 "s": jnp.stack([c["s"] for c in layers]),
             }
         return jnp.stack(layers)
+
+
+def _select_rows(table, rows, position, slot_axis):
+    """``table`` with ``rows`` at each slot's ``position``, as a select —
+    never a scatter (module docstring). ``table`` is ``[.., S, L, ..]`` with
+    the slots at ``slot_axis`` and the cache positions after them, ``rows``
+    the same without the position axis, ``position: [S]``; both may be the
+    int8 ``{"q", "s"}`` pytree, whose leaves differ only in trailing axes.
+    A position of ``L`` or more matches nothing: the slot keeps its pages.
+    """
+
+    def leaf(t, r):
+        hit = jnp.arange(t.shape[slot_axis + 1]) == position[:, None]  # [S, L]
+        hit = hit.reshape(hit.shape + (1,) * (t.ndim - slot_axis - 2))
+        return jnp.where(hit, jnp.expand_dims(r, slot_axis + 1), t)
+
+    return jax.tree.map(leaf, table, rows)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -245,43 +294,41 @@ class CausalSelfAttention(nn.Module):
         return self._finish(x, ctx), k, v
 
     def decode(self, x, k_cache, v_cache, position):
-        # position == Lmax marks an idle lane: its scatter drops (writing
-        # anywhere could corrupt a mid-chunk-prefill slot's pages) and its
-        # attention clamps — the lane's output is garbage nobody reads.
+        """One token per slot against this layer's table AS THE STEP FOUND
+        IT. Returns ``(x', k_row, v_row)``: the rows ``[S, h, d]`` (or the
+        int8 ``{"q", "s"}`` pair) the step has to write at ``position``,
+        which ``CausalLM.decode_step`` writes for all layers at once.
+        Attention reads the table with the row selected in, the operand
+        values of write-then-attend without the write."""
+        # position == Lmax marks an idle lane: no cache position matches, so
+        # nothing is written (writing anywhere could corrupt a mid-chunk-
+        # prefill slot's pages) and its attention clamps — the lane's
+        # output is garbage nobody reads.
         q, k, v = self.query(x), self.key(x), self.value(x)  # [S, h, d]
-        idx = jnp.arange(x.shape[0])
-        if isinstance(k_cache, dict):
-            # int8 KV mode: quantize the new token per slot at the write,
-            # attend with the factored per-position scales.
-            with jax.named_scope("kv_write"):
-                qk, sk = quantize_kv(k)
-                qv, sv = quantize_kv(v)
-                k_cache = {
-                    "q": k_cache["q"].at[idx, position].set(qk, mode="drop"),
-                    "s": k_cache["s"].at[idx, position].set(sk, mode="drop"),
-                }
-                v_cache = {
-                    "q": v_cache["q"].at[idx, position].set(qv, mode="drop"),
-                    "s": v_cache["s"].at[idx, position].set(sv, mode="drop"),
-                }
-            ctx = _cached_attention(
-                q, k_cache["q"], v_cache["q"],
-                jnp.minimum(position, k_cache["q"].shape[1] - 1),
-                k_scale=k_cache["s"], v_scale=v_cache["s"],
-            )
-            return self._finish(x, ctx), k_cache, v_cache
         with jax.named_scope("kv_write"):
-            k_cache = k_cache.at[idx, position].set(
-                k.astype(k_cache.dtype), mode="drop"
+            if isinstance(k_cache, dict):
+                # int8 KV mode: quantize the new token per slot at the
+                # write, attend with the factored per-position scales.
+                k_row = dict(zip(("q", "s"), quantize_kv(k)))
+                v_row = dict(zip(("q", "s"), quantize_kv(v)))
+            else:
+                k_row = k.astype(k_cache.dtype)
+                v_row = v.astype(v_cache.dtype)
+        with jax.named_scope("cached_attention"):
+            k_read = _select_rows(k_cache, k_row, position, slot_axis=0)
+            v_read = _select_rows(v_cache, v_row, position, slot_axis=0)
+        if isinstance(k_cache, dict):
+            ctx = _cached_attention(
+                q, k_read["q"], v_read["q"],
+                jnp.minimum(position, k_read["q"].shape[1] - 1),
+                k_scale=k_read["s"], v_scale=v_read["s"],
             )
-            v_cache = v_cache.at[idx, position].set(
-                v.astype(v_cache.dtype), mode="drop"
+        else:
+            ctx = _cached_attention(
+                q, k_read, v_read,
+                jnp.minimum(position, k_read.shape[1] - 1),
             )
-        ctx = _cached_attention(
-            q, k_cache, v_cache,
-            jnp.minimum(position, k_cache.shape[1] - 1),
-        )
-        return self._finish(x, ctx), k_cache, v_cache
+        return self._finish(x, ctx), k_row, v_row
 
     def prefill_chunk(self, x, positions, k_cache, v_cache):
         # x [B, C, H]; positions [B, C] absolute (sentinel == Lc on
@@ -356,10 +403,10 @@ class CausalLmLayer(nn.Module):
         return self._ffn(x), k, v
 
     def decode(self, x, k_cache, v_cache, position):
-        x, k_cache, v_cache = self.attention.decode(
+        x, k_row, v_row = self.attention.decode(
             x, k_cache, v_cache, position
         )
-        return self._ffn(x), k_cache, v_cache
+        return self._ffn(x), k_row, v_row
 
     def prefill_chunk(self, x, positions, k_cache, v_cache):
         x, k_cache, v_cache = self.attention.prefill_chunk(
@@ -434,15 +481,22 @@ class CausalLM(nn.Module):
         x = self._embed(
             token, jnp.minimum(position, self.cfg.max_position - 1)
         )  # [S, H]
-        new_k, new_v = [], []
+        k_rows, v_rows = [], []
         for i, layer in enumerate(self.layers):
-            x, kc, vc = layer.decode(
+            x, k_row, v_row = layer.decode(
                 x, _layer_cache(k_cache, i), _layer_cache(v_cache, i),
                 position,
             )
-            new_k.append(kc)
-            new_v.append(vc)
-        return self._head(x), _stack_cache(new_k), _stack_cache(new_v)
+            k_rows.append(k_row)
+            v_rows.append(v_row)
+        # Every layer read the step's INPUT table; the [nl, S, h, d] of new
+        # rows go into it here, once, in the layout it lives in (module
+        # docstring, "Why decode_step writes by select").
+        k_rows, v_rows = _stack_cache(k_rows), _stack_cache(v_rows)
+        with jax.named_scope("kv_write"):
+            k_cache = _select_rows(k_cache, k_rows, position, slot_axis=1)
+            v_cache = _select_rows(v_cache, v_rows, position, slot_axis=1)
+        return self._head(x), k_cache, v_cache
 
     def prefill_chunk(self, input_ids, positions, k_cache, v_cache):
         # Absolute-position chunk prefill against the slot cache: caches

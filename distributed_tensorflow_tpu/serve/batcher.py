@@ -1161,6 +1161,15 @@ class ContinuousBatcher:
                 "kv_active_bytes": self._n_active * getattr(
                     self._engine, "slot_page_bytes", 0
                 ),
+                # The decode program's scratch and the bytes of the slot
+                # table it updates in place, as compiled (engine.py): the
+                # device memory a slot count costs beyond its pages.
+                "decode_scratch_bytes": getattr(
+                    self._engine, "decode_scratch_bytes", None
+                ),
+                "decode_aliased_bytes": getattr(
+                    self._engine, "decode_aliased_bytes", None
+                ),
                 # Emitted tokens per decode/verify step completion: 1.0 on
                 # a plain engine, >1 when speculation is winning.
                 "tokens_per_step": (

@@ -1421,6 +1421,18 @@ class CausalLMEngine(_AotEngine):
                 .compile()
             ),
         )
+        # What the decode program reserves beside its operands, and how much
+        # of the donated slot table it updates in place (per device; None
+        # where the backend reports no memory analysis). Decided at compile
+        # time, so it is read once, here: an operator who sizes --slots
+        # reads it on the ready line or /statusz, not as an OOM.
+        self.decode_scratch_bytes = self.decode_aliased_bytes = None
+        try:
+            ma = self._decode_compiled.memory_analysis()
+            self.decode_scratch_bytes = int(ma.temp_size_in_bytes)
+            self.decode_aliased_bytes = int(ma.alias_size_in_bytes)
+        except Exception:  # noqa: BLE001 — best-effort per backend
+            pass
         self._verify_compiled = None
         if self.spec_tokens:
             verify_fn = self._wrap(
@@ -1496,11 +1508,13 @@ class CausalLMEngine(_AotEngine):
         logger.info(
             "causal-LM engine ready: layout=%s slots=%d cache_len=%d "
             "buckets=%s tiers=%s chunk=%s pool_blocks=%s spec_k=%s "
+            "decode_scratch_bytes=%s decode_aliased_bytes=%s "
             "(%d executables)",
             self.layout, slots, self.cache_len, self.buckets,
             self.batch_tiers, self.prefill_chunk_size or None,
             self.prefix_cache.n_blocks if self.prefix_cache else None,
             self.spec_tokens or None,
+            self.decode_scratch_bytes, self.decode_aliased_bytes,
             len(self._prefill_compiled) + len(self._chunk_compiled) + 1
             + (1 if self.prefix_cache is not None else 0)
             + (2 if self._kv_transfer else 0) + n_spec_cells + n_mig_cells,

@@ -1,4 +1,5 @@
-"""The flash-attention kernels compile for a TPU v5e at production shapes.
+"""The flash-attention kernels compile for a TPU v5e at production shapes,
+and the decode step compiles there without moving its slot table.
 
 Interpret mode, which every other kernel test runs in, accepts what the
 chip's compiler refuses: a slice off the (8, 128) tiling, more scoped VMEM
@@ -17,6 +18,7 @@ import os
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
@@ -149,3 +151,94 @@ def test_explicit_flat_past_the_vmem_budget_is_refused_before_the_compiler(
 ):
     with pytest.raises(ValueError, match="VMEM"):
         _compile_fwd_bwd(one_chip, (4, 2048, 12, 64), jnp.bfloat16, "flat")
+
+
+# ------------------------------------------- the decode step's slot table
+
+# benchmarks/workloads/lm_base.chat_steady: lm_base at GPT-1's sizes, 128
+# slots, cache 384 (bucket 256 + 128 new tokens).
+_SLOTS, _CACHE_LEN = 128, 384
+
+
+def _compile_decode(one_chip, kv):
+    from distributed_tensorflow_tpu.models.causal_lm import (
+        CausalLM,
+        CausalLMConfig,
+    )
+    from distributed_tensorflow_tpu.serve.engine import _make_causal_decode
+
+    cfg = CausalLMConfig(vocab_size=40478, dtype=jnp.bfloat16)
+    model = CausalLM(cfg)
+
+    def struct(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params = jax.tree.map(
+        lambda x: struct(x.shape, jnp.bfloat16),
+        jax.eval_shape(
+            lambda: model.init(
+                jax.random.PRNGKey(0),
+                jnp.zeros((1, 8), jnp.int32), jnp.ones((1, 8), bool),
+            )["params"]
+        ),
+    )
+    pages = (
+        cfg.num_layers, _SLOTS, _CACHE_LEN, cfg.num_heads,
+        cfg.hidden_size // cfg.num_heads,
+    )
+    if kv == "int8":
+        table = {
+            "q": struct(pages, jnp.int8),
+            "s": struct(pages[:3], jnp.float32),
+        }
+    else:
+        table = struct(pages, jnp.bfloat16)
+    compiled = (
+        jax.jit(
+            _make_causal_decode(model, _CACHE_LEN), donate_argnums=(1, 2, 3)
+        )
+        .lower(
+            params, table, table,
+            struct((_SLOTS,), jnp.int32), struct((_SLOTS,), jnp.int32),
+            struct((_SLOTS,), jnp.bool_), struct((_SLOTS,), jnp.float32),
+            struct((_SLOTS,), jnp.int32),
+        )
+        .compile()
+    )
+    table_bytes = sum(
+        x.dtype.itemsize * int(np.prod(x.shape))
+        for x in jax.tree.leaves(table)
+    )
+    return compiled, pages, table_bytes
+
+
+@pytest.mark.parametrize("kv", ["bf16", "int8"])
+def test_decode_step_never_moves_its_slot_table(one_chip, kv):
+    """The table lives with the cache position minor-most and the scatter
+    and dynamic-update-slice emitters want another layout: written through
+    either, every step copies the table there and back (PERF.md, PR 28:
+    39.5 ms a step, 3.55 GB of scratch). decode_step writes by select;
+    this is the guard that keeps it so when someone touches the layer
+    loop. A compile, not a time."""
+    import re
+
+    compiled, pages, table_bytes = _compile_decode(one_chip, kv)
+    text = compiled.as_text()
+    entry = text[text.index("\nENTRY "):]
+    dims = ",".join(map(str, pages[1:]))
+    # one layer's pages or all layers', whatever the element type and layout
+    page_table = re.compile(r"\w+\[(?:%d,|1,)?%s\]" % (pages[0], dims))
+    made_by = {}
+    for line in entry.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%(\S+) = (.*?) ([a-z][a-z\-]*)\(", line)
+        if m and page_table.search(m.group(2)):
+            made_by.setdefault(m.group(3), []).append(m.group(1))
+    # The operands, and the one select that writes both tables in place;
+    # above all no copy, scatter, slice, concatenate or dynamic-update-slice.
+    assert set(made_by) <= {
+        "parameter", "fusion", "get-tuple-element", "tuple", "bitcast",
+    }, made_by
+    assert len(made_by.get("fusion", ())) <= 2, made_by["fusion"]
+    ma = compiled.memory_analysis()
+    assert ma.temp_size_in_bytes < 0.2e9, ma.temp_size_in_bytes  # was 3.546e9
+    assert ma.alias_size_in_bytes >= 2 * table_bytes

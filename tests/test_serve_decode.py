@@ -302,6 +302,24 @@ def test_grid_cells_carry_the_scope_names_the_metrics_select(decode_engine, cell
         assert f"/{scope}/" in text, scope
 
 
+def test_status_shows_what_the_decode_program_reserves(decode_engine):
+    """Scratch and in-place bytes of the decode executable, read once when
+    it is built: on the engine, and in the batcher digest /statusz shows.
+    The slot table is donated and written in place, so at least its bytes
+    are aliased wherever the backend reports a memory analysis."""
+    eng = decode_engine
+    ma = eng._decode_compiled.memory_analysis()
+    assert eng.decode_scratch_bytes == ma.temp_size_in_bytes
+    assert eng.decode_aliased_bytes == ma.alias_size_in_bytes
+    assert eng.decode_aliased_bytes >= eng.slot_page_bytes * eng.slots
+    with ContinuousBatcher(eng, BatcherConfig()) as b:
+        st = b.status()
+    assert st["decode_scratch_bytes"] == eng.decode_scratch_bytes
+    assert st["decode_aliased_bytes"] == eng.decode_aliased_bytes
+    with ContinuousBatcher(_StubDecodeEngine(), BatcherConfig()) as b:
+        assert b.status()["decode_scratch_bytes"] is None
+
+
 def test_engine_validate_rejects_oversized(decode_engine):
     from distributed_tensorflow_tpu.serve import RequestError
 
