@@ -522,7 +522,9 @@ def _build_bert_workload(cfg_kwargs: dict):
                     else None
                 ),
                 "model_state": {},
-                "loss_fn": make_bert_pretraining_loss(model),
+                "loss_fn": make_bert_pretraining_loss(
+                    model, mask_prob=data.cfg.mask_prob
+                ),
                 "batches": lambda start_step=0: mlm_device_batches(
                     data,
                     mesh,
@@ -537,7 +539,9 @@ def _build_bert_workload(cfg_kwargs: dict):
                     seq_sharded=bool(seq_parallel),
                     expert_sharded=expert_sharded,
                 ),
-                "metric_fn": make_bert_eval_metrics(model),
+                "metric_fn": make_bert_eval_metrics(
+                    model, mask_prob=data.cfg.mask_prob
+                ),
                 "eval_batches": eval_batches,
             }
 

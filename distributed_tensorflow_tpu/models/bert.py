@@ -22,6 +22,7 @@ Training objective: masked-LM cross-entropy over masked positions
 from __future__ import annotations
 
 import dataclasses
+import math
 
 import jax
 import jax.numpy as jnp
@@ -613,25 +614,40 @@ class BertForPreTraining(nn.Module):
             2, dtype=jnp.float32, kernel_init=nn.initializers.normal(0.02)
         )
 
+    def mlm_logits(self, rows):
+        """The MLM head over ``rows [..., H]``: transform, GELU, LayerNorm,
+        tied decoder. Every position for the serving paths below; the
+        masked rows alone for the loss (:func:`_mlm_head_stats`)."""
+        h = self.mlm_ln(nn.gelu(self.mlm_transform(rows), approximate=True))
+        # Tied decoder: logits against the word-embedding table. Logits
+        # KEEP the compute dtype: at BERT geometry [rows, V] is the head's
+        # biggest array (2.0 GB bf16 over all 64 x 512 positions, which
+        # only the serving paths compute; 0.34 GB over the loss's gathered
+        # rows), and the r5 trace showed the old f32 upcast doubling
+        # every loss-side pass over it (the CE reduce, the argmax, and the
+        # bwd softmax recompute — docs/PERF.md r5). _mlm_stats does its
+        # reductions in f32 on the fly; bf16 storage costs no stability
+        # (max is exact in bf16, exp/sum accumulate in f32).
+        return self.bert.embeddings.word.attend(h) + self.mlm_bias.astype(
+            self.cfg.dtype
+        )
+
+    def nsp_logits(self, pooled):
+        return self.nsp_head(pooled).astype(jnp.float32)
+
     def _heads(self, hidden, pooled):
         with jax.named_scope("mlm_head"):
-            h = self.mlm_ln(
-                nn.gelu(self.mlm_transform(hidden), approximate=True)
-            )
-            # Tied decoder: logits against the word-embedding table. Logits
-            # KEEP the compute dtype: at BERT geometry the [B, L, V] tensor
-            # is the single biggest array in the step (1.5 GB bf16 at L=512
-            # b=48), and the r5 trace showed the old f32 upcast doubling
-            # every loss-side pass over it (3.0 GB reads in the CE reduce,
-            # the argmax, and the bwd softmax recompute — docs/PERF.md r5).
-            # _mlm_stats does its reductions in f32 on the fly; bf16 storage
-            # costs no stability (max is exact in bf16, exp/sum accumulate
-            # in f32).
-            mlm_logits = self.bert.embeddings.word.attend(
-                h
-            ) + self.mlm_bias.astype(self.cfg.dtype)
-        nsp_logits = self.nsp_head(pooled)
-        return mlm_logits, nsp_logits.astype(jnp.float32)
+            mlm_logits = self.mlm_logits(hidden)
+        return mlm_logits, self.nsp_logits(pooled)
+
+    def encode(self, input_ids, attention_mask, token_type_ids, *, train=False):
+        """``(hidden [B,L,H], nsp_logits [B,2])`` — what the loss and the
+        eval metrics take, leaving the MLM head to run over the rows they
+        choose."""
+        hidden, pooled = self.bert(
+            input_ids, attention_mask, token_type_ids, train=train
+        )
+        return hidden, self.nsp_logits(pooled)
 
     def __call__(self, input_ids, attention_mask, token_type_ids, *, train=False):
         hidden, pooled = self.bert(
@@ -651,23 +667,23 @@ class BertForPreTraining(nn.Module):
         return mlm_logits, nsp_logits, pooled
 
 
-def _mlm_stats(mlm_logits, batch, seq_axis):
+def _mlm_stats(mlm_logits, targets):
     """Shared MLM statistics for the train loss and eval metrics: CE sum,
-    masked-token count, and correct count over this shard — psum'd over the
-    seq ring so they are GLOBAL sums (the one masking/clamp/psum recipe both
-    paths must agree on).
+    masked-token count, and correct count over the given rows (the one
+    masking/clamp recipe both paths must agree on). ``targets < 0`` marks a
+    row that does not count; :func:`_mlm_head_stats` picks the rows and
+    psums the three over the seq ring.
 
     The CE is computed in f32 ON THE FLY from the logits' storage dtype
     (bf16 at the production config): the row max is exact in bf16, the
     shifted exp/sum converts per element inside the fused reduce, and the
     backward emits the softmax cotangent in storage dtype. Versus upcasting
-    the [B, L, V] logits to f32 first, every pass over the step's biggest
+    the [rows, V] logits to f32 first, every pass over the head's biggest
     tensor moves half the bytes (measured 6.8 ms for the old f32 CE reduce
     alone, docs/PERF.md r5). Accuracy reuses the already-computed
-    row max instead of a second full argmax pass over [B, L, V]: a masked
+    row max instead of a second full argmax pass over [rows, V]: a masked
     position counts correct iff its target logit equals the row max
     (ties — measure-zero in f32, rare in bf16 — count correct)."""
-    targets = batch["mlm_targets"]
     weights = (targets >= 0).astype(jnp.float32)
     m = lax.stop_gradient(jnp.max(mlm_logits, axis=-1, keepdims=True))
     # Convert-then-subtract: the convert runs in-register inside the fused
@@ -685,31 +701,168 @@ def _mlm_stats(mlm_logits, batch, seq_axis):
     correct = jnp.sum(
         (tgt_logit == m[..., 0]).astype(jnp.float32) * weights
     )
-    if seq_axis is not None:
-        num = lax.psum(num, seq_axis)
-        den = lax.psum(den, seq_axis)
-        correct = lax.psum(correct, seq_axis)
     return num, den, correct
 
 
-def make_bert_eval_metrics(model: BertForPreTraining):
+def mlm_gather_rows(n_rows: int, mask_prob: float) -> int | None:
+    """How many rows the gathered MLM head runs over for a shard of
+    ``n_rows`` positions masked at ``mask_prob``: the expected count plus
+    eight standard deviations of Binomial(n_rows, mask_prob), rounded up to
+    a multiple of 128 (5,504 of 32,768 at 0.15). ``None`` where that is not
+    under half the rows: gathering buys too little there, and the head runs
+    dense, statically."""
+    sigma = math.sqrt(n_rows * mask_prob * (1.0 - mask_prob))
+    k = 128 * math.ceil((n_rows * mask_prob + 8.0 * sigma) / 128)
+    return k if 2 * k < n_rows else None
+
+
+def _gathered(stats, k_rows: int):
+    """``stats(head_params, rows [M,H], targets [M]) -> (num, den, correct)``
+    run over the masked rows of ``[N, H]`` (at most ``k_rows``, a shape) in
+    place of all ``N``, exactly: a shard that holds more masked rows than
+    ``k_rows`` takes the dense head for that step (``lax.cond``), never a
+    clipped loss.
+
+    Differentiating through ``lax.cond`` would make each branch emit the
+    other's residuals as zeros (the dense branch's are the [N, V] logits).
+    So each branch computes its value AND its gradients inside the branch,
+    and a ``custom_vjp`` hands them out scaled by the cotangent of ``num``:
+    exact, because the outputs are scalars and only ``num`` depends on
+    anything differentiable."""
+
+    def fits(targets):
+        return jnp.sum(targets >= 0) <= k_rows
+
+    def pick(hidden, targets):
+        # Ascending indices of the masked rows by one sort (0.02 ms at N =
+        # 32,768 on a v5e; nonzero(size=) builds the same by a scatter in
+        # 0.29), padded with N: a padded slot reads a row of zeros and gets
+        # target -1, which is weight 0 in ``stats``.
+        n_rows = targets.shape[0]
+        masked = targets >= 0
+        idx = jnp.sort(jnp.where(masked, jnp.arange(n_rows), n_rows))[:k_rows]
+        rows = hidden.at[idx].get(mode="fill", fill_value=0)
+        row_targets = targets.at[idx].get(mode="fill", fill_value=-1)
+        return rows, row_targets
+
+    def value_gathered(head_params, hidden, targets):
+        return stats(head_params, *pick(hidden, targets))
+
+    def num_and_grads(head_params, rows, row_targets):
+        def num_first(p, r):
+            num, den, correct = stats(p, r, row_targets)
+            return num, (den, correct)
+
+        (num, (den, correct)), grads = jax.value_and_grad(
+            num_first, argnums=(0, 1), has_aux=True
+        )(head_params, rows)
+        return (num, den, correct), grads
+
+    def grads_gathered(head_params, hidden, targets):
+        out, (d_params, d_rows) = num_and_grads(
+            head_params, *pick(hidden, targets)
+        )
+        # The gather's transpose, written as a gather: masked row n was slot
+        # (masked rows before n) of ``rows``. A scatter-add of the same
+        # 5,504 rows takes twice as long on the chip (0.39 against 0.20 ms).
+        masked = targets >= 0
+        slot = jnp.cumsum(masked) - 1
+        d_hidden = jnp.where(
+            masked[:, None], d_rows.at[slot].get(mode="clip"), 0
+        )
+        return out, (d_params, d_hidden)
+
+    @jax.custom_vjp
+    def head(head_params, hidden, targets):
+        return lax.cond(
+            fits(targets), value_gathered, stats, head_params, hidden, targets
+        )
+
+    def head_fwd(head_params, hidden, targets):
+        return lax.cond(
+            fits(targets), grads_gathered, num_and_grads,
+            head_params, hidden, targets,
+        )
+
+    def head_bwd(grads, cotangents):
+        g_num = cotangents[0]
+
+        def scaled(x):
+            # In float32: the cotangent (1 / masked tokens) rounded to bf16
+            # would tilt the whole encoder's gradient by up to 2^-9.
+            return (x.astype(jnp.float32) * g_num).astype(x.dtype)
+
+        return (*jax.tree.map(scaled, grads), None)  # (params, hidden, targets)
+
+    head.defvjp(head_fwd, head_bwd)
+    return head
+
+
+def _mlm_head_stats(model: BertForPreTraining, mask_prob: float):
+    """``head_stats(params, hidden [B,L,H], targets [B,L]) -> (num, den,
+    correct, share)``: the MLM head and :func:`_mlm_stats` over this
+    shard's masked rows, for the train loss and the eval metrics alike, the
+    three sums psum'd over the seq ring so they are GLOBAL. ``share`` is the
+    part of the shard's rows the head ran over: ``k / N`` gathered, 1.0
+    dense (statically, at shapes where :func:`mlm_gather_rows` says so, or
+    for a step whose shard holds more than ``k`` masked rows)."""
+    seq_axis = model.cfg.seq_axis
+
+    def stats(head_params, rows, targets):
+        logits = model.apply(
+            {"params": head_params}, rows, method=BertForPreTraining.mlm_logits
+        )
+        return _mlm_stats(logits, targets)
+
+    def head_stats(params, hidden, targets):
+        # Only what the head reads: its gradients are taken inside a branch
+        # (_gathered), where the encoder's would be 0.4 GB of zeros.
+        head_params = {k: params[k] for k in ("mlm_transform", "mlm_ln", "mlm_bias")}
+        head_params["bert"] = {
+            "embeddings": {"word": params["bert"]["embeddings"]["word"]}
+        }
+        n_rows = targets.size
+        k_rows = mlm_gather_rows(n_rows, mask_prob)
+        with jax.named_scope("mlm_head"):
+            if k_rows is None:
+                num, den, correct = stats(head_params, hidden, targets)
+                share = jnp.ones((), jnp.float32)
+            else:
+                num, den, correct = _gathered(stats, k_rows)(
+                    head_params,
+                    hidden.reshape(n_rows, hidden.shape[-1]),
+                    targets.reshape(n_rows),
+                )
+                share = jnp.where(den <= k_rows, k_rows / n_rows, 1.0)
+        if seq_axis is not None:
+            num = lax.psum(num, seq_axis)
+            den = lax.psum(den, seq_axis)
+            correct = lax.psum(correct, seq_axis)
+        return num, den, correct, share
+
+    return head_stats
+
+
+def make_bert_eval_metrics(model: BertForPreTraining, *, mask_prob: float = 0.15):
     """Eval ``metric_fn`` for :func:`make_eval_step`: MLM/NSP losses and
     accuracies on held-out batches, no dropout, no mutation. MLM entries are
     ``(num, den)`` pairs so the eval step reduces them as global ratios over
-    the DP axes (variable masked-token counts per shard); seq-parallel
-    handling is shared with the training loss (:func:`_mlm_stats`)."""
-    seq_axis = model.cfg.seq_axis
+    the DP axes (variable masked-token counts per shard); the head, its row
+    gather and the seq-parallel handling are shared with the training loss
+    (:func:`_mlm_head_stats`)."""
+    head_stats = _mlm_head_stats(model, mask_prob)
 
     def metric_fn(params, model_state, batch):
         del model_state
-        mlm_logits, nsp_logits = model.apply(
+        hidden, nsp_logits = model.apply(
             {"params": params},
             batch["input_ids"],
             batch["attention_mask"],
             batch["token_type_ids"],
             train=False,
+            method=BertForPreTraining.encode,
         )
-        num, den, correct = _mlm_stats(mlm_logits, batch, seq_axis)
+        num, den, correct, _ = head_stats(params, hidden, batch["mlm_targets"])
         b = batch["nsp_label"].shape[0]
         nsp_ce = optax.softmax_cross_entropy_with_integer_labels(
             nsp_logits, batch["nsp_label"]
@@ -819,7 +972,7 @@ def bert_param_specs(
     return jax.tree_util.tree_map_with_path(spec_for, params)
 
 
-def make_bert_pretraining_loss(model: BertForPreTraining):
+def make_bert_pretraining_loss(model: BertForPreTraining, *, mask_prob: float = 0.15):
     """LossFn for the engine: MLM (ignore targets < 0) + NSP.
 
     Batches: ``input_ids, attention_mask, token_type_ids, mlm_targets`` all
@@ -827,14 +980,21 @@ def make_bert_pretraining_loss(model: BertForPreTraining):
     With ``cfg.seq_axis`` set, the MLM numerator/denominator are psum'd over
     the seq ring so every shard returns the *global* loss — required by the
     engine's seq-grad contract (train/step.py).
+
+    The MLM head runs over the masked rows only (:func:`_mlm_head_stats`);
+    ``mask_prob`` is the rate the data masks at, which sizes the gather and
+    never the result. The metric ``mlm_head_share`` says what share of the
+    rows the head ran over: a mean above ``k / N`` over a run is the share
+    of steps whose data was masked more densely than ``mask_prob`` says and
+    took the dense head.
     """
-    seq_axis = model.cfg.seq_axis
     moe = model.cfg.moe_experts > 0
+    head_stats = _mlm_head_stats(model, mask_prob)
 
     def loss_fn(params, model_state, batch, rng):
         # mutable=["intermediates"] is harmless for dense BERT (nothing is
         # sown; mods comes back empty) — one apply call for both paths.
-        (mlm_logits, nsp_logits), mods = model.apply(
+        (hidden, nsp_logits), mods = model.apply(
             {"params": params},
             batch["input_ids"],
             batch["attention_mask"],
@@ -842,6 +1002,7 @@ def make_bert_pretraining_loss(model: BertForPreTraining):
             train=True,
             rngs={"dropout": rng},
             mutable=["intermediates"],
+            method=BertForPreTraining.encode,
         )
         if moe:
             # Leaves are scalars (per-layer module list; the pipelined
@@ -849,8 +1010,7 @@ def make_bert_pretraining_loss(model: BertForPreTraining):
             # (the nn.scan encoder) — jnp.mean handles both uniformly.
             aux_leaves = jax.tree.leaves(mods["intermediates"])
             moe_aux = sum(jnp.mean(a) for a in aux_leaves) / len(aux_leaves)
-        with jax.named_scope("mlm_head"):
-            num, den, correct = _mlm_stats(mlm_logits, batch, seq_axis)
+        num, den, correct, share = head_stats(params, hidden, batch["mlm_targets"])
         den = jnp.maximum(den, 1.0)
         mlm_loss = num / den
         nsp_loss = optax.softmax_cross_entropy_with_integer_labels(
@@ -861,6 +1021,7 @@ def make_bert_pretraining_loss(model: BertForPreTraining):
             "mlm_loss": mlm_loss,
             "nsp_loss": nsp_loss,
             "mlm_accuracy": correct / den,
+            "mlm_head_share": share,
         }
         if moe:
             loss = loss + model.cfg.moe_aux_weight * moe_aux

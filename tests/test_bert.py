@@ -6,6 +6,8 @@ the same parameter updates as the dense single-shard model (SURVEY.md §5
 long-context row + train/step.py seq-grad contract).
 """
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -21,7 +23,9 @@ from distributed_tensorflow_tpu.data.text import (
 from distributed_tensorflow_tpu.models.bert import (
     BertConfig,
     BertForPreTraining,
+    make_bert_eval_metrics,
     make_bert_pretraining_loss,
+    mlm_gather_rows,
 )
 from distributed_tensorflow_tpu.parallel.mesh import build_mesh
 from distributed_tensorflow_tpu.train import create_train_state, make_train_step
@@ -149,6 +153,131 @@ def test_bert_seq_parallel_equals_dense(devices8):
         results["ring"][1],
         results["dense"][1],
     )
+
+
+# ------------------------------------------- the MLM head's row gather
+
+# Shapes at which the gather engages on the CPU: every shard (or micro-slice)
+# holds 1,024 or 2,048 rows, of which the head runs over 256 or 512.
+# name -> (mesh, seq-parallel, grad_accum, global batch, L, all content masked)
+GATHER_CASES = {
+    "single": ({"data": 1}, False, 1, 16, 128, False),
+    "data8": ({"data": 8}, False, 1, 64, 128, False),
+    "seq4": ({"data": 2, "seq": 4}, True, 1, 32, 256, False),
+    "grad_accum2": ({"data": 2}, False, 2, 32, 128, False),
+    "overflow": ({"data": 1}, False, 1, 16, 128, True),
+}
+
+
+def _gather_cfg(**kw):
+    return BertConfig(
+        vocab_size=1000, hidden_size=32, num_layers=1, num_heads=2,
+        intermediate_size=64, max_position=256, dropout_rate=0.0, **kw,
+    )
+
+
+def _one_step(mesh, model, params, batch, *, mask_prob, seq, grad_accum):
+    """One engine step whose optimizer keeps the reduced gradient as its
+    state (a momentum trace with no decay) and moves nothing."""
+    tx = optax.chain(optax.trace(decay=0.0), optax.scale(0.0))
+    state = place_state(create_train_state(params, tx), mesh)
+    step = make_train_step(
+        make_bert_pretraining_loss(model, mask_prob=mask_prob), tx, mesh,
+        batch_spec=bert_batch_specs(mesh, seq_sharded=seq),
+        grad_accum=grad_accum, donate=False,
+    )
+    state, metrics = step(state, batch, jax.random.key(3))
+    grads = jax.device_get(state.opt_state[0].trace)
+    return {k: float(v) for k, v in metrics.items()}, grads
+
+
+@pytest.mark.parametrize("case", sorted(GATHER_CASES))
+def test_gathered_mlm_head_equals_dense(devices8, case):
+    """The head over the masked rows alone gives the loss, the metrics and
+    every gradient of the head over all rows, to float32 rounding: on one
+    device, per ``data`` shard, per ``seq`` shard (each gathers its own
+    rows, then the psum) and per micro-slice of gradient accumulation. A
+    shard with more masked rows than the gather holds takes the dense
+    branch and says so in ``mlm_head_share``."""
+    spec, seq, grad_accum, gb, L, overflow = GATHER_CASES[case]
+    n_dev = int(np.prod(list(spec.values())))
+    mesh = build_mesh(spec, devices=jax.devices()[:n_dev])
+    _, params = _init(_gather_cfg(), key=5, l=L)
+    model = BertForPreTraining(_gather_cfg(seq_axis="seq" if seq else None))
+    data = SyntheticMLM(SyntheticMLMConfig(vocab_size=1000, seq_len=L, seed=4))
+    host = data.batch(gb, seed=11)
+    if overflow:
+        host["mlm_targets"] = np.where(
+            host["input_ids"] >= 4, host["input_ids"], -1
+        ).astype(np.int32)
+    shardings = jax.tree.map(
+        lambda s: jax.sharding.NamedSharding(mesh, s),
+        bert_batch_specs(mesh, seq_sharded=seq),
+    )
+    batch = {k: jax.device_put(v, shardings[k]) for k, v in host.items()}
+    local_rows = gb * L // n_dev // grad_accum
+    k_rows = mlm_gather_rows(local_rows, 0.15)
+    assert k_rows is not None and mlm_gather_rows(local_rows, 0.5) is None
+
+    run = functools.partial(
+        _one_step, mesh, model, params, batch, seq=seq, grad_accum=grad_accum
+    )
+    got, got_grads = run(mask_prob=0.15)
+    want, want_grads = run(mask_prob=0.5)  # no gather at this rate: dense
+    assert want.pop("mlm_head_share") == 1.0
+    share = got.pop("mlm_head_share")
+    assert share == (1.0 if overflow else pytest.approx(k_rows / local_rows))
+    assert got.keys() == want.keys()
+    for k in want:
+        np.testing.assert_allclose(got[k], want[k], rtol=1e-5, err_msg=k)
+    flat_want = dict(jax.tree_util.tree_leaves_with_path(want_grads))
+    # the key biases' gradient is zero but for rounding (a softmax does not
+    # see them): the floor is in units of the largest gradient
+    floor = 1e-9 * max(np.abs(w).max() for w in flat_want.values())
+    for path, g in jax.tree_util.tree_leaves_with_path(got_grads):
+        w = flat_want[path]
+        np.testing.assert_allclose(
+            g, w, rtol=1e-5, atol=1e-6 * np.abs(w).max() + floor,
+            err_msg=jax.tree_util.keystr(path),
+        )
+
+
+def test_gathered_mlm_head_in_eval_metrics_equals_dense():
+    """Eval goes through the same helper: same sums, no gradients taken."""
+    model, params = _init(_gather_cfg(), key=5, l=128)
+    data = SyntheticMLM(SyntheticMLMConfig(vocab_size=1000, seq_len=128, seed=4))
+    batch = {k: jnp.asarray(v) for k, v in data.batch(16, seed=11).items()}
+    got = jax.jit(make_bert_eval_metrics(model))(params, {}, batch)
+    want = jax.jit(make_bert_eval_metrics(model, mask_prob=0.5))(params, {}, batch)
+    masked = batch["mlm_targets"] >= 0
+    assert got["mlm_loss"][1] == want["mlm_loss"][1] == masked.sum()
+    jax.tree.map(lambda a, b: np.testing.assert_allclose(a, b, rtol=1e-5), got, want)
+    # and both are the cross-entropy of the logits the model serves
+    logits, _ = model.apply(
+        {"params": params}, batch["input_ids"], batch["attention_mask"],
+        batch["token_type_ids"], train=False,
+    )
+    ce = optax.softmax_cross_entropy_with_integer_labels(
+        logits, jnp.maximum(batch["mlm_targets"], 0)
+    )
+    np.testing.assert_allclose(got["mlm_loss"][0], (ce * masked).sum(), rtol=1e-5)
+
+
+@pytest.mark.parametrize("rows,branch", [((2, 16), False), ((16, 128), True)])
+def test_mlm_head_branches_only_where_the_gather_engages(rows, branch):
+    """At tiny shapes the gather would hold half the rows or more: the head
+    is dense statically, the program of before, with no conditional in it."""
+    b, l = rows
+    model, params = _init(_gather_cfg(), l=l)
+    ids = jnp.zeros((b, l), jnp.int32)
+    batch = {
+        "input_ids": ids, "attention_mask": jnp.ones((b, l), bool),
+        "token_type_ids": ids, "mlm_targets": ids,
+        "nsp_label": jnp.zeros((b,), jnp.int32),
+    }
+    grad = jax.jit(jax.grad(make_bert_pretraining_loss(model), has_aux=True))
+    text = grad.lower(params, {}, batch, jax.random.key(0)).as_text()
+    assert ("stablehlo.case" in text or "stablehlo.if" in text) == branch
 
 
 def test_bert_stale_mode(devices8):

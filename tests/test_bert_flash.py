@@ -56,14 +56,17 @@ def test_train_step_carries_the_scope_names_the_metrics_select():
         make_train_step,
     )
 
+    def zeros_batch(b, l):
+        ids = jnp.zeros((b, l), jnp.int32)
+        return {
+            "input_ids": ids, "attention_mask": jnp.ones((b, l), bool),
+            "token_type_ids": ids, "mlm_targets": ids,
+            "nsp_label": jnp.zeros((b,), jnp.int32),
+        }
+
     model = BertForPreTraining(_cfg(attn_impl="flash"))
-    b, l = 8, 32
-    ids = jnp.zeros((b, l), jnp.int32)
-    batch = {
-        "input_ids": ids, "attention_mask": jnp.ones((b, l), bool),
-        "token_type_ids": ids, "mlm_targets": ids,
-        "nsp_label": jnp.zeros((b,), jnp.int32),
-    }
+    batch = zeros_batch(8, 32)
+    ids = batch["input_ids"]
     params = model.init(
         jax.random.key(0), ids, batch["attention_mask"], ids, train=False
     )["params"]
@@ -77,10 +80,32 @@ def test_train_step_carries_the_scope_names_the_metrics_select():
         create_train_state(params, tx, {}), batch, make_rng(0)
     ).as_text(debug_info=True)
     assert "@jit_per_device_step" in text
+    # as scripts/trace_scopes.py finds a scope: jax writes the outermost one
+    # of a differentiated function as jvp(mlm_head)
     for scope in ("flash_fwd", "flash_dq", "flash_dkv", "mlm_head",
                   "grad_reduce", "clip", "optimizer"):
-        assert re.search(rf'[/"]{scope}/', text), scope
+        assert re.search(rf'[/("]{scope}[/)]', text), scope
     # the kernels keep `attention` innermost (the TPU compiler names the
     # custom call after it), and the head's backward carries its scope too
     assert "/flash_dkv/attention/pallas_call" in text
-    assert re.search(r"transpose\(jvp\(BertForPreTraining\)\)/[^\"]*mlm_head/", text)
+    assert re.search(r"transpose\(jvp\([^\"]*mlm_head[/)]", text)
+    assert "stablehlo.case" not in text  # 256 rows: the head is dense here
+
+    # At a shape where the head gathers its masked rows, the scope still
+    # covers the index build, both branches with their backward passes (taken
+    # inside the branches) and the scaling by the loss's cotangent.
+    # 128 x 64: 1,024 rows a device on the eight-way data mesh
+    text = step.lower(
+        create_train_state(params, tx, {}), zeros_batch(128, 64), make_rng(0)
+    ).as_text(debug_info=True)
+    names = set(re.findall(r'loc\("([^"]+)"', text))
+    head = {n for n in names if re.search(r"[/(]mlm_head[/)]", n)}
+    assert any(n.endswith("/cond/branch_1_fun/jit(sort)") for n in head)
+    for branch in ("branch_0_fun", "branch_1_fun"):
+        for way in ("jvp(", "transpose(jvp("):
+            want = f"/cond/{branch}/{way}BertForPreTraining.mlm_logits)"
+            assert any(want in n and n.endswith("word.attend/dot_general")
+                       for n in head), want
+    assert any(re.search(r"transpose\(jvp\([^)]*mlm_head\)+/mul$", n) for n in head)
+    parts = ("word.attend/", "mlm_transform/", "mlm_ln/", "/cond/branch_")
+    assert all(n in head for n in names if any(p in n for p in parts))

@@ -242,3 +242,73 @@ def test_decode_step_never_moves_its_slot_table(one_chip, kv):
     ma = compiled.memory_analysis()
     assert ma.temp_size_in_bytes < 0.2e9, ma.temp_size_in_bytes  # was 3.546e9
     assert ma.alias_size_in_bytes >= 2 * table_bytes
+
+
+# -------------------------------------- the MLM head over the masked rows
+
+def test_mlm_head_gathers_its_rows_at_the_cell_shape(one_chip):
+    """benchmarks/workloads/bert_base.pretrain_L512: 64 x 512 positions of
+    BERT-base in bf16, masked at 15%. The head's value and gradients compile
+    as one conditional whose gathered branch runs the tied decoder over 5,504
+    rows and pays nothing for the dense one: differentiating through the
+    branch instead would have it write the dense branch's residuals, the
+    [32768, 30522] logits, as zeros. A compile, not a time."""
+    import re
+
+    from distributed_tensorflow_tpu.models.bert import (
+        BertConfig,
+        BertForPreTraining,
+        _mlm_head_stats,
+        mlm_gather_rows,
+    )
+
+    rows, vocab = 64 * 512, 30522
+    k_rows = mlm_gather_rows(rows, 0.15)
+    assert k_rows == 5504
+    model = BertForPreTraining(BertConfig(dtype=jnp.bfloat16, num_layers=1))
+    head_stats = _mlm_head_stats(model, 0.15)
+
+    def struct(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    ids = jnp.zeros((1, 8), jnp.int32)
+    params = jax.tree.map(
+        lambda x: struct(x.shape, x.dtype),
+        jax.eval_shape(
+            lambda: model.init(
+                jax.random.PRNGKey(0), ids, jnp.ones((1, 8), bool), ids
+            )["params"]
+        ),
+    )
+
+    def mlm_loss(params, hidden, targets):
+        num, den, _, share = head_stats(params, hidden, targets)
+        return num / jnp.maximum(den, 1.0), share
+
+    compiled = (
+        jax.jit(jax.value_and_grad(mlm_loss, argnums=(0, 1), has_aux=True))
+        .lower(
+            params, struct((64, 512, 768), jnp.bfloat16),
+            struct((64, 512), jnp.int32),
+        )
+        .compile()
+    )
+    text = compiled.as_text()
+    (branches,) = re.findall(r"conditional\(.*branch_computations=\{([^}]*)\}", text)
+    dense, gathered = (
+        # a computation's text: from its header to the closing brace
+        text[text.index(f"\n{name.strip()} ("):].split("\n}\n", 1)[0]
+        for name in branches.split(",")
+    )
+
+    def results(body, dims):  # instructions whose result has these dims
+        return re.findall(
+            r"= \(?\w+\[%s\]\S* ([a-z][a-z\-]*)\(" % dims, body
+        )
+
+    assert results(dense, f"{rows},{vocab}")
+    assert results(gathered, f"{k_rows},{vocab}")  # the decoder's 5,504 rows
+    # nothing of the dense head's size in the gathered branch, zeros or other
+    assert not results(gathered, rf"(?:\d+,)*{rows},{vocab}")
+    assert not results(gathered, rf"64,512,{vocab}")
+    assert "broadcast" not in results(gathered, rf"(?:\d+,)+{vocab}")
