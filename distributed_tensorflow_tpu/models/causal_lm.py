@@ -10,73 +10,48 @@ Param leaf names intentionally match BERT's (``query``/``key``/``value``/
 :func:`bert_param_specs`' suffix rules shard this model unchanged —
 :func:`causal_param_specs` just delegates.
 
-Three forwards share one param tree:
+Five forwards share one param tree:
 
 - ``__call__(input_ids, attention_mask) -> logits [B, L, V]`` — the full
   causally-masked forward: training loss, scoring, and the one-shot
   reference the serving decode path is tested against.
 - ``prefill(input_ids, attention_mask) -> (logits, k [nl,B,L,h,d], v)`` —
   same math, but also returns every layer's projected K/V so the serving
-  engine can scatter them into its slot cache (serve/engine.py
-  ``CausalLMEngine``).
-- ``decode_step(token [S], position [S], k_cache, v_cache) -> (logits [S,V],
-  k_cache', v_cache')`` — ONE token per cache slot: embed at the slot's
-  position, write the new K/V at ``position``, attend positions
-  ``<= position``. Shapes are fixed by the slot count, so slot
-  assignment/reuse never retraces (the "fixed pool of per-slot cache
-  pages" contract). It writes by select, not by scatter (below).
-- ``prefill_chunk(input_ids [B, C], positions [B, C], k_cache, v_cache) ->
-  (logits [B, C, V], k_cache', v_cache')`` — a CHUNK of each row's prompt
-  at arbitrary ABSOLUTE positions against per-row caches ``[nl, B, Lc, h,
-  d]``: write the chunk's K/V at ``positions``, attend the cache causally
-  (each query sees positions ``<= its own``). One method covers both
-  prefix-cache suffix prefill (one chunk starting at ``cached_len``) and
-  fixed-size chunked prefill of long prompts; padding lanes carry the
-  out-of-range sentinel position ``Lc`` so their cache writes drop
-  (``mode="drop"``) while attention/embedding use the clamped position.
-- ``verify_step(tokens [S, K+1], positions [S, K+1], k_cache, v_cache) ->
-  (logits [S, K+1, V], k_cache', v_cache')`` — speculative decoding's
-  batched verify: score a slot's last verified token plus up to K draft
-  tokens in ONE dispatch. Same math as ``prefill_chunk`` (it delegates),
-  which is the point: column j's logits are bit-identical to what
-  ``decode_step`` would produce after j accepted tokens, so greedy
-  accept-matching preserves the exact non-speculative stream.
+  engine can write them into its slot cache (``kvcache.write_prompt``,
+  from serve/engine.py ``CausalLMEngine``).
+
+The other three take and return ONE ``cache``: a pytree whose every leaf is
+``[nl, slots, positions, *trailing]``. What the leaves are (dense K/V, or
+int8 payloads with their scales) is models/kvcache.py's business alone; the
+methods here are written once, over its operations.
+
+- ``decode_step(token [S], position [S], cache) -> (logits [S,V], cache')``
+  — ONE token per cache slot: embed at the slot's position, write the new
+  K/V at ``position``, attend positions ``<= position``. Shapes are fixed
+  by the slot count, so slot assignment/reuse never retraces (the "fixed
+  pool of per-slot cache pages" contract). It writes by select, not by
+  scatter (models/kvcache.py, "Why decode_step writes by select").
+- ``prefill_chunk(input_ids [B, C], positions [B, C], cache) -> (logits
+  [B, C, V], cache')`` — a CHUNK of each row's prompt at arbitrary ABSOLUTE
+  positions against per-row caches ``[nl, B, Lc, ..]``: write the chunk's
+  K/V at ``positions``, attend the cache causally (each query sees
+  positions ``<= its own``). One method covers both prefix-cache suffix
+  prefill (one chunk starting at ``cached_len``) and fixed-size chunked
+  prefill of long prompts; padding lanes carry the out-of-range sentinel
+  position ``Lc`` so their cache writes drop (``mode="drop"``) while
+  attention/embedding use the clamped position.
+- ``verify_step(tokens [S, K+1], positions [S, K+1], cache) -> (logits
+  [S, K+1, V], cache')`` — speculative decoding's batched verify: score a
+  slot's last verified token plus up to K draft tokens in ONE dispatch.
+  Same math as ``prefill_chunk`` (it delegates), which is the point:
+  column j's logits are bit-identical to what ``decode_step`` would
+  produce after j accepted tokens, so greedy accept-matching preserves the
+  exact non-speculative stream.
 
 Numerics: both attention paths accumulate scores and context in f32 with
 the same masking convention (fully-masked rows -> exactly 0), so a token
 decoded step-by-step matches the full forward's argmax at the same
 position — tests/test_serve_decode.py pins greedy parity exactly.
-
-Why decode_step writes by select. On the TPU the slot table ``[nl, S, L, h,
-d]`` lives with the cache POSITION minor-most (layout ``{2,4,3,1,0}``:
-``d x L`` tiles without padding, ``h x d`` would not), which is the layout
-the attention einsums read. The ``scatter`` and ``dynamic-update-slice``
-emitters want ``{4,3,..}`` instead, so the compiler brackets every such
-write with two copies of whatever table it writes. Compiled for a described
-v5e at the serving benchmark's geometry (bf16, 128 slots, cache 384, tables
-donated; ``memory_analysis().temp_size_in_bytes``):
-
-- per layer ``table[i].at[idx, position].set(..)``, then re-stack (the
-  spelling until PR 28): 48 copies of a layer table a step, 2 slicing
-  fusions, 24 scatters, 24 re-stacking updates — 3.55 GB;
-- one stacked scatter ``table.at[:, idx, position].set(..)`` at the end: the
-  whole table copied there and back — 2.45 GB; the same for a loop of
-  per-slot ``dynamic_update_slice``, rolled or unrolled — 2.45 GB;
-- the stacked table carried through the layers with ``.at[i, idx,
-  position].set(..)``: the whole program flips layout, 24 full-table
-  scatters — 7.26 GB;
-- what is here: each layer attends ``where(position_hit, new_row,
-  table[i])`` (slice and select fuse into the attention loop; no layer
-  table exists), and the ``[nl, S, h, d]`` of new rows are written once, by
-  one select over the stacked table that aliases its donated operand —
-  0.026 GB, no table-sized copy, slice, scatter or update (int8 KV: 0.028).
-
-The select passes over the whole table to write ``nl x S`` rows; that one
-pass is what the layout costs, and PERF.md (PR 28) has its time on the chip.
-The operand values are those of write-then-attend, bit for bit
-(tests/test_decode_kv_write.py); tests/test_chip_compile.py keeps the
-compiled program free of the copies. ``prefill_chunk`` / ``verify_step``
-still slice, scatter and re-stack, and have the copies by construction.
 """
 
 from __future__ import annotations
@@ -87,49 +62,8 @@ import jax
 import jax.numpy as jnp
 from flax import linen as nn
 
+from distributed_tensorflow_tpu.models import kvcache
 from distributed_tensorflow_tpu.models.bert import _tp_psum, bert_param_specs
-from distributed_tensorflow_tpu.models.quant import quantize_kv
-
-_MASK_VALUE = -1e30
-
-
-def _layer_cache(cache, i):
-    """Slice layer ``i`` out of a stacked cache — plain ``[nl, ...]`` array
-    or the quantized ``{"q", "s"}`` pytree (models/quant.py)."""
-    if isinstance(cache, dict):
-        return {"q": cache["q"][i], "s": cache["s"][i]}
-    return cache[i]
-
-
-def _stack_cache(layers):
-    """Stack per-layer cache returns, preserving the quantized pytree
-    structure when present. Part of ``kv_write``: the per-layer tables the
-    scatters of ``prefill_chunk`` produced become the new slot table, and the
-    per-layer rows of ``decode_step`` the ``[nl, S, h, d]`` it writes."""
-    with jax.named_scope("kv_write"):
-        if isinstance(layers[0], dict):
-            return {
-                "q": jnp.stack([c["q"] for c in layers]),
-                "s": jnp.stack([c["s"] for c in layers]),
-            }
-        return jnp.stack(layers)
-
-
-def _select_rows(table, rows, position, slot_axis):
-    """``table`` with ``rows`` at each slot's ``position``, as a select —
-    never a scatter (module docstring). ``table`` is ``[.., S, L, ..]`` with
-    the slots at ``slot_axis`` and the cache positions after them, ``rows``
-    the same without the position axis, ``position: [S]``; both may be the
-    int8 ``{"q", "s"}`` pytree, whose leaves differ only in trailing axes.
-    A position of ``L`` or more matches nothing: the slot keeps its pages.
-    """
-
-    def leaf(t, r):
-        hit = jnp.arange(t.shape[slot_axis + 1]) == position[:, None]  # [S, L]
-        hit = hit.reshape(hit.shape + (1,) * (t.ndim - slot_axis - 2))
-        return jnp.where(hit, jnp.expand_dims(r, slot_axis + 1), t)
-
-    return jax.tree.map(leaf, table, rows)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -173,85 +107,10 @@ def _causal_attention(q, k, v, pad_mask):
     l = q.shape[1]
     causal = jnp.tril(jnp.ones((l, l), bool))
     m = causal[None, None, :, :] & pad_mask[:, None, None, :]
-    s = jnp.where(m, s, _MASK_VALUE)
+    s = jnp.where(m, s, kvcache.MASK_VALUE)
     p = jax.nn.softmax(s, axis=-1) * m
     return jnp.einsum(
         "bhlk,bkhd->blhd", p.astype(v.dtype), v,
-        preferred_element_type=jnp.float32,
-    ).astype(q.dtype)
-
-
-def _cached_attention(q, k_cache, v_cache, position, k_scale=None,
-                      v_scale=None):
-    """One-token-per-slot attention against the slot cache.
-
-    ``q: [S, h, d]``; caches ``[S, Lmax, h, d]``; ``position: [S]`` — the
-    index the newest token was just written at (attends ``<= position``).
-    ``k_scale``/``v_scale`` (``[S, Lmax]``) carry the int8 cache's
-    per-position dequant factors: the k-scale multiplies the score matrix
-    after the QK^T product and the v-scale folds into the softmax weights
-    before the context product, so the dense cache is never materialized.
-    """
-    with jax.named_scope("cached_attention"):
-        scale = q.shape[-1] ** -0.5
-        kc = k_cache if k_scale is None else k_cache.astype(jnp.float32)
-        s = jnp.einsum(
-            "shd,slhd->shl", q, kc, preferred_element_type=jnp.float32
-        )
-        if k_scale is not None:
-            s = s * k_scale[:, None, :]
-        s = s * scale
-        valid = jnp.arange(k_cache.shape[1])[None, :] <= position[:, None]
-        s = jnp.where(valid[:, None, :], s, _MASK_VALUE)
-        p = jax.nn.softmax(s, axis=-1) * valid[:, None, :]
-        vc = v_cache
-        if v_scale is not None:
-            p = p * v_scale[:, None, :]
-            vc = v_cache.astype(jnp.float32)
-        return jnp.einsum(
-            "shl,slhd->shd", p.astype(vc.dtype), vc,
-            preferred_element_type=jnp.float32,
-        ).astype(q.dtype)
-
-
-def _chunk_attention(q, k_cache, v_cache, position, k_scale=None,
-                     v_scale=None):
-    """Chunk-of-queries attention against per-row caches.
-
-    ``q: [B, C, h, d]``; caches ``[B, Lc, h, d]``; ``position: [B, C]`` —
-    the (clamped) cache index each query was written at; each attends
-    ``<= its own position``. Same f32 score/context accumulation and
-    exactly-0 masking as ``_cached_attention``, so a prompt prefilled in
-    chunks matches the full forward's argmax position-for-position.
-    Cache positions beyond a row's written length hold zeros or a prior
-    occupant's values — finite either way, and their softmax weight is
-    exactly 0 under the causal mask, so they never reach the output.
-    ``k_scale``/``v_scale`` (``[B, Lc]``): the int8 cache's per-position
-    dequant factors, applied in the SAME factored order as
-    ``_cached_attention`` so verify columns stay bit-identical to the
-    decode steps they replace under quantization.
-    """
-    scale = q.shape[-1] ** -0.5
-    kc = k_cache if k_scale is None else k_cache.astype(jnp.float32)
-    s = jnp.einsum(
-        "bchd,blhd->bhcl", q, kc, preferred_element_type=jnp.float32
-    )
-    if k_scale is not None:
-        s = s * k_scale[:, None, None, :]
-    s = s * scale
-    valid = (
-        jnp.arange(k_cache.shape[1])[None, None, :]
-        <= position[:, :, None]
-    )  # [B, C, Lc]
-    m = valid[:, None, :, :]
-    s = jnp.where(m, s, _MASK_VALUE)
-    p = jax.nn.softmax(s, axis=-1) * m
-    vc = v_cache
-    if v_scale is not None:
-        p = p * v_scale[:, None, None, :]
-        vc = v_cache.astype(jnp.float32)
-    return jnp.einsum(
-        "bhcl,blhd->bchd", p.astype(vc.dtype), vc,
         preferred_element_type=jnp.float32,
     ).astype(q.dtype)
 
@@ -293,80 +152,33 @@ class CausalSelfAttention(nn.Module):
         # the slot cache, so the decode path attends identical values.
         return self._finish(x, ctx), k, v
 
-    def decode(self, x, k_cache, v_cache, position):
+    def decode(self, x, cache, position):
         """One token per slot against this layer's table AS THE STEP FOUND
-        IT. Returns ``(x', k_row, v_row)``: the rows ``[S, h, d]`` (or the
-        int8 ``{"q", "s"}`` pair) the step has to write at ``position``,
-        which ``CausalLM.decode_step`` writes for all layers at once.
-        Attention reads the table with the row selected in, the operand
-        values of write-then-attend without the write."""
+        IT. Returns ``(x', rows)``: the rows ``[S, ..]``, in the table's
+        form, that the step has to write at ``position``, which
+        ``CausalLM.decode_step`` writes for all layers at once. Attention
+        reads the table with the rows selected in, the operand values of
+        write-then-attend without the write."""
         # position == Lmax marks an idle lane: no cache position matches, so
         # nothing is written (writing anywhere could corrupt a mid-chunk-
         # prefill slot's pages) and its attention clamps — the lane's
         # output is garbage nobody reads.
         q, k, v = self.query(x), self.key(x), self.value(x)  # [S, h, d]
-        with jax.named_scope("kv_write"):
-            if isinstance(k_cache, dict):
-                # int8 KV mode: quantize the new token per slot at the
-                # write, attend with the factored per-position scales.
-                k_row = dict(zip(("q", "s"), quantize_kv(k)))
-                v_row = dict(zip(("q", "s"), quantize_kv(v)))
-            else:
-                k_row = k.astype(k_cache.dtype)
-                v_row = v.astype(v_cache.dtype)
+        rows = kvcache.encode(cache, k, v)
         with jax.named_scope("cached_attention"):
-            k_read = _select_rows(k_cache, k_row, position, slot_axis=0)
-            v_read = _select_rows(v_cache, v_row, position, slot_axis=0)
-        if isinstance(k_cache, dict):
-            ctx = _cached_attention(
-                q, k_read["q"], v_read["q"],
-                jnp.minimum(position, k_read["q"].shape[1] - 1),
-                k_scale=k_read["s"], v_scale=v_read["s"],
-            )
-        else:
-            ctx = _cached_attention(
-                q, k_read, v_read,
-                jnp.minimum(position, k_read.shape[1] - 1),
-            )
-        return self._finish(x, ctx), k_row, v_row
+            read = kvcache.select_rows(cache, rows, position, slot_axis=0)
+        ctx = kvcache.cached_attention(q, read, position)
+        return self._finish(x, ctx), rows
 
-    def prefill_chunk(self, x, positions, k_cache, v_cache):
+    def prefill_chunk(self, x, positions, cache):
         # x [B, C, H]; positions [B, C] absolute (sentinel == Lc on
-        # padding lanes -> the scatter drops); caches [B, Lc, h, d].
+        # padding lanes -> the scatter drops); cache [B, Lc, ..].
         q, k, v = self.query(x), self.key(x), self.value(x)  # [B, C, h, d]
-        rows = jnp.arange(x.shape[0])[:, None]
-        if isinstance(k_cache, dict):
-            # int8 KV mode, chunk-wise: per-(row, position) scales written
-            # with the pages keep verify columns bit-identical to the
-            # decode steps they stand in for (same quantize-at-write, same
-            # factored dequant order).
-            qk, sk = quantize_kv(k)
-            qv, sv = quantize_kv(v)
-            k_cache = {
-                "q": k_cache["q"].at[rows, positions].set(qk, mode="drop"),
-                "s": k_cache["s"].at[rows, positions].set(sk, mode="drop"),
-            }
-            v_cache = {
-                "q": v_cache["q"].at[rows, positions].set(qv, mode="drop"),
-                "s": v_cache["s"].at[rows, positions].set(sv, mode="drop"),
-            }
-            ctx = _chunk_attention(
-                q, k_cache["q"], v_cache["q"],
-                jnp.minimum(positions, k_cache["q"].shape[1] - 1),
-                k_scale=k_cache["s"], v_scale=v_cache["s"],
-            )
-            return self._finish(x, ctx), k_cache, v_cache
-        k_cache = k_cache.at[rows, positions].set(
-            k.astype(k_cache.dtype), mode="drop"
+        cache = kvcache.scatter_rows(
+            cache, kvcache.encode(cache, k, v), positions
         )
-        v_cache = v_cache.at[rows, positions].set(
-            v.astype(v_cache.dtype), mode="drop"
-        )
-        ctx = _chunk_attention(
-            q, k_cache, v_cache,
-            jnp.minimum(positions, k_cache.shape[1] - 1),
-        )
-        return self._finish(x, ctx), k_cache, v_cache
+        ctx = kvcache.chunk_attention(q, cache, positions)
+        return self._finish(x, ctx), cache
 
 
 class CausalLmLayer(nn.Module):
@@ -402,17 +214,13 @@ class CausalLmLayer(nn.Module):
         x, k, v = self.attention(x, pad_mask)
         return self._ffn(x), k, v
 
-    def decode(self, x, k_cache, v_cache, position):
-        x, k_row, v_row = self.attention.decode(
-            x, k_cache, v_cache, position
-        )
-        return self._ffn(x), k_row, v_row
+    def decode(self, x, cache, position):
+        x, rows = self.attention.decode(x, cache, position)
+        return self._ffn(x), rows
 
-    def prefill_chunk(self, x, positions, k_cache, v_cache):
-        x, k_cache, v_cache = self.attention.prefill_chunk(
-            x, positions, k_cache, v_cache
-        )
-        return self._ffn(x), k_cache, v_cache
+    def prefill_chunk(self, x, positions, cache):
+        x, cache = self.attention.prefill_chunk(x, positions, cache)
+        return self._ffn(x), cache
 
 
 class CausalLM(nn.Module):
@@ -475,30 +283,25 @@ class CausalLM(nn.Module):
             vs.append(v)
         return self._head(x), jnp.stack(ks), jnp.stack(vs)
 
-    def decode_step(self, token, position, k_cache, v_cache):
+    def decode_step(self, token, position, cache):
         # Clamp for the position-embedding lookup only; the raw (possibly
         # idle-lane sentinel) position drives the layers' dropped writes.
         x = self._embed(
             token, jnp.minimum(position, self.cfg.max_position - 1)
         )  # [S, H]
-        k_rows, v_rows = [], []
+        rows = []
         for i, layer in enumerate(self.layers):
-            x, k_row, v_row = layer.decode(
-                x, _layer_cache(k_cache, i), _layer_cache(v_cache, i),
-                position,
-            )
-            k_rows.append(k_row)
-            v_rows.append(v_row)
-        # Every layer read the step's INPUT table; the [nl, S, h, d] of new
-        # rows go into it here, once, in the layout it lives in (module
-        # docstring, "Why decode_step writes by select").
-        k_rows, v_rows = _stack_cache(k_rows), _stack_cache(v_rows)
+            x, row = layer.decode(x, kvcache.take_layer(cache, i), position)
+            rows.append(row)
+        # Every layer read the step's INPUT table; the [nl, S, ..] of new
+        # rows go into it here, once, in the layout it lives in
+        # (models/kvcache.py, "Why decode_step writes by select").
+        rows = kvcache.stack_layers(rows)
         with jax.named_scope("kv_write"):
-            k_cache = _select_rows(k_cache, k_rows, position, slot_axis=1)
-            v_cache = _select_rows(v_cache, v_rows, position, slot_axis=1)
-        return self._head(x), k_cache, v_cache
+            cache = kvcache.select_rows(cache, rows, position, slot_axis=1)
+        return self._head(x), cache
 
-    def prefill_chunk(self, input_ids, positions, k_cache, v_cache):
+    def prefill_chunk(self, input_ids, positions, cache):
         # Absolute-position chunk prefill against the slot cache: caches
         # ahead of a row's written length may hold garbage, but the causal
         # mask gives them exactly-0 weight and every such page is
@@ -506,19 +309,18 @@ class CausalLM(nn.Module):
         # anything attends it — the same dead-store argument decode_step
         # relies on for slot reuse. Positions are clamped for embedding /
         # attention; raw (possibly sentinel) positions drive the writes.
-        Lc = (k_cache["q"] if isinstance(k_cache, dict) else k_cache).shape[2]
-        x = self._embed(input_ids, jnp.minimum(positions, Lc - 1))
-        new_k, new_v = [], []
+        x = self._embed(
+            input_ids, jnp.minimum(positions, kvcache.cache_len(cache) - 1)
+        )
+        layers = []
         for i, layer in enumerate(self.layers):
-            x, kc, vc = layer.prefill_chunk(
-                x, positions, _layer_cache(k_cache, i),
-                _layer_cache(v_cache, i)
+            x, table = layer.prefill_chunk(
+                x, positions, kvcache.take_layer(cache, i)
             )
-            new_k.append(kc)
-            new_v.append(vc)
-        return self._head(x), _stack_cache(new_k), _stack_cache(new_v)
+            layers.append(table)
+        return self._head(x), kvcache.stack_layers(layers)
 
-    def verify_step(self, tokens, positions, k_cache, v_cache):
+    def verify_step(self, tokens, positions, cache):
         # Speculative-decoding verify over the slot table: [S, K+1] tokens
         # at absolute positions against per-slot caches. Column 0 is each
         # slot's last verified token re-scored at its current position;
@@ -529,7 +331,7 @@ class CausalLM(nn.Module):
         # place. K/V written for columns past the accepted prefix sit
         # beyond the rolled-back slot position: masked dead, overwritten by
         # the slot's next real tokens — rollback costs nothing.
-        return self.prefill_chunk(tokens, positions, k_cache, v_cache)
+        return self.prefill_chunk(tokens, positions, cache)
 
 
 def sample_tokens(logits, temperature, seed, step):

@@ -53,6 +53,7 @@ import numpy as np
 
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+from distributed_tensorflow_tpu.models import kvcache
 from distributed_tensorflow_tpu.models.causal_lm import sample_tokens
 from distributed_tensorflow_tpu.models.quant import (
     cast_params,
@@ -60,7 +61,6 @@ from distributed_tensorflow_tpu.models.quant import (
     fp32_equiv_nbytes,
     is_quantized_tree,
     normalize_quant_dtype,
-    quantize_kv,
     quantize_params,
 )
 from distributed_tensorflow_tpu.obs.memory import default_registry, tree_nbytes
@@ -722,55 +722,31 @@ class BertInferenceEngine(_AotEngine):
         return results
 
 
-def _kv_leaf(cache):
-    """The payload leaf of a KV operand: the int8 ``"q"`` array of a
-    quantized ``{"q", "s"}`` pytree, or the plain dense array. Geometry
-    (layers/slots/cache_len/heads/head_dim) is always read off this leaf so
-    shape logic is mode-agnostic."""
-    return cache["q"] if isinstance(cache, dict) else cache
+# -- LM grid executable bodies. A cache (slot table, prefix pool, a stage
+# -- of either) is ONE pytree operand whose every leaf is [layers, slots or
+# -- blocks, positions, *trailing]; these bodies address the first three
+# -- axes and map over the leaves. What the leaves are is models/kvcache.py's.
 
 
 def _make_causal_prefill(model):
     """Prefill executable body for one (tier, bucket): run the full causal
-    forward, scatter every layer's K/V into the slot cache pages, and
-    sample each row's FIRST generated token on-device.
+    forward, write every layer's K/V into the admitted rows' slot pages
+    (``kvcache.write_prompt``: padding rows drop, pages are encoded as the
+    decode path would have), and sample each row's FIRST generated token
+    on-device."""
 
-    Tier padding rows carry slot index == S (one past the pool) so the
-    ``mode="drop"`` scatters write nowhere — padding can never dirty a
-    live slot's pages.
-
-    Quantized caches (``{"q", "s"}`` pytrees) quantize the fresh fp32 K/V
-    at the scatter — same per-position absmax the incremental decode write
-    uses, so a prefilled page is bit-identical to one the decode path
-    would have written."""
-
-    def prefill_fn(params, ck, cv, last, ids, mask, slots, lengths, temps,
+    def prefill_fn(params, cache, last, ids, mask, slots, lengths, temps,
                    seeds):
         params = dequantize_params(params, model.cfg.dtype)
-        logits, k, v = model.apply(
+        logits, *fresh = model.apply(
             {"params": params}, ids, mask, method="prefill"
         )
         rows = jnp.arange(ids.shape[0])
         last_logits = logits[rows, jnp.maximum(lengths, 1) - 1]
         tok = sample_tokens(last_logits, temps, seeds, lengths)
-        L = ids.shape[1]
-
-        def scatter(cache, fresh):
-            if isinstance(cache, dict):
-                q, s = quantize_kv(fresh)  # [nl, T, L, h, d] -> s [nl, T, L]
-                return {
-                    "q": cache["q"].at[:, slots, :L].set(q, mode="drop"),
-                    "s": cache["s"].at[:, slots, :L].set(s, mode="drop"),
-                }
-            return cache.at[:, slots, :L].set(
-                fresh.astype(cache.dtype), mode="drop"
-            )
-
-        with jax.named_scope("kv_write"):
-            ck = scatter(ck, k)
-            cv = scatter(cv, v)
+        cache = kvcache.write_prompt(cache, slots, *fresh)
         last = last.at[slots].set(tok, mode="drop")
-        return ck, cv, last, tok
+        return cache, last, tok
 
     return prefill_fn
 
@@ -784,17 +760,17 @@ def _make_causal_decode(model, cache_len: int):
     steps inactive, and a stray write would corrupt pages its earlier
     chunks already filled (chunked prefill never re-writes them)."""
 
-    def decode_fn(params, ck, cv, last, lengths, active, temps, seeds):
+    def decode_fn(params, cache, last, lengths, active, temps, seeds):
         params = dequantize_params(params, model.cfg.dtype)
         pos = jnp.where(
             active, jnp.minimum(lengths, cache_len - 1), cache_len
         )
-        logits, ck, cv = model.apply(
-            {"params": params}, last, pos, ck, cv, method="decode_step"
+        logits, cache = model.apply(
+            {"params": params}, last, pos, cache, method="decode_step"
         )
         tok = sample_tokens(logits, temps, seeds, lengths + 1)
         last = jnp.where(active, tok, last)
-        return ck, cv, last, tok
+        return cache, last, tok
 
     return decode_fn
 
@@ -819,15 +795,15 @@ def _make_causal_verify(model, cache_len: int, k: int):
     K/V written past ``lengths + m`` are dead stores the rolled-back slot
     position masks; the host rollback is just not advancing its length."""
 
-    def verify_fn(params, ck, cv, last, drafts, lengths, n_input, temps,
+    def verify_fn(params, cache, last, drafts, lengths, n_input, temps,
                   seeds):
         params = dequantize_params(params, model.cfg.dtype)
         tokens = jnp.concatenate([last[:, None], drafts], axis=1)  # [S, k+1]
         cols = jnp.arange(k + 1)[None, :]
         pos = lengths[:, None] + cols
         wpos = jnp.where(cols < n_input[:, None], pos, cache_len)
-        logits, ck, cv = model.apply(
-            {"params": params}, tokens, wpos, ck, cv, method="verify_step"
+        logits, cache = model.apply(
+            {"params": params}, tokens, wpos, cache, method="verify_step"
         )
         # Column j's sampling key is position lengths + j + 1 — exactly the
         # key the (j+1)-th plain decode step after this point would fold
@@ -842,12 +818,12 @@ def _make_causal_verify(model, cache_len: int, k: int):
         m = jnp.sum(jnp.cumprod(matches.astype(jnp.int32), axis=1), axis=1)
         new_last = tok[jnp.arange(tok.shape[0]), m]
         last = jnp.where(n_input > 0, new_last, last)
-        return ck, cv, last, tok
+        return cache, last, tok
 
     return verify_fn
 
 
-def _make_causal_chunk_prefill(model, cache_len: int):
+def _make_causal_chunk_prefill(model, cache_len: int, block_tokens: int):
     """Chunk-prefill executable body for one (tier, chunk bucket): a fused
     page-gather prologue + one absolute-position prompt chunk + on-device
     first-token sampling where the chunk completes its row's prompt.
@@ -869,44 +845,32 @@ def _make_causal_chunk_prefill(model, cache_len: int):
     keyed on absolute position exactly like the monolithic prefill — bit
     parity with the cold path follows."""
 
-    def chunk_fn(params, ck, cv, last, pool_k, pool_v, ids, starts,
-                 lengths, chain, n_gather, slots, temps, seeds):
+    def chunk_fn(params, cache, last, pool, ids, starts, lengths, chain,
+                 n_gather, slots, temps, seeds):
         params = dequantize_params(params, model.cfg.dtype)
-        nl = _kv_leaf(ck).shape[0]
         T, C = ids.shape
-        # Quantized caches are {"q","s"} pytrees: every gather/blend/scatter
-        # below maps over both leaves, so prefix pages move WITH their
-        # scales bit-exactly (the cached-vs-cold parity contract).
-        rows_k = jax.tree.map(lambda a: a[:, slots], ck)  # padding ix clamps
-        rows_v = jax.tree.map(lambda a: a[:, slots], cv)
-        bt = _kv_leaf(pool_k).shape[2]
-        M = chain.shape[1]
-        span = M * bt
-        sel_rows = jnp.arange(span)[None, :] < (n_gather * bt)[:, None]
+        span = chain.shape[1] * block_tokens
+        sel_rows = (
+            jnp.arange(span)[None, :] < (n_gather * block_tokens)[:, None]
+        )
 
-        def blend(rows, pool):
-            def one(r, p):
-                g = p[:, chain].reshape(nl, T, span, *p.shape[3:])
-                sel = sel_rows.reshape((1, T, span) + (1,) * (p.ndim - 3))
-                return r.at[:, :, :span].set(
-                    jnp.where(sel, g, r[:, :, :span])
-                )
+        # Every gather / blend / scatter maps over all leaves, so prefix
+        # pages move WITH their scales bit-exactly (the cached-vs-cold
+        # parity contract).
+        def blend(r, p):
+            g = p[:, chain].reshape(p.shape[0], T, span, *p.shape[3:])
+            sel = sel_rows.reshape((1, T, span) + (1,) * (p.ndim - 3))
+            return r.at[:, :, :span].set(jnp.where(sel, g, r[:, :, :span]))
 
-            return jax.tree.map(one, rows, pool)
-
-        rows_k = blend(rows_k, pool_k)
-        rows_v = blend(rows_v, pool_v)
+        rows = jax.tree.map(lambda a: a[:, slots], cache)  # padding ix clamps
+        rows = jax.tree.map(blend, rows, pool)
         pos = starts[:, None] + jnp.arange(C)[None, :]
         wpos = jnp.where(pos < lengths[:, None], pos, cache_len)
-        logits, nk, nv = model.apply(
-            {"params": params}, ids, wpos, rows_k, rows_v,
-            method="prefill_chunk",
+        logits, rows = model.apply(
+            {"params": params}, ids, wpos, rows, method="prefill_chunk"
         )
-        ck = jax.tree.map(
-            lambda c, n: c.at[:, slots].set(n, mode="drop"), ck, nk
-        )
-        cv = jax.tree.map(
-            lambda c, n: c.at[:, slots].set(n, mode="drop"), cv, nv
+        cache = jax.tree.map(
+            lambda c, n: c.at[:, slots].set(n, mode="drop"), cache, rows
         )
         is_last = starts + C >= lengths
         li = jnp.clip(lengths - 1 - starts, 0, C - 1)
@@ -915,113 +879,82 @@ def _make_causal_chunk_prefill(model, cache_len: int):
         )
         upd = jnp.where(is_last, tok, jnp.take(last, slots, mode="clip"))
         last = last.at[slots].set(upd, mode="drop")
-        return ck, cv, last, tok
+        return cache, last, tok
 
     return chunk_fn
 
 
-def _make_prefix_insert(block_tokens: int):
+def _make_prefix_insert(cache_len: int, block_tokens: int):
     """Publish-to-pool executable body: copy a finished slot's prefix
     pages into newly allocated pool blocks (``block_ids``/``block_pos``
     padded with the out-of-pool sentinel, whose scatters drop).
 
-    The slot caches are DONATED and returned untouched so the donation
+    The slot cache is DONATED and returned untouched so the donation
     chain through the engine's device state stays linear — every
     executable (chunk -> insert -> decode) consumes the previous one's
     outputs, and XLA aliases buffers instead of copying to protect a
     still-referenced operand."""
+    nb = cache_len // block_tokens
 
-    def insert_fn(pool_k, pool_v, ck, cv, slot, block_ids, block_pos):
-        nl, _, lc = _kv_leaf(ck).shape[:3]
-        nb = lc // block_tokens
+    def insert_fn(pool, cache, slot, block_ids, block_pos):
         bp = jnp.minimum(block_pos, nb - 1)
 
-        def publish(pool, cache):
-            def one(p, c):
-                # Works for both ranks: c.shape[3:] is (h, d) for pages and
-                # () for the per-position scale plane.
-                src = c[:, slot, : nb * block_tokens].reshape(
-                    nl, nb, block_tokens, *c.shape[3:]
-                )
-                return p.at[:, block_ids].set(src[:, bp], mode="drop")
+        def publish(p, c):
+            src = c[:, slot, : nb * block_tokens].reshape(
+                c.shape[0], nb, block_tokens, *c.shape[3:]
+            )
+            return p.at[:, block_ids].set(src[:, bp], mode="drop")
 
-            return jax.tree.map(one, pool, cache)
-
-        return publish(pool_k, ck), publish(pool_v, cv), ck, cv
+        return jax.tree.map(publish, pool, cache), cache
 
     return insert_fn
 
 
-def _make_pool_export():
-    """Gather-for-transfer executable body (serve/disagg.py): read a
-    pinned chain's pages out of the prefix pool into a fixed ``[nl,
-    max_chain, block_tokens, heads, head_dim]`` stage (pad lanes repeat
-    block 0; the importer's sentinel ids drop them). The pool operands
-    are NOT donated — export copies, the pool stays live, and the
-    caller's ``KVBlockPool.match`` pin keeps the gathered blocks
-    immutable for the duration."""
+def _make_export():
+    """Gather-for-transfer executable body (serve/disagg.py), compiled at
+    two operand shapes. Over the prefix pool with a ``[max_chain]`` vector
+    of block ids: a pinned chain's pages as a fixed ``[nl, max_chain,
+    block_tokens, ..]`` stage (pad lanes repeat block 0; the importer's
+    sentinel ids drop them). Over the slot table with ONE slot index: a
+    live stream's ``[nl, cache_len, ..]`` lane (stream migration). The
+    operand is NOT donated either way — export copies, the table stays
+    live; for the pool the caller's ``KVBlockPool.match`` pin keeps the
+    gathered blocks immutable for the duration."""
 
-    def export_fn(pool_k, pool_v, block_ids):
-        take = lambda p: jax.tree.map(  # noqa: E731
-            lambda a: jnp.take(a, block_ids, axis=1), p
-        )
-        return take(pool_k), take(pool_v)
+    def export_fn(table, idx):
+        return jax.tree.map(lambda a: jnp.take(a, idx, axis=1), table)
 
     return export_fn
 
 
 def _make_pool_import():
     """Adopt-transferred-pages executable body (serve/disagg.py): scatter
-    a fixed ``[nl, max_chain, block_tokens, heads, head_dim]`` stage of
-    received KV pages into the prefix pool at ``block_ids`` (padded with
-    the out-of-pool sentinel, whose scatters drop — pad lanes carry
-    garbage pages that never land). The pool operands are DONATED like
-    every other executable in the chain; the import dispatches between
-    decode steps on the loop thread, so the decode executable itself is
-    untouched."""
+    a fixed ``[nl, max_chain, block_tokens, ..]`` stage of received KV
+    pages into the prefix pool at ``block_ids`` (padded with the
+    out-of-pool sentinel, whose scatters drop — pad lanes carry garbage
+    pages that never land). The pool is DONATED like every other
+    executable in the chain; the import dispatches between decode steps on
+    the loop thread, so the decode executable itself is untouched."""
 
-    def import_fn(pool_k, pool_v, pages_k, pages_v, block_ids):
-        put = lambda p, g: jax.tree.map(  # noqa: E731
-            lambda a, b: a.at[:, block_ids].set(b, mode="drop"), p, g
+    def import_fn(pool, pages, block_ids):
+        return jax.tree.map(
+            lambda a, b: a.at[:, block_ids].set(b, mode="drop"), pool, pages
         )
-        return put(pool_k, pages_k), put(pool_v, pages_v)
 
     return import_fn
 
 
-def _make_slot_export():
-    """Live-stream checkpoint executable body (serve/disagg.py stream
-    migration): gather ONE slot's lane out of the slot-table KV cache
-    into a ``[nl, cache_len, heads, head_dim]`` stage. The cache operands
-    are NOT donated — export copies between decode steps and the cache
-    stays live (sibling of :func:`_make_pool_export`, at slot instead of
-    pool-block granularity)."""
-
-    def export_fn(ck, cv, slot):
-        take = lambda c: jax.tree.map(  # noqa: E731
-            lambda a: jnp.take(a, slot, axis=1), c
-        )
-        return take(ck), take(cv)
-
-    return export_fn
-
-
 def _make_slot_import():
     """Resume-a-migrated-stream executable body: scatter a received
-    ``[nl, cache_len, heads, head_dim]`` stage into ONE slot's cache lane
-    and seed ``last_token[slot]`` with the stream's newest token, so the
-    very next decode step continues the generation mid-flight. Cache /
-    last_token operands are DONATED like every executable in the decode
-    chain; dispatches between decode steps on the loop thread."""
+    ``[nl, cache_len, ..]`` stage into ONE slot's cache lane and seed
+    ``last_token[slot]`` with the stream's newest token, so the very next
+    decode step continues the generation mid-flight. Cache / last_token
+    operands are DONATED like every executable in the decode chain;
+    dispatches between decode steps on the loop thread."""
 
-    def import_fn(ck, cv, last, stage_k, stage_v, slot, tok):
-        put = lambda c, st: jax.tree.map(  # noqa: E731
-            lambda a, b: a.at[:, slot].set(b), c, st
-        )
-        ck = put(ck, stage_k)
-        cv = put(cv, stage_v)
-        last = last.at[slot].set(tok)
-        return ck, cv, last
+    def import_fn(cache, last, stage, slot, tok):
+        cache = jax.tree.map(lambda a, b: a.at[:, slot].set(b), cache, stage)
+        return cache, last.at[slot].set(tok)
 
     return import_fn
 
@@ -1030,13 +963,16 @@ class CausalLMEngine(_AotEngine):
     """Autoregressive generation over a trained :class:`CausalLM` checkpoint
     with a paged, slot-addressed KV cache.
 
-    The cache is a FIXED pool of per-slot pages — ``k/v: [num_layers,
-    slots, cache_len, heads, head_dim]`` plus a ``last_token [slots]``
-    vector — living on device for the engine's lifetime and threaded
-    functionally through every executable with buffer donation, so each
-    step updates the pool in place and slot assignment/reuse never changes
-    a shape (= never recompiles, the decode analog of the tier grid's
-    "startup pays every compile" rule). The AOT grid is:
+    The cache is a FIXED pool of per-slot pages — one pytree whose every
+    leaf is ``[num_layers, slots, cache_len, *trailing]`` (which leaves, and
+    what trails them, is models/kvcache.py's ``cache_layout``: dense K and
+    V ``(heads, head_dim)``, or int8 payloads with their scales) plus a
+    ``last_token [slots]`` vector — living on device for the engine's
+    lifetime and threaded functionally through every executable with
+    buffer donation, so each step updates the pool in place and slot
+    assignment/reuse never changes a shape (= never recompiles, the decode
+    analog of the tier grid's "startup pays every compile" rule). The AOT
+    grid is:
 
     - ``prefill`` per (batch tier x prompt bucket): the full causal
       forward + a scatter of the prompt's K/V into the admitted rows'
@@ -1063,8 +999,9 @@ class CausalLMEngine(_AotEngine):
     stream is a function of the request, not of its batchmates, so
     continuous batching is bit-identical to a solo run.
 
-    Tensor parallelism (a mesh with a ``model`` axis) shards the head axis
-    of the cache pages and the params per ``causal_param_specs``; batch
+    Tensor parallelism (a mesh with a ``model`` axis) shards the cache
+    leaves as the layout says (the head axis of the pages) and the params
+    per ``causal_param_specs``; batch
     inputs replicate (every model shard sees every slot — slot state must
     stay coherent, and decode batches are tiny). Expert/pipeline axes are
     rejected at startup. DP axes likewise replicate: a decode engine is
@@ -1077,8 +1014,8 @@ class CausalLMEngine(_AotEngine):
     — so prompt admission becomes a sequence of bounded chunk dispatches
     the batcher interleaves with decode steps. With a prefix-cache budget
     the engine also owns a device-resident pool of KV pages ``[nl,
-    n_blocks, block_tokens, heads, head_dim]`` (sharded like the slot
-    cache, so TP gathers pages with per-shard head dims) indexed by a host
+    n_blocks, block_tokens, *trailing]`` (the same layout, sharded like the
+    slot cache, so TP gathers pages with per-shard head dims) indexed by a host
     :class:`~..serve.kvpool.KVBlockPool` trie, plus one ``insert``
     executable that publishes a finished slot's prefix pages back to the
     pool. A chunk at ``start == 0`` with nothing to gather is exactly the
@@ -1125,7 +1062,7 @@ class CausalLMEngine(_AotEngine):
         # Quantized serving (ROADMAP item 4; docs/DEPLOY.md "Quantized
         # serving"): weight_dtype packs kernels to int8 at engine build
         # (idempotent — restore_serving_state may have packed them already),
-        # kv_dtype stores cache/pool pages as int8 {"q","s"} pytrees.
+        # kv_dtype picks the cache layout (models/kvcache.py).
         self.weight_dtype, self.kv_dtype = self._plan_quant(
             cfg, tp=tp, weight_dtype=weight_dtype, kv_dtype=kv_dtype
         )
@@ -1135,11 +1072,6 @@ class CausalLMEngine(_AotEngine):
             params = quantize_params(params)
         elif jnp.dtype(self.weight_dtype) != jnp.dtype(cfg.dtype):
             params = cast_params(params, jnp.dtype(self.weight_dtype))
-        self._kv_quantized = self.kv_dtype == "int8"
-        self._kv_store_dtype = (
-            jnp.dtype(cfg.dtype) if self._kv_quantized
-            else jnp.dtype(self.kv_dtype)
-        )
         self.slots = slots
         self.buckets = tuple(
             sorted({min(int(b), cfg.max_position) for b in buckets})
@@ -1172,10 +1104,10 @@ class CausalLMEngine(_AotEngine):
             causal_param_specs,
         )
 
-        cache_shape = (
-            cfg.num_layers, slots, self.cache_len,
-            cfg.num_heads, cfg.hidden_size // cfg.num_heads,
-        )
+        # What a sequence caches: the model side declares the leaves, the
+        # engine handles [layers, slots, positions] of whatever they are.
+        self._layout = kvcache.cache_layout(cfg, self.kv_dtype)
+        table = (cfg.num_layers, slots, self.cache_len)
         if self._model_sharded:
             self._param_specs = causal_param_specs(params, model_axis="model")
             self._param_sharding = jax.tree.map(
@@ -1183,15 +1115,12 @@ class CausalLMEngine(_AotEngine):
                 self._param_specs,
                 is_leaf=lambda x: isinstance(x, P),
             )
-            self._cache_spec = P(None, None, None, "model", None)
         else:
             self._param_specs = None
-            self._cache_spec = P()
-        self._cache_sharding = self._kv_sharding(self._cache_spec)
+        self._cache_sharding = kvcache.shardings(self._layout, self.mesh)
         self._rep = replicated_sharding(self.mesh)
         self.params = self._place(params)
-        self._cache_k = self._kv_zeros(cache_shape, self._cache_sharding)
-        self._cache_v = self._kv_zeros(cache_shape, self._cache_sharding)
+        self._cache = kvcache.zeros(self._layout, table, self._cache_sharding)
         self._last_token = jax.device_put(
             jnp.zeros((slots,), jnp.int32), self._rep
         )
@@ -1199,10 +1128,13 @@ class CausalLMEngine(_AotEngine):
             "lm_params", self.params, dtype=self.weight_dtype,
             fp32_nbytes=fp32_equiv_nbytes(self.params),
         )
-        kv_bytes = tree_nbytes(self._cache_k) + tree_nbytes(self._cache_v)
+        fp32_per_token = kvcache.bytes_per_token(
+            kvcache.cache_layout(cfg, "float32"), cfg.num_layers
+        )
+        kv_bytes = tree_nbytes(self._cache)
         self.memory.register(
             "kv_slot_cache", kv_bytes, dtype=self.kv_dtype,
-            fp32_nbytes=2 * int(np.prod(cache_shape)) * 4,
+            fp32_nbytes=slots * self.cache_len * fp32_per_token,
         )
         # Per-slot share of the slot-table KV cache: the batcher multiplies
         # this by slots_active so /statusz and /memz agree on active bytes.
@@ -1237,10 +1169,7 @@ class CausalLMEngine(_AotEngine):
                 )
             else:
                 n_blocks = 1  # dummy pool keeps one chunk operand layout
-            pool_shape = (
-                cfg.num_layers, n_blocks, self.block_tokens,
-                cfg.num_heads, cfg.hidden_size // cfg.num_heads,
-            )
+            pool = (cfg.num_layers, n_blocks, self.block_tokens)
             self._pool_blocks = n_blocks
             # Orders every dispatch that DONATES the pool (insert/import,
             # decode-loop thread) against the one that reads it from
@@ -1249,13 +1178,13 @@ class CausalLMEngine(_AotEngine):
             # completed, and one dispatched before the publish of a block
             # it had matched shipped that block's stale bytes.
             self._pool_lock = threading.Condition()
-            self._pool_k = self._kv_zeros(pool_shape, self._cache_sharding)
-            self._pool_v = self._kv_zeros(pool_shape, self._cache_sharding)
+            self._pool = kvcache.zeros(
+                self._layout, pool, self._cache_sharding
+            )
             self.memory.register(
-                "kv_prefix_pool",
-                tree_nbytes(self._pool_k) + tree_nbytes(self._pool_v),
+                "kv_prefix_pool", tree_nbytes(self._pool),
                 dtype=self.kv_dtype,
-                fp32_nbytes=2 * int(np.prod(pool_shape)) * 4,
+                fp32_nbytes=n_blocks * self.block_tokens * fp32_per_token,
             )
         else:
             self.prefill_chunk_size = 0
@@ -1277,32 +1206,35 @@ class CausalLMEngine(_AotEngine):
         self._slot_import_compiled = None
         n_spec_cells = 1 if self.spec_tokens else 0
         n_mig_cells = 2 if self.stream_migrate else 0
+        # Each cell states its operands where it is compiled: ``cache`` is
+        # the layout's spec tree (slot table, pool and page stages alike),
+        # everything batch-like replicates.
+        cache, rep = kvcache.specs(self._layout), P()
+        table_s = kvcache.structs(self._layout, table, self._cache_sharding)
+
+        def i32(*shape):
+            return self._rep_struct(shape, jnp.int32)
+
+        def f32(*shape):
+            return self._rep_struct(shape, jnp.float32)
+
         if not self._chunked_mode:
             self._plan_cells(
                 len(self.batch_tiers) * len(self.buckets) + 1 + n_spec_cells
                 + n_mig_cells
             )
+            fn = self._wrap(
+                _make_causal_prefill(self.model),
+                (self._param_specs, cache, rep) + (rep,) * 6,
+                (cache, rep, rep),
+            )
             for T in self.batch_tiers:
-                fn = self._wrap(_make_causal_prefill(self.model), n_batch=6)
                 for L in self.buckets:
-                    self._prefill_compiled[T, L] = self._compile_cell(
-                        f"lm/{self.layout}/prefill/t{T}/b{L}",
-                        lambda fn=fn, T=T, L=L: (
-                            jax.jit(fn, donate_argnums=(1, 2, 3))
-                            .lower(
-                                self.params,
-                                self._kv_struct(cache_shape),
-                                self._kv_struct(cache_shape),
-                                self._rep_struct((slots,), jnp.int32),
-                                self._rep_struct((T, L), jnp.int32),
-                                self._rep_struct((T, L), jnp.bool_),
-                                self._rep_struct((T,), jnp.int32),
-                                self._rep_struct((T,), jnp.int32),
-                                self._rep_struct((T,), jnp.float32),
-                                self._rep_struct((T,), jnp.int32),
-                            )
-                            .compile()
-                        ),
+                    self._prefill_compiled[T, L] = self._aot(
+                        f"prefill/t{T}/b{L}", fn, (1, 2),
+                        self.params, table_s, i32(slots), i32(T, L),
+                        self._rep_struct((T, L), jnp.bool_), i32(T), i32(T),
+                        f32(T), i32(T),
                     )
         else:
             self._kv_transfer = (
@@ -1314,112 +1246,64 @@ class CausalLMEngine(_AotEngine):
                 + (2 if self._kv_transfer else 0) + n_spec_cells
                 + n_mig_cells
             )
-            chunk_fn = self._wrap_chunk(
-                _make_causal_chunk_prefill(self.model, self.cache_len)
+            pool_s = kvcache.structs(self._layout, pool, self._cache_sharding)
+            M = self._max_chain
+            fn = self._wrap(
+                _make_causal_chunk_prefill(
+                    self.model, self.cache_len, self.block_tokens
+                ),
+                (self._param_specs, cache, rep, cache) + (rep,) * 8,
+                (cache, rep, rep),
             )
-            pool_struct = self._kv_struct(pool_shape)
             for T in self.batch_tiers:
                 for C in self._chunk_buckets:
-                    self._chunk_compiled[T, C] = self._compile_cell(
-                        f"lm/{self.layout}/chunk/t{T}/c{C}",
-                        lambda T=T, C=C: (
-                            jax.jit(chunk_fn, donate_argnums=(1, 2, 3))
-                            .lower(
-                                self.params,
-                                self._kv_struct(cache_shape),
-                                self._kv_struct(cache_shape),
-                                self._rep_struct((slots,), jnp.int32),
-                                pool_struct,
-                                pool_struct,
-                                self._rep_struct((T, C), jnp.int32),
-                                self._rep_struct((T,), jnp.int32),
-                                self._rep_struct((T,), jnp.int32),
-                                self._rep_struct((T, self._max_chain),
-                                                 jnp.int32),
-                                self._rep_struct((T,), jnp.int32),
-                                self._rep_struct((T,), jnp.int32),
-                                self._rep_struct((T,), jnp.float32),
-                                self._rep_struct((T,), jnp.int32),
-                            )
-                            .compile()
-                        ),
+                    self._chunk_compiled[T, C] = self._aot(
+                        f"chunk/t{T}/c{C}", fn, (1, 2),
+                        self.params, table_s, i32(slots), pool_s, i32(T, C),
+                        i32(T), i32(T), i32(T, M), i32(T), i32(T), f32(T),
+                        i32(T),
                     )
             if self.prefix_cache is not None:
-                insert_fn = self._wrap_insert(
-                    _make_prefix_insert(self.block_tokens)
-                )
-                self._insert_compiled = self._compile_cell(
-                    f"lm/{self.layout}/insert",
-                    lambda: (
-                        jax.jit(insert_fn, donate_argnums=(0, 1, 2, 3))
-                        .lower(
-                            pool_struct,
-                            pool_struct,
-                            self._kv_struct(cache_shape),
-                            self._kv_struct(cache_shape),
-                            self._rep_struct((), jnp.int32),
-                            self._rep_struct((self._max_chain,), jnp.int32),
-                            self._rep_struct((self._max_chain,), jnp.int32),
-                        )
-                        .compile()
+                self._insert_compiled = self._aot(
+                    "insert",
+                    self._wrap(
+                        _make_prefix_insert(self.cache_len, self.block_tokens),
+                        (cache, cache, rep, rep, rep), (cache, cache),
                     ),
+                    (0, 1), pool_s, table_s, i32(), i32(M), i32(M),
                 )
             if self._kv_transfer:
-                pages_struct = self._kv_struct(
-                    (cfg.num_layers, self._max_chain, self.block_tokens,
-                     *pool_shape[3:]),
+                # Export gathers pinned pages OUT of the pool — the pool is
+                # NOT donated (it must survive the gather; eager ops over
+                # the donation-aliased pool are exactly what this AOT cell
+                # exists to avoid).
+                self._export_compiled = self._aot(
+                    "export",
+                    self._wrap(_make_export(), (cache, rep), cache),
+                    (), pool_s, i32(M),
                 )
-                # Export gathers pinned pages OUT of the pool — the pool
-                # operands are NOT donated (they must survive the gather;
-                # eager ops over the donation-aliased pool are exactly
-                # what this AOT cell exists to avoid).
-                export_fn = self._wrap_export(_make_pool_export())
-                self._export_compiled = self._compile_cell(
-                    f"lm/{self.layout}/export",
-                    lambda: (
-                        jax.jit(export_fn)
-                        .lower(
-                            pool_struct,
-                            pool_struct,
-                            self._rep_struct((self._max_chain,), jnp.int32),
-                        )
-                        .compile()
+                self._import_compiled = self._aot(
+                    "import",
+                    self._wrap(
+                        _make_pool_import(), (cache, cache, rep), cache
                     ),
-                )
-                import_fn = self._wrap_import(_make_pool_import())
-                self._import_compiled = self._compile_cell(
-                    f"lm/{self.layout}/import",
-                    lambda: (
-                        jax.jit(import_fn, donate_argnums=(0, 1))
-                        .lower(
-                            pool_struct,
-                            pool_struct,
-                            pages_struct,
-                            pages_struct,
-                            self._rep_struct((self._max_chain,), jnp.int32),
-                        )
-                        .compile()
+                    (0,), pool_s,
+                    kvcache.structs(
+                        self._layout,
+                        (cfg.num_layers, M, self.block_tokens),
+                        self._cache_sharding,
                     ),
+                    i32(M),
                 )
-        decode_fn = self._wrap(
-            _make_causal_decode(self.model, self.cache_len), n_batch=4
-        )
-        self._decode_compiled = self._compile_cell(
-            f"lm/{self.layout}/decode",
-            lambda: (
-                jax.jit(decode_fn, donate_argnums=(1, 2, 3))
-                .lower(
-                    self.params,
-                    self._kv_struct(cache_shape),
-                    self._kv_struct(cache_shape),
-                    self._rep_struct((slots,), jnp.int32),
-                    self._rep_struct((slots,), jnp.int32),
-                    self._rep_struct((slots,), jnp.bool_),
-                    self._rep_struct((slots,), jnp.float32),
-                    self._rep_struct((slots,), jnp.int32),
-                )
-                .compile()
+        self._decode_compiled = self._aot(
+            "decode",
+            self._wrap(
+                _make_causal_decode(self.model, self.cache_len),
+                (self._param_specs, cache, rep) + (rep,) * 4,
+                (cache, rep, rep),
             ),
+            (1, 2), self.params, table_s, i32(slots), i32(slots),
+            self._rep_struct((slots,), jnp.bool_), f32(slots), i32(slots),
         )
         # What the decode program reserves beside its operands, and how much
         # of the donated slot table it updates in place (per device; None
@@ -1435,75 +1319,45 @@ class CausalLMEngine(_AotEngine):
             pass
         self._verify_compiled = None
         if self.spec_tokens:
-            verify_fn = self._wrap(
-                _make_causal_verify(
-                    self.model, self.cache_len, self.spec_tokens
+            self._verify_compiled = self._aot(
+                "verify",
+                self._wrap(
+                    _make_causal_verify(
+                        self.model, self.cache_len, self.spec_tokens
+                    ),
+                    (self._param_specs, cache, rep) + (rep,) * 5,
+                    (cache, rep, rep),
                 ),
-                n_batch=5,
-            )
-            self._verify_compiled = self._compile_cell(
-                f"lm/{self.layout}/verify",
-                lambda: (
-                    jax.jit(verify_fn, donate_argnums=(1, 2, 3))
-                    .lower(
-                        self.params,
-                        self._kv_struct(cache_shape),
-                        self._kv_struct(cache_shape),
-                        self._rep_struct((slots,), jnp.int32),
-                        self._rep_struct(
-                            (slots, self.spec_tokens), jnp.int32
-                        ),
-                        self._rep_struct((slots,), jnp.int32),
-                        self._rep_struct((slots,), jnp.int32),
-                        self._rep_struct((slots,), jnp.float32),
-                        self._rep_struct((slots,), jnp.int32),
-                    )
-                    .compile()
-                ),
+                (1, 2), self.params, table_s, i32(slots),
+                i32(slots, self.spec_tokens), i32(slots), i32(slots),
+                f32(slots), i32(slots),
             )
         if self.stream_migrate:
-            stage_spec = (
-                P(None, None, "model", None) if self._model_sharded else P()
-            )
-            self._slot_stage_spec = stage_spec
-            self._slot_stage_sharding = self._kv_sharding(stage_spec)
-            slot_stage_struct = self._kv_struct(
-                (cfg.num_layers, self.cache_len, cfg.num_heads,
-                 cfg.hidden_size // cfg.num_heads),
-                sharding=self._slot_stage_sharding,
+            # One slot's lane drops the slot axis: [nl, cache_len, ..].
+            lane = kvcache.specs(self._layout, lead=2)
+            self._lane_sharding = kvcache.shardings(
+                self._layout, self.mesh, lead=2
             )
             # Slot export reads the live cache between decode steps — the
-            # cache operands are NOT donated (the stream may stay resident
-            # if the push fails and the batcher re-adopts it locally).
-            sexp_fn = self._wrap_slot_export(_make_slot_export())
-            self._slot_export_compiled = self._compile_cell(
-                f"lm/{self.layout}/slot_export",
-                lambda: (
-                    jax.jit(sexp_fn)
-                    .lower(
-                        self._kv_struct(cache_shape),
-                        self._kv_struct(cache_shape),
-                        self._rep_struct((), jnp.int32),
-                    )
-                    .compile()
-                ),
+            # cache is NOT donated (the stream may stay resident if the
+            # push fails and the batcher re-adopts it locally).
+            self._slot_export_compiled = self._aot(
+                "slot_export",
+                self._wrap(_make_export(), (cache, rep), lane),
+                (), table_s, i32(),
             )
-            simp_fn = self._wrap_slot_import(_make_slot_import())
-            self._slot_import_compiled = self._compile_cell(
-                f"lm/{self.layout}/slot_import",
-                lambda: (
-                    jax.jit(simp_fn, donate_argnums=(0, 1, 2))
-                    .lower(
-                        self._kv_struct(cache_shape),
-                        self._kv_struct(cache_shape),
-                        self._rep_struct((slots,), jnp.int32),
-                        slot_stage_struct,
-                        slot_stage_struct,
-                        self._rep_struct((), jnp.int32),
-                        self._rep_struct((), jnp.int32),
-                    )
-                    .compile()
+            self._slot_import_compiled = self._aot(
+                "slot_import",
+                self._wrap(
+                    _make_slot_import(),
+                    (cache, rep, lane, rep, rep), (cache, rep),
                 ),
+                (0, 1), table_s, i32(slots),
+                kvcache.structs(
+                    self._layout, (cfg.num_layers, self.cache_len),
+                    self._lane_sharding,
+                ),
+                i32(), i32(),
             )
         logger.info(
             "causal-LM engine ready: layout=%s slots=%d cache_len=%d "
@@ -1570,16 +1424,9 @@ class CausalLMEngine(_AotEngine):
             )
         kv = normalize_quant_dtype(kv_dtype, "kv_dtype") \
             or str(np.dtype(cfg.dtype).name)
-        if kv == "int8":
-            # int8 page payload + two f32 per-position scales (k and v).
-            bytes_per_block = (
-                2 * cfg.num_layers * block_tokens * (cfg.hidden_size + 4)
-            )
-        else:
-            bytes_per_block = (
-                2 * cfg.num_layers * block_tokens * cfg.hidden_size
-                * jnp.dtype(kv).itemsize
-            )
+        bytes_per_block = block_tokens * kvcache.bytes_per_token(
+            kvcache.cache_layout(cfg, kv), cfg.num_layers
+        )
         n_blocks = int(prefix_cache_mb * 2**20 // bytes_per_block)
         if prefix_cache_mb > 0 and n_blocks < 1:
             raise ValueError(
@@ -1645,172 +1492,37 @@ class CausalLMEngine(_AotEngine):
         default = str(np.dtype(cfg.dtype).name)
         return (w or default, k or default)
 
-    # -- quantized-KV plumbing: every cache/pool/stage operand flows
-    # -- through these helpers, so int8 mode is ONE representation decision
-    # -- (the {"q","s"} pytree) instead of per-cell branching.
-
-    def _kv_wrap_spec(self, spec):
-        """shard_map spec for a KV operand: the per-position scale plane
-        drops the trailing (heads, head_dim) axes, so a TP "model" entry
-        never lands in its spec."""
-        if not self._kv_quantized:
-            return spec
-        return {"q": spec, "s": P(*tuple(spec)[:-2])}
-
-    def _kv_sharding(self, spec):
-        if not self._kv_quantized:
-            return NamedSharding(self.mesh, spec)
-        return {
-            "q": NamedSharding(self.mesh, spec),
-            "s": NamedSharding(self.mesh, P(*tuple(spec)[:-2])),
-        }
-
-    def _kv_struct(self, shape, sharding=None):
-        sharding = self._cache_sharding if sharding is None else sharding
-        if not self._kv_quantized:
-            return jax.ShapeDtypeStruct(
-                shape, self._kv_store_dtype, sharding=sharding
-            )
-        return {
-            "q": jax.ShapeDtypeStruct(
-                shape, jnp.int8, sharding=sharding["q"]
-            ),
-            "s": jax.ShapeDtypeStruct(
-                shape[:-2], jnp.float32, sharding=sharding["s"]
-            ),
-        }
-
-    def _kv_zeros(self, shape, sharding):
-        if not self._kv_quantized:
-            return jax.device_put(
-                jnp.zeros(shape, self._kv_store_dtype), sharding
-            )
-        return {
-            "q": jax.device_put(jnp.zeros(shape, jnp.int8), sharding["q"]),
-            "s": jax.device_put(
-                jnp.zeros(shape[:-2], jnp.float32), sharding["s"]
-            ),
-        }
-
     def kv_bytes_per_token(self) -> int:
         """Slot-cache bytes ONE cached token occupies (K + V across all
         layers, plus scales at int8) — the `serve_kv_bytes_per_token{dtype=}`
         gauge and DEPLOY.md's sizing math both read this."""
-        cfg = self.model.cfg
-        if self._kv_quantized:
-            return 2 * cfg.num_layers * (cfg.hidden_size + 4)
-        return (
-            2 * cfg.num_layers * cfg.hidden_size
-            * jnp.dtype(self._kv_store_dtype).itemsize
+        return kvcache.bytes_per_token(
+            self._layout, self.model.cfg.num_layers
         )
 
     def _rep_struct(self, shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=self._rep)
 
-    def _wrap(self, fn, n_batch: int):
-        """shard_map the step over the model axis when sharded; the cache's
-        head axis splits, everything batch-like replicates (post-psum
-        logits are identical across shards, so replicated outs are safe)."""
+    def _wrap(self, fn, in_specs, out_specs):
+        """shard_map an executable body over the model axis when sharded:
+        a cache's leaves split as the layout says (per-shard gathers and
+        scatters of pages stay local — no cross-shard page traffic),
+        everything batch-like replicates (post-psum logits are identical
+        across shards, so replicated outs are safe)."""
         if not self._model_sharded:
             return fn
-        cache, rep = self._kv_wrap_spec(self._cache_spec), P()
-        # (params, cache_k, cache_v, last) + the n_batch step operands.
-        in_specs = (self._param_specs, cache, cache, rep) + (rep,) * n_batch
         return jax.shard_map(
-            fn,
-            mesh=self.mesh,
-            in_specs=in_specs,
-            out_specs=(cache, cache, rep, rep),
+            fn, mesh=self.mesh, in_specs=in_specs, out_specs=out_specs,
             check_vma=False,
         )
 
-    def _wrap_chunk(self, fn):
-        """Chunk-prefill twin of ``_wrap``: the pool pages shard their
-        head axis exactly like the slot cache (per-shard gathers stay
-        local — no cross-shard page traffic), everything else replicates."""
-        if not self._model_sharded:
-            return fn
-        cache, rep = self._kv_wrap_spec(self._cache_spec), P()
-        in_specs = (
-            self._param_specs, cache, cache, rep, cache, cache,
-        ) + (rep,) * 8
-        return jax.shard_map(
-            fn,
-            mesh=self.mesh,
-            in_specs=in_specs,
-            out_specs=(cache, cache, rep, rep),
-            check_vma=False,
-        )
-
-    def _wrap_insert(self, fn):
-        if not self._model_sharded:
-            return fn
-        cache, rep = self._kv_wrap_spec(self._cache_spec), P()
-        return jax.shard_map(
-            fn,
-            mesh=self.mesh,
-            in_specs=(cache, cache, cache, cache, rep, rep, rep),
-            out_specs=(cache, cache, cache, cache),
-            check_vma=False,
-        )
-
-    def _wrap_import(self, fn):
-        """Pool-import twin of ``_wrap_insert``: transferred pages shard
-        their head axis exactly like the pool they scatter into."""
-        if not self._model_sharded:
-            return fn
-        cache, rep = self._kv_wrap_spec(self._cache_spec), P()
-        return jax.shard_map(
-            fn,
-            mesh=self.mesh,
-            in_specs=(cache, cache, cache, cache, rep),
-            out_specs=(cache, cache),
-            check_vma=False,
-        )
-
-    def _wrap_export(self, fn):
-        """Pool-export twin of ``_wrap_import``: per-shard gathers stay
-        local (the page stage splits its head axis like the pool)."""
-        if not self._model_sharded:
-            return fn
-        cache, rep = self._kv_wrap_spec(self._cache_spec), P()
-        return jax.shard_map(
-            fn,
-            mesh=self.mesh,
-            in_specs=(cache, cache, rep),
-            out_specs=(cache, cache),
-            check_vma=False,
-        )
-
-    def _wrap_slot_export(self, fn):
-        """Slot-lane export for stream migration: the gathered stage drops
-        the slot dim, so its head axis sits one position earlier than the
-        cache spec's — per-shard gathers stay local either way."""
-        if not self._model_sharded:
-            return fn
-        cache, rep = self._kv_wrap_spec(self._cache_spec), P()
-        stage = self._kv_wrap_spec(P(None, None, "model", None))
-        return jax.shard_map(
-            fn,
-            mesh=self.mesh,
-            in_specs=(cache, cache, rep),
-            out_specs=(stage, stage),
-            check_vma=False,
-        )
-
-    def _wrap_slot_import(self, fn):
-        """Slot-lane import (resume a migrated stream): the received stage
-        shards its head axis like the cache it scatters into."""
-        if not self._model_sharded:
-            return fn
-        cache, rep = self._kv_wrap_spec(self._cache_spec), P()
-        stage = self._kv_wrap_spec(P(None, None, "model", None))
-        return jax.shard_map(
-            fn,
-            mesh=self.mesh,
-            in_specs=(cache, cache, rep, stage, stage, rep, rep),
-            out_specs=(cache, cache, rep),
-            check_vma=False,
+    def _aot(self, cell: str, fn, donate: tuple[int, ...], *args):
+        """Lower ``fn`` at ``args`` and compile it as grid cell ``cell``,
+        timed through ``_compile_cell`` like every other cell so
+        ``/compilez`` and warm-fraction readiness gating see it."""
+        return self._compile_cell(
+            f"lm/{self.layout}/{cell}",
+            lambda: jax.jit(fn, donate_argnums=donate).lower(*args).compile(),
         )
 
     # -- request surface ------------------------------------------------
@@ -1948,15 +1660,14 @@ class CausalLMEngine(_AotEngine):
             seeds[r] = int(a.get("seed", 0))
         mask[len(admissions):, 0] = True
         t_assembled = time.monotonic()
-        ck, cv, last, tok = self._prefill_compiled[T, L](
-            self.params, self._cache_k, self._cache_v, self._last_token,
+        self._cache, self._last_token, tok = self._prefill_compiled[T, L](
+            self.params, self._cache, self._last_token,
             jax.device_put(ids, self._rep), jax.device_put(mask, self._rep),
             jax.device_put(slot_ix, self._rep),
             jax.device_put(lengths, self._rep),
             jax.device_put(temps, self._rep),
             jax.device_put(seeds, self._rep),
         )
-        self._cache_k, self._cache_v, self._last_token = ck, cv, last
         self._record_dispatch(T, L, len(admissions))
         return InFlightBatch(
             out={"tok": tok}, key=key, n=len(admissions),
@@ -2030,9 +1741,8 @@ class CausalLMEngine(_AotEngine):
             temps[r] = float(row.get("temperature", 0.0))
             seeds[r] = int(row.get("seed", 0))
         t_assembled = time.monotonic()
-        ck, cv, last, tok = self._chunk_compiled[T, C](
-            self.params, self._cache_k, self._cache_v, self._last_token,
-            self._pool_k, self._pool_v,
+        self._cache, self._last_token, tok = self._chunk_compiled[T, C](
+            self.params, self._cache, self._last_token, self._pool,
             jax.device_put(ids, self._rep),
             jax.device_put(starts, self._rep),
             jax.device_put(lengths, self._rep),
@@ -2042,7 +1752,6 @@ class CausalLMEngine(_AotEngine):
             jax.device_put(temps, self._rep),
             jax.device_put(seeds, self._rep),
         )
-        self._cache_k, self._cache_v, self._last_token = ck, cv, last
         self._record_dispatch(T, C, len(rows))
         return InFlightBatch(
             out={"tok": tok}, key=key, n=len(rows),
@@ -2074,14 +1783,11 @@ class CausalLMEngine(_AotEngine):
             jax.device_put(pos, self._rep),
         )
         with self._pool_lock:
-            pk, pv, ck, cv = self._insert_compiled(
-                self._pool_k, self._pool_v, self._cache_k, self._cache_v,
-                *args,
+            self._pool, self._cache = self._insert_compiled(
+                self._pool, self._cache, *args
             )
-            self._pool_k, self._pool_v = pk, pv
             self.prefix_cache.mark_published(bid for bid, _ in blocks)
             self._pool_lock.notify_all()
-        self._cache_k, self._cache_v = ck, cv
 
     # -- disaggregated-serving page transfer (serve/disagg.py) ----------
 
@@ -2123,7 +1829,7 @@ class CausalLMEngine(_AotEngine):
                     f"blocks {list(blocks)} are indexed but their pages "
                     "were never published to the device pool"
                 )
-            return self._export_compiled(self._pool_k, self._pool_v, jdx)
+            return kvcache.split_kv(self._export_compiled(self._pool, jdx))
 
     def import_prefix_pages(
         self, blocks: list[tuple[int, int]], pages_k, pages_v
@@ -2156,14 +1862,13 @@ class CausalLMEngine(_AotEngine):
                 )
             ids[int(cix)] = int(bid)
         args = (
-            jax.device_put(pages_k, self._cache_sharding),
-            jax.device_put(pages_v, self._cache_sharding),
+            jax.device_put(
+                kvcache.join_kv(pages_k, pages_v), self._cache_sharding
+            ),
             jax.device_put(ids, self._rep),
         )
         with self._pool_lock:
-            self._pool_k, self._pool_v = self._import_compiled(
-                self._pool_k, self._pool_v, *args
-            )
+            self._pool = self._import_compiled(self._pool, *args)
             self.prefix_cache.mark_published(bid for bid, _ in blocks)
             self._pool_lock.notify_all()
 
@@ -2173,15 +1878,10 @@ class CausalLMEngine(_AotEngine):
         these match."""
         if self.prefix_cache is None:
             raise RuntimeError("engine has no prefix cache")
-        nl, _, bt, heads, hd = _kv_leaf(self._pool_k).shape
         return {
-            "num_layers": int(nl),
-            "block_tokens": int(bt),
-            "heads": int(heads),
-            "head_dim": int(hd),
-            # int8 pools report int8 (the q payload's dtype): fp32 and int8
-            # peers must refuse each other's pages fail-closed.
-            "dtype": str(np.dtype(_kv_leaf(self._pool_k).dtype).name),
+            "num_layers": int(self.model.cfg.num_layers),
+            "block_tokens": int(self.block_tokens),
+            **kvcache.page_geometry(self._layout),
             "max_chain": int(self._max_chain),
         }
 
@@ -2199,10 +1899,9 @@ class CausalLMEngine(_AotEngine):
                 "engine built without stream_migrate=True (no slot-export "
                 "cell)"
             )
-        return self._slot_export_compiled(
-            self._cache_k, self._cache_v,
-            jax.device_put(np.int32(slot), self._rep),
-        )
+        return kvcache.split_kv(self._slot_export_compiled(
+            self._cache, jax.device_put(np.int32(slot), self._rep)
+        ))
 
     def import_slot_pages(self, slot: int, pages_k, pages_v,
                           last_token: int) -> None:
@@ -2218,27 +1917,24 @@ class CausalLMEngine(_AotEngine):
                 "engine built without stream_migrate=True (no slot-import "
                 "cell)"
             )
-        ck, cv, last = self._slot_import_compiled(
-            self._cache_k, self._cache_v, self._last_token,
-            jax.device_put(pages_k, self._slot_stage_sharding),
-            jax.device_put(pages_v, self._slot_stage_sharding),
+        self._cache, self._last_token = self._slot_import_compiled(
+            self._cache, self._last_token,
+            jax.device_put(
+                kvcache.join_kv(pages_k, pages_v), self._lane_sharding
+            ),
             jax.device_put(np.int32(slot), self._rep),
             jax.device_put(np.int32(last_token), self._rep),
         )
-        self._cache_k, self._cache_v, self._last_token = ck, cv, last
 
     def stream_page_meta(self) -> dict:
         """Slot-lane geometry digest the stream wire format stamps into
         its header — two engines can ship live streams between each other
         iff these match (``cache_len`` may differ: the receiver re-pads,
         refusing only streams longer than its own lanes)."""
-        nl, _, cache_len, heads, hd = _kv_leaf(self._cache_k).shape
         return {
-            "num_layers": int(nl),
-            "cache_len": int(cache_len),
-            "heads": int(heads),
-            "head_dim": int(hd),
-            "dtype": str(np.dtype(_kv_leaf(self._cache_k).dtype).name),
+            "num_layers": int(self.model.cfg.num_layers),
+            "cache_len": int(self.cache_len),
+            **kvcache.page_geometry(self._layout),
         }
 
     def decode(self, lengths, active, temps, seeds) -> InFlightBatch:
@@ -2262,12 +1958,11 @@ class CausalLMEngine(_AotEngine):
         np.copyto(btmp, temps)
         np.copyto(bseed, seeds)
         t_assembled = time.monotonic()
-        ck, cv, last, tok = self._decode_compiled(
-            self.params, self._cache_k, self._cache_v, self._last_token,
+        self._cache, self._last_token, tok = self._decode_compiled(
+            self.params, self._cache, self._last_token,
             jax.device_put(blen, self._rep), jax.device_put(bact, self._rep),
             jax.device_put(btmp, self._rep), jax.device_put(bseed, self._rep),
         )
-        self._cache_k, self._cache_v, self._last_token = ck, cv, last
         return InFlightBatch(
             out={"tok": tok}, key=key, n=int(np.sum(bact)), meta=None,
             buffers=buffers, layout=self.layout, t_assembled=t_assembled,
@@ -2310,13 +2005,12 @@ class CausalLMEngine(_AotEngine):
         np.copyto(btmp, temps)
         np.copyto(bseed, seeds)
         t_assembled = time.monotonic()
-        ck, cv, last, tok = self._verify_compiled(
-            self.params, self._cache_k, self._cache_v, self._last_token,
+        self._cache, self._last_token, tok = self._verify_compiled(
+            self.params, self._cache, self._last_token,
             jax.device_put(bdr, self._rep), jax.device_put(blen, self._rep),
             jax.device_put(bnin, self._rep), jax.device_put(btmp, self._rep),
             jax.device_put(bseed, self._rep),
         )
-        self._cache_k, self._cache_v, self._last_token = ck, cv, last
         return InFlightBatch(
             out={"tok": tok}, key=key, n=int(np.sum(bnin > 0)), meta=None,
             buffers=buffers, layout=self.layout, t_assembled=t_assembled,

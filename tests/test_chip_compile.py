@@ -195,10 +195,10 @@ def _compile_decode(one_chip, kv):
         table = struct(pages, jnp.bfloat16)
     compiled = (
         jax.jit(
-            _make_causal_decode(model, _CACHE_LEN), donate_argnums=(1, 2, 3)
+            _make_causal_decode(model, _CACHE_LEN), donate_argnums=(1, 2)
         )
         .lower(
-            params, table, table,
+            params, {"k": table, "v": table},
             struct((_SLOTS,), jnp.int32), struct((_SLOTS,), jnp.int32),
             struct((_SLOTS,), jnp.bool_), struct((_SLOTS,), jnp.float32),
             struct((_SLOTS,), jnp.int32),

@@ -2,7 +2,7 @@
 
 decode_step attends the slot table as the step found it, with the new row
 selected in, and writes all layers' rows once at the end by a select over
-the stacked table (models/causal_lm.py, "Why decode_step writes by
+the stacked table (models/kvcache.py, "Why decode_step writes by
 select"). The reference here is the plain spelling it replaced: per layer,
 scatter the token with ``.at[idx, position].set(mode="drop")``, attend the
 written table, re-stack. Same operand values, so on the CPU the returned
@@ -20,8 +20,8 @@ import pytest
 from distributed_tensorflow_tpu.models.causal_lm import (
     CausalLM,
     CausalLMConfig,
-    _cached_attention,
 )
+from distributed_tensorflow_tpu.models.kvcache import cached_attention
 from distributed_tensorflow_tpu.models.quant import quantize_kv
 
 _SLOTS, _CACHE_LEN = 5, 24
@@ -30,7 +30,8 @@ _SLOTS, _CACHE_LEN = 5, 24
 class _WriteThenAttend(CausalLM):
     """The same parameters under the spelling decode_step had."""
 
-    def decode_step(self, token, position, k_cache, v_cache):
+    def decode_step(self, token, position, cache):
+        k_cache, v_cache = cache["k"], cache["v"]
         x = self._embed(
             token, jnp.minimum(position, self.cfg.max_position - 1)
         )
@@ -53,23 +54,18 @@ class _WriteThenAttend(CausalLM):
                     write, {n: t[i] for n, t in v_cache.items()},
                     dict(zip(("q", "s"), quantize_kv(v))),
                 )
-                ctx = _cached_attention(
-                    q, kc["q"], vc["q"], clamped,
-                    k_scale=kc["s"], v_scale=vc["s"],
-                )
             else:
                 kc = write(k_cache[i], k.astype(k_cache.dtype))
                 vc = write(v_cache[i], v.astype(v_cache.dtype))
-                ctx = _cached_attention(q, kc, vc, clamped)
+            ctx = cached_attention(q, {"k": kc, "v": vc}, clamped)
             x = layer._ffn(att._finish(x, ctx))
             new_k.append(kc)
             new_v.append(vc)
         stack = lambda *layers: jnp.stack(layers)  # noqa: E731
-        return (
-            self._head(x),
-            jax.tree.map(stack, *new_k),
-            jax.tree.map(stack, *new_v),
-        )
+        return self._head(x), {
+            "k": jax.tree.map(stack, *new_k),
+            "v": jax.tree.map(stack, *new_v),
+        }
 
 
 @pytest.fixture(scope="module")
@@ -85,8 +81,8 @@ def lm():
 
     def step_of(model):
         return jax.jit(
-            lambda tok, pos, ck, cv: model.apply(
-                {"params": params}, tok, pos, ck, cv, method="decode_step"
+            lambda tok, pos, cache: model.apply(
+                {"params": params}, tok, pos, cache, method="decode_step"
             )
         )
 
@@ -134,22 +130,21 @@ _SCENARIOS = {
 def test_decode_step_is_write_then_attend_bit_for_bit(lm, kv, scenario):
     cfg, step, reference = lm
     rng = np.random.default_rng(1)
-    ck = ref_ck = _table(cfg, kv, 2)
-    cv = ref_cv = _table(cfg, kv, 3)
+    cache = ref_cache = {"k": _table(cfg, kv, 2), "v": _table(cfg, kv, 3)}
     for positions in _SCENARIOS[scenario]:
         tok = jnp.asarray(rng.integers(5, cfg.vocab_size, _SLOTS), jnp.int32)
         pos = jnp.asarray(positions, jnp.int32)
-        before = (ck, cv)
-        logits, ck, cv = step(tok, pos, ck, cv)
-        ref_logits, ref_ck, ref_cv = reference(tok, pos, ref_ck, ref_cv)
-        _same((ck, cv), (ref_ck, ref_cv))
+        before = cache
+        logits, cache = step(tok, pos, cache)
+        ref_logits, ref_cache = reference(tok, pos, ref_cache)
+        _same(cache, ref_cache)
         live = np.asarray(positions) < _CACHE_LEN
         # an idle lane's logits are garbage nobody reads, but the same garbage
         _same(logits, ref_logits)
         # ... and its slot's pages are untouched, as are all but one
         # position of a live slot's
         for new, old in zip(
-            jax.tree.leaves((ck, cv)), jax.tree.leaves(before), strict=True
+            jax.tree.leaves(cache), jax.tree.leaves(before), strict=True
         ):
             new, old = np.asarray(new), np.asarray(old)
             np.testing.assert_array_equal(new[:, ~live], old[:, ~live])

@@ -392,7 +392,7 @@ def test_export_import_round_trip_pages_bit_exact(role_pair):
         # A publish on the batcher thread donates the pool buffer and
         # rebinds the ref; _pool_lock is what orders a read against it.
         with eng._pool_lock:
-            src = np.asarray(jax.device_get(eng._pool_k))[:, m.blocks]
+            src = np.asarray(jax.device_get(eng._pool["k"]))[:, m.blocks]
         assert host_k.tobytes() == src.tobytes()
         ids = [int(t) for t in prompt[: n * pool.block_tokens]]
         buf = serialize_chain(ids, host_k,

@@ -258,48 +258,52 @@ def test_pipelined_results_ordered_under_concurrent_submits():
         san.assert_clean()
 
 
+# Watched accesses and lock acquisitions the sanitizer records per request of
+# the workload below. Measured (PR 32): 9.1-9.5 / 25.3-25.8 when the 120
+# requests are submitted back to back and ride batches of four; 30.5-31.0 /
+# 48.0-48.1 when the submitter is slowed until every request is its own batch
+# (3-20 ms between submits), where both level off — time spent idle adds
+# nothing. A loaded host can only move a run from the first pair towards the
+# second, so the budget is the plateau plus a third.
+_RACETRACE_ACCESSES_PER_REQUEST = 40
+_RACETRACE_ACQUISITIONS_PER_REQUEST = 64
+
+
 def test_racetrace_overhead_within_ten_percent():
-    """Acceptance bound: running the pipelined workload under the race
-    sanitizer costs <= 10% wall-clock vs the same workload untracked.
-    The workload is deliberately sleep-paced (as real serving is device-
-    paced) so the bound is about instrumentation cost on the hot path, not
-    about raw python dispatch."""
+    """What the race sanitizer costs the pipelined hot path, counted in the
+    work it does per request: watched accesses and tracked acquisitions
+    within a pinned budget, and a clean report. The workload is sleep-paced
+    (as real serving is device-paced).
+
+    The name is from when this compared two wall clocks (traced <= 1.10 x
+    plain + 20 ms, best of five each): a ratio that the five other xdist
+    workers of the tier-1 run decided, red four runs in five. A change that
+    makes the sanitizer watch more of the hot path — what the bound was
+    for — moves these counts whatever the host is doing."""
 
     class _SleepyStub(_PipelinedStub):
         def fetch(self, handle):
             time.sleep(0.002)  # stands in for device time
             return super().fetch(handle)
 
-    def workload() -> float:
+    n = 120
+    with sanitize_races(modules=[batcher_mod]) as san:
         eng = _SleepyStub()
         cfg = BatcherConfig(
             max_batch=4, max_delay_ms=0.5, max_in_flight=2, max_queue=256
         )
-        t0 = time.monotonic()
         b = DynamicBatcher(
             eng.run_batch, cfg, dispatch=eng.dispatch, fetch=eng.fetch
         )
-        futs = [b.submit(i) for i in range(120)]
-        assert [f.result(timeout=30)["v"] for f in futs] == list(range(120))
+        futs = [b.submit(i) for i in range(n)]
+        assert [f.result(timeout=30)["v"] for f in futs] == list(range(n))
         b.close()
-        return time.monotonic() - t0
-
-    workload()  # warm-up: imports, thread machinery
-    # Alternate the two arms so both see the same stretch of a host that
-    # five other xdist workers load unevenly; best of five each.
-    plain = traced = float("inf")
-    accesses = 0
-    for _ in range(5):
-        plain = min(plain, workload())
-        with sanitize_races(modules=[batcher_mod]) as san:
-            traced = min(traced, workload())
-            san.assert_clean()
-        accesses += san.accesses
-    assert accesses > 0
-    # 10% + 20ms absolute slack so scheduler jitter on a loaded CI host
-    # can't fail a bound the steady-state comfortably meets.
-    assert traced <= plain * 1.10 + 0.020, (
-        f"racetrace overhead too high: plain={plain:.3f}s traced={traced:.3f}s"
+        san.assert_clean()
+    assert 0 < san.accesses <= n * _RACETRACE_ACCESSES_PER_REQUEST, (
+        f"{san.accesses / n:.1f} watched accesses a request"
+    )
+    assert 0 < san.acquisitions <= n * _RACETRACE_ACQUISITIONS_PER_REQUEST, (
+        f"{san.acquisitions / n:.1f} tracked acquisitions a request"
     )
 
 
