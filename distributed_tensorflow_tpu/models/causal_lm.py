@@ -15,10 +15,11 @@ Five forwards share one param tree:
 - ``__call__(input_ids, attention_mask) -> logits [B, L, V]`` — the full
   causally-masked forward: training loss, scoring, and the one-shot
   reference the serving decode path is tested against.
-- ``prefill(input_ids, attention_mask) -> (logits, k [nl,B,L,h,d], v)`` —
-  same math, but also returns every layer's projected K/V so the serving
-  engine can write them into its slot cache (``kvcache.write_prompt``,
-  from serve/engine.py ``CausalLMEngine``).
+- ``prefill(input_ids, attention_mask) -> (logits, k [nl,B,L,h*d], v)`` —
+  same math, but also returns every layer's projected K/V, each position
+  one merged row as the cache holds it, so the serving engine can write
+  them into its slot cache (``kvcache.write_prompt``, from serve/engine.py
+  ``CausalLMEngine``).
 
 The other three take and return ONE ``cache``: a pytree whose every leaf is
 ``[nl, slots, positions, *trailing]``. What the leaves are (dense K/V, or
@@ -29,8 +30,8 @@ methods here are written once, over its operations.
   — ONE token per cache slot: embed at the slot's position, write the new
   K/V at ``position``, attend positions ``<= position``. Shapes are fixed
   by the slot count, so slot assignment/reuse never retraces (the "fixed
-  pool of per-slot cache pages" contract). It writes by select, not by
-  scatter (models/kvcache.py, "Why decode_step writes by select").
+  pool of per-slot cache pages" contract). It writes the step's rows in
+  place (models/kvcache.py, "How decode_step writes and reads").
 - ``prefill_chunk(input_ids [B, C], positions [B, C], cache) -> (logits
   [B, C, V], cache')`` — a CHUNK of each row's prompt at arbitrary ABSOLUTE
   positions against per-row caches ``[nl, B, Lc, ..]``: write the chunk's
@@ -140,6 +141,13 @@ class CausalSelfAttention(nn.Module):
         )
         self.ln = nn.LayerNorm(epsilon=1e-12, dtype=cfg.dtype)
 
+    def _qkv(self, x):
+        """``q [.., h, d]`` and the rows ``k, v [.., h * d]``: a cached
+        position is one merged row (models/kvcache.py), so only the query
+        is split into heads."""
+        rows = kvcache.merge_heads
+        return self.query(x), rows(self.key(x)), rows(self.value(x))
+
     def _finish(self, x, ctx):
         out = _tp_psum(self.cfg, self.out(ctx))
         out = out + self.out_bias.astype(out.dtype)
@@ -148,9 +156,11 @@ class CausalSelfAttention(nn.Module):
     def __call__(self, x, pad_mask):
         q, k, v = self.query(x), self.key(x), self.value(x)
         ctx = _causal_attention(q, k, v, pad_mask)
-        # K/V returned pre-attention: prefill scatters exactly these into
-        # the slot cache, so the decode path attends identical values.
-        return self._finish(x, ctx), k, v
+        # K/V returned pre-attention, as the rows a cache holds: prefill
+        # scatters exactly these into the slot cache, so the decode path
+        # attends identical values.
+        rows = kvcache.merge_heads
+        return self._finish(x, ctx), rows(k), rows(v)
 
     def decode(self, x, cache, position):
         """One token per slot against this layer's table AS THE STEP FOUND
@@ -163,7 +173,7 @@ class CausalSelfAttention(nn.Module):
         # nothing is written (writing anywhere could corrupt a mid-chunk-
         # prefill slot's pages) and its attention clamps — the lane's
         # output is garbage nobody reads.
-        q, k, v = self.query(x), self.key(x), self.value(x)  # [S, h, d]
+        q, k, v = self._qkv(x)  # [S, h, d], [S, h * d] x 2
         rows = kvcache.encode(cache, k, v)
         with jax.named_scope("cached_attention"):
             read = kvcache.select_rows(cache, rows, position, slot_axis=0)
@@ -173,7 +183,7 @@ class CausalSelfAttention(nn.Module):
     def prefill_chunk(self, x, positions, cache):
         # x [B, C, H]; positions [B, C] absolute (sentinel == Lc on
         # padding lanes -> the scatter drops); cache [B, Lc, ..].
-        q, k, v = self.query(x), self.key(x), self.value(x)  # [B, C, h, d]
+        q, k, v = self._qkv(x)  # [B, C, h, d], [B, C, h * d] x 2
         cache = kvcache.scatter_rows(
             cache, kvcache.encode(cache, k, v), positions
         )
@@ -294,11 +304,9 @@ class CausalLM(nn.Module):
             x, row = layer.decode(x, kvcache.take_layer(cache, i), position)
             rows.append(row)
         # Every layer read the step's INPUT table; the [nl, S, ..] of new
-        # rows go into it here, once, in the layout it lives in
-        # (models/kvcache.py, "Why decode_step writes by select").
-        rows = kvcache.stack_layers(rows)
-        with jax.named_scope("kv_write"):
-            cache = kvcache.select_rows(cache, rows, position, slot_axis=1)
+        # rows go into it here, once and in place (models/kvcache.py, "How
+        # decode_step writes and reads").
+        cache = kvcache.write_rows(cache, kvcache.stack_layers(rows), position)
         return self._head(x), cache
 
     def prefill_chunk(self, input_ids, positions, cache):

@@ -7,48 +7,56 @@ are, what trails them, their dtype, how they shard and how a fresh row is
 encoded into them is decided here:
 
 - :func:`cache_layout` describes the leaves (:class:`Leaf`). Dense K/V is
-  ``{"k", "v"}``, each ``(heads, head_dim)`` in the store dtype; int8 K/V
-  makes each side the ``{"q", "s"}`` pair of models/quant.py: the int8
-  payload and its float32 per-position scale, which has no trailing axes.
+  ``{"k", "v"}``, each one row of ``heads * head_dim`` in the store dtype;
+  int8 K/V makes each side the ``{"q", "s"}`` pair of models/quant.py: the
+  int8 payload and its float32 per-position scale, which has no trailing
+  axes.
 - ``specs`` / ``shardings`` / ``structs`` / ``zeros`` / ``bytes_per_token``
   are one ``tree.map`` over that description each, for any layout.
 - The model's reads and writes — ``take_layer``, ``encode``, ``select_rows``,
-  ``scatter_rows``, ``stack_layers``, ``write_prompt``, ``cached_attention``,
-  ``chunk_attention`` — are written once for every form.
+  ``write_rows``, ``scatter_rows``, ``stack_layers``, ``write_prompt``,
+  ``cached_attention``, ``chunk_attention`` — are written once for every form.
 - ``split_kv`` / ``join_kv`` / ``page_geometry`` convert at the engine's host
   boundary, where serve/disagg.py and the wire format still speak of
-  ``pages_k, pages_v`` (ROADMAP Design 1, the host half).
+  ``pages_k, pages_v`` and of ``[.., heads, head_dim]``: a row-major reshape
+  of the same bytes (ROADMAP Design 1, the host half).
 
-Why decode_step writes by select. On the TPU the slot table ``[nl, S, L, h,
-d]`` lives with the cache POSITION minor-most (layout ``{2,4,3,1,0}``:
-``d x L`` tiles without padding, ``h x d`` would not), which is the layout
-the attention einsums read. The ``scatter`` and ``dynamic-update-slice``
-emitters want ``{4,3,..}`` instead, so the compiler brackets every such
-write with two copies of whatever table it writes. Compiled for a described
-v5e at the serving benchmark's geometry (bf16, 128 slots, cache 384, tables
-donated; ``memory_analysis().temp_size_in_bytes``):
+How decode_step writes and reads. A cached position of one layer is ONE
+contiguous row: the heads are merged, the table is ``[nl, S, L, h * d]``, and
+on the TPU its default layout ``{3,2,1,0:T(8,128)(2,1)}`` is the one it lives
+in (768 = 6 lane tiles, no padding; trailing ``(h=12, d=64)`` would pad to
+(16, 128) tiles, which is why the table of PRs 28-32 lived position
+minor-most, admitted no in-place write, and was written by a select over all
+of it: 3.6 GB of traffic a step for 4.7 MB of rows). Compiled for a described
+v5e at the serving benchmark's geometry (bf16, 128 slots, cache 384, the table
+donated; ``memory_analysis().temp_size_in_bytes``; times are in PERF.md, PR
+33):
 
-- per layer ``table[i].at[idx, position].set(..)``, then re-stack (the
-  spelling until PR 28): 48 copies of a layer table a step, 2 slicing
-  fusions, 24 scatters, 24 re-stacking updates — 3.55 GB;
-- one stacked scatter ``table.at[:, idx, position].set(..)`` at the end: the
-  whole table copied there and back — 2.45 GB; the same for a loop of
-  per-slot ``dynamic_update_slice``, rolled or unrolled — 2.45 GB;
-- the stacked table carried through the layers with ``.at[i, idx,
-  position].set(..)``: the whole program flips layout, 24 full-table
-  scatters — 7.26 GB;
-- what is here: each layer attends ``where(position_hit, new_row,
-  table[i])`` (slice and select fuse into the attention loop; no layer
-  table exists), and the ``[nl, S, h, d]`` of new rows are written once, by
-  one select over the stacked table that aliases its donated operand —
-  0.026 GB, no table-sized copy, slice, scatter or update (int8 KV: 0.028).
+- the write, what is here (:func:`write_rows`): the ``nl x S`` rows scattered
+  into the FLAT table ``[nl * S * L, h * d]`` at ``(layer * S + slot) * L +
+  position``, ``unique_indices`` and ``mode="drop"`` — 0 B, aliased, a
+  ``kCustom`` scatter fusion a side; a ``fori_loop`` of row-sized
+  ``dynamic_update_slice`` on the flat table does the same (97 kB);
+- the STACKED scatter ``table.at[:, arange(S), position].set(rows)`` still
+  copies the table to ``{3,0,2,1}`` and back — 1.21 GB. Do not retry it;
+- the read, what is here (:func:`_attend`): both contractions over the merged
+  row, scores against a block-diagonal query and the context's own lanes kept
+  — two ``kOutput`` fusions a layer that read the parameter, 0 B;
+- the read with the lane axis split (``table[i].reshape(S, L, h, d)`` and the
+  per-head ``shd,slhd->shl``): the layer is sliced, copied to ``{2,3,1,0}``
+  and converted to float32 — 0.227 GB a layer. Do not retry it;
+- a leaf without trailing axes (the int8 scale, ``[nl, S, L]``, 2.4 MB) has
+  the position minor-most again; it keeps the select, a pass over 0.3% of what
+  the payload's was.
 
-The select passes over the whole table to write ``nl x S`` rows; that one
-pass is what the layout costs, and PERF.md (PR 28) has its time on the chip.
-The operand values are those of write-then-attend, bit for bit
-(tests/test_decode_kv_write.py); tests/test_chip_compile.py keeps the
-compiled program free of the copies. ``prefill_chunk`` / ``verify_step``
-still slice, scatter and re-stack, and have the copies by construction.
+Each layer attends ``where(position_hit, new_row, table[i])`` of the step's
+INPUT table (the select fuses into the attention's read; no layer table
+exists), so the operand values are those of write-then-attend, bit for bit
+(tests/test_decode_kv_write.py), and the rows go in once, after the last
+read. The whole step: 26 MB of scratch, both tables aliased, no fusion, copy,
+slice or convert of a table (tests/test_chip_compile.py keeps it so).
+``prefill_chunk`` / ``verify_step`` still slice a layer out, scatter into it
+and re-stack, and have the copies by construction.
 """
 
 from __future__ import annotations
@@ -82,15 +90,15 @@ def cache_layout(cfg, kv_dtype: str):
     """The leaves ``CausalLM(cfg)`` caches under ``kv_dtype`` (a concrete
     name from ``CausalLMEngine._plan_quant``). Heads split over
     ``cfg.model_axis``; a scale has no axis to split."""
-    heads = (cfg.num_heads, cfg.hidden_size // cfg.num_heads)
-    split = (cfg.model_axis, None)
+    row = (cfg.hidden_size,)  # heads x head_dim, merged: one contiguous row
+    split = (cfg.model_axis,)
     if kv_dtype == "int8":
         side = {
-            "q": Leaf(heads, np.dtype(np.int8), split),
+            "q": Leaf(row, np.dtype(np.int8), split),
             "s": Leaf((), np.dtype(np.float32), ()),
         }
     else:
-        side = Leaf(heads, jnp.dtype(kv_dtype), split)
+        side = Leaf(row, jnp.dtype(kv_dtype), split)
     return {"k": side, "v": side}
 
 
@@ -142,25 +150,43 @@ def bytes_per_token(layout, num_layers: int) -> int:
 # -- the engine's host boundary -------------------------------------------
 
 
-def split_kv(tree):
+def merge_heads(a):
+    """``[.., h, d]`` as the row ``[.., h * d]`` a cached position is."""
+    return a.reshape(*a.shape[:-2], -1)
+
+
+def _payload(side, fn):
+    """``fn`` over the leaf of a side that has the row; a scale rides as is."""
+    if isinstance(side, dict):
+        return {**side, "q": fn(side["q"])}
+    return fn(side)
+
+
+def split_kv(tree, heads: int):
     """A cache-shaped tree as the ``(pages_k, pages_v)`` the host half takes:
-    plain arrays, or the ``{"q", "s"}`` pair each."""
-    return tree["k"], tree["v"]
+    plain arrays, or the ``{"q", "s"}`` pair each. The wire says ``[..,
+    heads, head_dim]`` where the cache holds one merged row: a row-major
+    reshape, the same bytes."""
+    split = lambda a: a.reshape(*a.shape[:-1], heads, -1)  # noqa: E731
+    return _payload(tree["k"], split), _payload(tree["v"], split)
 
 
 def join_kv(pages_k, pages_v):
-    return {"k": pages_k, "v": pages_v}
+    return {
+        "k": _payload(pages_k, merge_heads),
+        "v": _payload(pages_v, merge_heads),
+    }
 
 
-def page_geometry(layout) -> dict:
-    """What the wire headers say of a page: int8 pools report int8 (the
-    payload's dtype), so fp32 and int8 peers refuse each other's pages."""
+def page_geometry(cfg, layout) -> dict:
+    """What the wire headers say of a page: the model's heads, and the
+    payload's dtype — int8 pools report int8, so fp32 and int8 peers refuse
+    each other's pages."""
     side = layout["k"]
     payload = side["q"] if isinstance(side, dict) else side
-    heads, head_dim = payload.shape
     return {
-        "heads": int(heads),
-        "head_dim": int(head_dim),
+        "heads": int(cfg.num_heads),
+        "head_dim": int(cfg.hidden_size // cfg.num_heads),
         "dtype": str(np.dtype(payload.dtype).name),
     }
 
@@ -185,21 +211,24 @@ def _encode(like, fresh):
 
 
 def encode(like, k, v):
-    """Fresh ``k, v: [..., h, d]`` as rows of the form ``like`` (a cache, or
-    one layer of it) stores: a cast, or the int8 ``{"q", "s"}`` pair. Every
-    writer encodes here, so a page is the same bits whichever path — prompt
+    """Fresh ``k, v: [..., h * d]`` (the projections' heads merged, as a
+    cached row is) as rows of the form ``like`` (a cache, or one layer of
+    it) stores: a cast, or the int8 ``{"q", "s"}`` pair. Every writer
+    encodes here, so a page is the same bits whichever path — prompt
     prefill, chunk, verify, decode — wrote it."""
     with jax.named_scope("kv_write"):
         return {"k": _encode(like["k"], k), "v": _encode(like["v"], v)}
 
 
 def select_rows(table, rows, position, slot_axis: int):
-    """``table`` with ``rows`` at each slot's ``position``, as a select —
-    never a scatter (module docstring). ``table`` is ``[.., S, L, ..]`` with
-    the slots at ``slot_axis`` and the cache positions after them, ``rows``
-    the same without the position axis, ``position: [S]``; the leaves differ
-    only in trailing axes. A position of ``L`` or more matches nothing: the
-    slot keeps its pages.
+    """``table`` with ``rows`` at each slot's ``position``, as a select.
+    ``table`` is ``[.., S, L, ..]`` with the slots at ``slot_axis`` and the
+    cache positions after them, ``rows`` the same without the position axis,
+    ``position: [S]``; the leaves differ only in trailing axes. A position
+    of ``L`` or more matches nothing: the slot keeps its pages. Over one
+    layer (``slot_axis=0``) it fuses into the attention that reads it; over
+    the stacked table it is a pass over the whole leaf, which
+    :func:`write_rows` keeps for the leaves that are small.
     """
 
     def leaf(t, r):
@@ -208,6 +237,36 @@ def select_rows(table, rows, position, slot_axis: int):
         return jnp.where(hit, jnp.expand_dims(r, slot_axis + 1), t)
 
     return jax.tree.map(leaf, table, rows)
+
+
+def write_rows(cache, rows, position):
+    """The stacked table ``[nl, S, L, ..]`` with the step's ``rows [nl, S,
+    ..]`` at each slot's ``position [S]``, in place (module docstring). A
+    leaf with trailing axes is written as ``nl x S`` rows of the flat ``[nl
+    * S * L, row]`` table, whose traffic is the rows'; a leaf without (a
+    scale) has the position minor-most and takes the select. A position of
+    ``L`` or more writes nothing: the slot keeps its pages."""
+    with jax.named_scope("kv_write"):
+        return jax.tree.map(
+            lambda t, r: (
+                _scatter_flat(t, r, position) if t.ndim > _LEAD
+                else select_rows(t, r, position, slot_axis=1)
+            ),
+            cache, rows,
+        )
+
+
+def _scatter_flat(t, r, position):
+    nl, s, l = t.shape[:_LEAD]
+    lane = jnp.arange(nl * s).reshape(nl, s)  # layer * S + slot
+    # an idle lane's index lies past the table, each its own: all dropped,
+    # and the indices stay unique as promised
+    idx = jnp.where(position < l, lane * l + position, nl * s * l + lane)
+    flat = t.reshape(nl * s * l, -1)
+    flat = flat.at[idx.reshape(-1)].set(
+        r.reshape(nl * s, -1), mode="drop", unique_indices=True
+    )
+    return flat.reshape(t.shape)
 
 
 def scatter_rows(table, rows, positions):
@@ -231,11 +290,12 @@ def stack_layers(layers):
 
 def write_prompt(cache, slots, k, v):
     """The slot table with whole prefilled prompts in it: ``k, v: [nl, T, L,
-    h, d]`` fresh from ``CausalLM.prefill`` go to positions ``[0, L)`` of
-    ``slots [T]``. A tier's padding rows carry slot index == S (one past the
-    pool), so their writes drop and never dirty a live slot's pages. Encoded
-    by :func:`encode` like every other write: a prefilled page is
-    bit-identical to one the decode path would have written."""
+    h * d]`` fresh from ``CausalLM.prefill`` go to positions ``[0, L)`` of
+    ``slots [T]``, contiguous rows each. A tier's padding rows carry slot
+    index == S (one past the pool), so their writes drop and never dirty a
+    live slot's pages. Encoded by :func:`encode` like every other write: a
+    prefilled page is bit-identical to one the decode path would have
+    written."""
     rows = encode(cache, k, v)
     with jax.named_scope("kv_write"):
         return jax.tree.map(
@@ -252,38 +312,51 @@ def _operand(side):
 
 
 def _attend(q, cache, position, qk: str, pv: str):
-    """Attention of ``q`` over one layer's cache, each query seeing cache
-    positions ``<= position`` (clamped: an idle lane's sentinel reads
-    garbage nobody uses). f32 score/context accumulation and exactly-0
-    masking, as the full forward. An int8 side is never dequantized: the
-    k-scale multiplies the scores after the QK^T product and the v-scale
-    folds into the softmax weights before the context product — in that
-    order in both callers, so verify columns stay bit-identical to the
-    decode steps they replace."""
+    """Attention of ``q [.., h, d]`` over one layer's cache ``[rows, L, h *
+    d]``, each query seeing cache positions ``<= position`` (clamped: an
+    idle lane's sentinel reads garbage nobody uses). Both contractions run
+    over the merged row ``c = h * d`` as the table holds it — splitting it
+    into heads would copy the layer (module docstring): the scores contract
+    ``k`` with the block-diagonal ``qb[.., c, h]`` (``q`` where lane ``c`` is
+    head ``h``'s, else 0), and of the context ``[rows, h, .., c]`` each head
+    keeps its own lanes. The zeros add nothing and the lanes dropped are other
+    heads' values, so this is per-head attention, in f32 score/context
+    accumulation and with exactly-0 masking as the full forward. An int8
+    side is never dequantized: the k-scale multiplies the scores after the
+    QK^T product and the v-scale folds into the softmax weights before the
+    context product — in that order in both callers, so verify columns stay
+    bit-identical to the decode steps they replace. ``h`` and ``d`` are the
+    local ones: under ``model`` sharding a shard holds whole heads."""
     k, k_scale = _operand(cache["k"])
     v, v_scale = _operand(cache["v"])
     position = jnp.minimum(position, k.shape[1] - 1)
-    s = jnp.einsum(qk, q, k, preferred_element_type=jnp.float32)
+    h, d = q.shape[-2:]
+    own = jnp.arange(h * d)[:, None] // d == jnp.arange(h)  # [c, h]
+    qb = jnp.where(own, q.reshape(*q.shape[:-2], h * d, 1), 0)
+    s = jnp.einsum(qk, qb, k, preferred_element_type=jnp.float32)
     between = tuple(range(1, s.ndim - 1))  # the axes between rows and L
     if k_scale is not None:
         s = s * jnp.expand_dims(k_scale, between)
-    s = s * q.shape[-1] ** -0.5
+    s = s * d ** -0.5
     valid = jnp.arange(k.shape[1]) <= position[..., None]
     valid = jnp.expand_dims(valid, 1)  # heads
     s = jnp.where(valid, s, MASK_VALUE)
     p = jax.nn.softmax(s, axis=-1) * valid
     if v_scale is not None:
         p = p * jnp.expand_dims(v_scale, between)
-    return jnp.einsum(
+    ctx = jnp.einsum(
         pv, p.astype(v.dtype), v, preferred_element_type=jnp.float32
-    ).astype(q.dtype)
+    )  # [rows, h, .., c]
+    mine = own.T.reshape(h, *(1,) * (ctx.ndim - 3), h * d)
+    out = jnp.sum(jnp.where(mine, ctx, 0), axis=1)  # [rows, .., c]
+    return out.reshape(q.shape).astype(q.dtype)
 
 
 def cached_attention(q, cache, position):
     """One token per slot: ``q: [S, h, d]``, the layer's cache ``[S, Lmax,
     ..]``, ``position: [S]`` the index the newest token sits at."""
     with jax.named_scope("cached_attention"):
-        return _attend(q, cache, position, "shd,slhd->shl", "shl,slhd->shd")
+        return _attend(q, cache, position, "sch,slc->shl", "shl,slc->shc")
 
 
 def chunk_attention(q, cache, position):
@@ -291,4 +364,6 @@ def chunk_attention(q, cache, position):
     Lc, ..]``, ``position: [B, C]``. Cache positions beyond a row's written
     length hold zeros or a prior occupant's values — finite either way, with
     softmax weight exactly 0 under the causal mask."""
-    return _attend(q, cache, position, "bchd,blhd->bhcl", "bhcl,blhd->bchd")
+    # the context comes heads-second, as the product leaves it: the CPU
+    # backend has no bf16 dot whose result is transposed ("->bqhc")
+    return _attend(q, cache, position, "bqch,blc->bhql", "bhql,blc->bhqc")

@@ -19,7 +19,7 @@ replaced and ``_q8_scale`` carries the kernel's last-axis sharding, so TP
 layouts restore shard-direct unchanged (models/bert.py spec rules).
 
 **KV cache** — int8 pages with per-position scales. A quantized cache
-operand is the pytree ``{"q": int8[..., heads, head_dim], "s":
+operand is the pytree ``{"q": int8[..., heads * head_dim], "s":
 f32[...]}``: one absmax scale per written position (per layer, per slot/
 block, per token — the finest granularity an incremental decode write can
 maintain without re-scaling a page). Writers quantize at the scatter
@@ -215,18 +215,16 @@ def free_replaced_leaves(old_tree, new_tree) -> int:
 
 
 def quantize_kv(x):
-    """Quantize K or V activations position-wise: absmax over the trailing
-    ``(heads, head_dim)`` axes. ``x: [..., h, d]`` -> ``(q int8[..., h, d],
-    scale f32[...])``."""
+    """Quantize K or V activations position-wise: absmax over a position's
+    row, the merged ``heads * head_dim`` axis models/kvcache.py stores.
+    ``x: [..., c]`` -> ``(q int8[..., c], scale f32[...])``."""
     xf = x.astype(jnp.float32)
-    s = jnp.maximum(jnp.max(jnp.abs(xf), axis=(-2, -1)) / 127.0, _EPS)
-    q = jnp.clip(
-        jnp.round(xf / s[..., None, None]), -127, 127
-    ).astype(jnp.int8)
+    s = jnp.maximum(jnp.max(jnp.abs(xf), axis=-1) / 127.0, _EPS)
+    q = jnp.clip(jnp.round(xf / s[..., None]), -127, 127).astype(jnp.int8)
     return q, s
 
 
 def dequantize_kv(q, s, dtype=jnp.float32):
     """Materialize a quantized KV stage back to dense (wire/debug paths
     only — attention uses the factored form and never calls this)."""
-    return (q.astype(jnp.float32) * s[..., None, None]).astype(dtype)
+    return (q.astype(jnp.float32) * s[..., None]).astype(dtype)

@@ -2130,8 +2130,12 @@ class ContinuousBatcher:
                     self._inflight_sem.acquire()
                 try:
                     with tracer.span("engine.decode_dispatch", "serve",
-                                     rows=len(tags), slots=len(active)):
+                                     rows=len(tags),
+                                     slots=len(active)) as span:
                         handle = engine.decode(lengths, active, temps, seeds)
+                        span.set(
+                            kv_rows_written=getattr(handle, "kv_rows", 0)
+                        )
                 except Exception as e:  # noqa: BLE001
                     self._inflight_sem.release()
                     self._fail_slots(tags, e)

@@ -164,6 +164,9 @@ class InFlightBatch:
     # batch_assemble phase) / jax.device_get returned (ends device).
     t_assembled: float = 0.0
     t_got: float = 0.0
+    # Cache rows a decode step writes: a row per layer and cache leaf for
+    # each active lane (an idle lane's sentinel position writes nothing).
+    kv_rows: int = 0
 
 
 @dataclasses.dataclass
@@ -966,7 +969,7 @@ class CausalLMEngine(_AotEngine):
     The cache is a FIXED pool of per-slot pages — one pytree whose every
     leaf is ``[num_layers, slots, cache_len, *trailing]`` (which leaves, and
     what trails them, is models/kvcache.py's ``cache_layout``: dense K and
-    V ``(heads, head_dim)``, or int8 payloads with their scales) plus a
+    V rows of ``heads * head_dim``, or int8 payloads with their scales) plus a
     ``last_token [slots]`` vector — living on device for the engine's
     lifetime and threaded functionally through every executable with
     buffer donation, so each step updates the pool in place and slot
@@ -1107,6 +1110,10 @@ class CausalLMEngine(_AotEngine):
         # What a sequence caches: the model side declares the leaves, the
         # engine handles [layers, slots, positions] of whatever they are.
         self._layout = kvcache.cache_layout(cfg, self.kv_dtype)
+        # rows a decode step writes for each live lane: one a layer and leaf
+        self._kv_rows_per_lane = cfg.num_layers * len(
+            jax.tree.leaves(self._layout)
+        )
         table = (cfg.num_layers, slots, self.cache_len)
         if self._model_sharded:
             self._param_specs = causal_param_specs(params, model_axis="model")
@@ -1829,7 +1836,10 @@ class CausalLMEngine(_AotEngine):
                     f"blocks {list(blocks)} are indexed but their pages "
                     "were never published to the device pool"
                 )
-            return kvcache.split_kv(self._export_compiled(self._pool, jdx))
+            return kvcache.split_kv(
+                self._export_compiled(self._pool, jdx),
+                self.model.cfg.num_heads,
+            )
 
     def import_prefix_pages(
         self, blocks: list[tuple[int, int]], pages_k, pages_v
@@ -1881,7 +1891,7 @@ class CausalLMEngine(_AotEngine):
         return {
             "num_layers": int(self.model.cfg.num_layers),
             "block_tokens": int(self.block_tokens),
-            **kvcache.page_geometry(self._layout),
+            **kvcache.page_geometry(self.model.cfg, self._layout),
             "max_chain": int(self._max_chain),
         }
 
@@ -1899,9 +1909,12 @@ class CausalLMEngine(_AotEngine):
                 "engine built without stream_migrate=True (no slot-export "
                 "cell)"
             )
-        return kvcache.split_kv(self._slot_export_compiled(
-            self._cache, jax.device_put(np.int32(slot), self._rep)
-        ))
+        return kvcache.split_kv(
+            self._slot_export_compiled(
+                self._cache, jax.device_put(np.int32(slot), self._rep)
+            ),
+            self.model.cfg.num_heads,
+        )
 
     def import_slot_pages(self, slot: int, pages_k, pages_v,
                           last_token: int) -> None:
@@ -1934,7 +1947,7 @@ class CausalLMEngine(_AotEngine):
         return {
             "num_layers": int(self.model.cfg.num_layers),
             "cache_len": int(self.cache_len),
-            **kvcache.page_geometry(self._layout),
+            **kvcache.page_geometry(self.model.cfg, self._layout),
         }
 
     def decode(self, lengths, active, temps, seeds) -> InFlightBatch:
@@ -1963,9 +1976,11 @@ class CausalLMEngine(_AotEngine):
             jax.device_put(blen, self._rep), jax.device_put(bact, self._rep),
             jax.device_put(btmp, self._rep), jax.device_put(bseed, self._rep),
         )
+        n = int(np.sum(bact))
         return InFlightBatch(
-            out={"tok": tok}, key=key, n=int(np.sum(bact)), meta=None,
+            out={"tok": tok}, key=key, n=n, meta=None,
             buffers=buffers, layout=self.layout, t_assembled=t_assembled,
+            kv_rows=n * self._kv_rows_per_lane,
         )
 
     def verify(self, drafts, lengths, n_input, temps, seeds) -> InFlightBatch:

@@ -2,7 +2,7 @@
 
 The host half of prefix-cache KV reuse (the vLLM/SGLang recipe adapted to
 this repo's slot-table cache): the ENGINE owns a device-resident pool of
-fixed-size KV pages ``[num_layers, n_blocks, block_tokens, heads,
+fixed-size KV pages ``[num_layers, n_blocks, block_tokens, heads *
 head_dim]`` sharded like the slot cache; this module owns every piece of
 bookkeeping about what those pages MEAN — a token-trie (radix) index
 mapping prompt prefixes to chains of block ids, refcount pins, and LRU

@@ -15,6 +15,7 @@ and only the one that runs this file loads the library.
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -161,6 +162,7 @@ _SLOTS, _CACHE_LEN = 128, 384
 
 
 def _compile_decode(one_chip, kv):
+    from distributed_tensorflow_tpu.models import kvcache
     from distributed_tensorflow_tpu.models.causal_lm import (
         CausalLM,
         CausalLMConfig,
@@ -182,66 +184,123 @@ def _compile_decode(one_chip, kv):
             )["params"]
         ),
     )
-    pages = (
-        cfg.num_layers, _SLOTS, _CACHE_LEN, cfg.num_heads,
-        cfg.hidden_size // cfg.num_heads,
+    table = jax.tree.map(
+        lambda leaf: struct(
+            (cfg.num_layers, _SLOTS, _CACHE_LEN, *leaf.shape), leaf.dtype
+        ),
+        kvcache.cache_layout(cfg, kv),
     )
-    if kv == "int8":
-        table = {
-            "q": struct(pages, jnp.int8),
-            "s": struct(pages[:3], jnp.float32),
-        }
-    else:
-        table = struct(pages, jnp.bfloat16)
     compiled = (
         jax.jit(
             _make_causal_decode(model, _CACHE_LEN), donate_argnums=(1, 2)
         )
         .lower(
-            params, {"k": table, "v": table},
+            params, table,
             struct((_SLOTS,), jnp.int32), struct((_SLOTS,), jnp.int32),
             struct((_SLOTS,), jnp.bool_), struct((_SLOTS,), jnp.float32),
             struct((_SLOTS,), jnp.int32),
         )
         .compile()
     )
-    table_bytes = sum(
+    cache_bytes = sum(
         x.dtype.itemsize * int(np.prod(x.shape))
         for x in jax.tree.leaves(table)
     )
-    return compiled, pages, table_bytes
+    return compiled, (cfg.num_layers, cfg.hidden_size), cache_bytes
 
 
-@pytest.mark.parametrize("kv", ["bf16", "int8"])
-def test_decode_step_never_moves_its_slot_table(one_chip, kv):
-    """The table lives with the cache position minor-most and the scatter
-    and dynamic-update-slice emitters want another layout: written through
-    either, every step copies the table there and back (PERF.md, PR 28:
-    39.5 ms a step, 3.55 GB of scratch). decode_step writes by select;
-    this is the guard that keeps it so when someone touches the layer
-    loop. A compile, not a time."""
-    import re
-
-    compiled, pages, table_bytes = _compile_decode(one_chip, kv)
+def _made_by(compiled, shape):
+    """The entry computation's instructions whose result holds an array of
+    ``shape`` (a regex over the dims), by opcode."""
     text = compiled.as_text()
     entry = text[text.index("\nENTRY "):]
-    dims = ",".join(map(str, pages[1:]))
-    # one layer's pages or all layers', whatever the element type and layout
-    page_table = re.compile(r"\w+\[(?:%d,|1,)?%s\]" % (pages[0], dims))
+    holds = re.compile(r"\w+\[%s\]" % shape)
     made_by = {}
     for line in entry.splitlines():
         m = re.match(r"\s*(?:ROOT )?%(\S+) = (.*?) ([a-z][a-z\-]*)\(", line)
-        if m and page_table.search(m.group(2)):
+        if m and holds.search(m.group(2)):
             made_by.setdefault(m.group(3), []).append(m.group(1))
-    # The operands, and the one select that writes both tables in place;
-    # above all no copy, scatter, slice, concatenate or dynamic-update-slice.
-    assert set(made_by) <= {
-        "parameter", "fusion", "get-tuple-element", "tuple", "bitcast",
-    }, made_by
-    assert len(made_by.get("fusion", ())) <= 2, made_by["fusion"]
+    return made_by
+
+
+@pytest.mark.parametrize("kv", ["bfloat16", "int8"])
+def test_decode_step_never_moves_its_slot_table(one_chip, kv):
+    """A cached position is one contiguous ``heads * head_dim`` row, the
+    table's default layout is the one it lives in, and the step writes its
+    ``layers x slots`` rows as rows of the flat table, in place
+    (models/kvcache.py, "How decode_step writes and reads"). Nothing of a
+    table's size is made for a leaf that has the row: not by a copy, slice,
+    concatenate or convert, and not by a fusion — the select PR 28 wrote
+    with was two, a pass over 3.6 GB a step (PERF.md, PR 33). This is the
+    guard that keeps it so when someone touches the layer loop or the
+    attention's contractions. A compile, not a time."""
+    compiled, (layers, row), cache_bytes = _compile_decode(one_chip, kv)
+    # one layer's pages or all layers', whatever the element type and layout
+    table = _made_by(
+        compiled, r"(?:%d,|1,)?%d,%d,%d" % (layers, _SLOTS, _CACHE_LEN, row)
+    )
+    assert set(table) <= {
+        "parameter", "get-tuple-element", "tuple", "bitcast",
+    }, table
+    # the same bytes as rows: only the two scatters that alias their operand
+    flat = _made_by(compiled, r"%d,%d" % (layers * _SLOTS * _CACHE_LEN, row))
+    assert set(flat) <= {"bitcast", "fusion"}, flat
+    assert len(flat.get("fusion", ())) == 2, flat
+    for name in flat["fusion"]:
+        (line,) = re.findall(
+            r"^\s*%%%s = .*$" % re.escape(name), compiled.as_text(), re.M
+        )
+        assert "kv_write/scatter" in line and "kind=kCustom" in line, line
+        assert '"aliasing_operands":{"lists":[{"indices":["0"' in line, line
     ma = compiled.memory_analysis()
     assert ma.temp_size_in_bytes < 0.2e9, ma.temp_size_in_bytes  # was 3.546e9
-    assert ma.alias_size_in_bytes >= 2 * table_bytes
+    assert ma.alias_size_in_bytes >= cache_bytes  # both sides, every leaf
+
+
+@pytest.mark.parametrize("spelling", ["split_lanes", "merged_row"])
+def test_reading_a_merged_row_per_head_copies_the_layer(one_chip, spelling):
+    """The finding that chose the read (PERF.md, PR 33): with the heads
+    merged in the table, scores written per head — split the lane axis,
+    then PR 28's ``shd,slhd->shl`` — make the compiler copy and convert the
+    layer it reads, 0.2 GB of scratch a layer and more; contracted over the
+    merged row against a block-diagonal query, as ``kvcache._attend`` does,
+    the layer is read where it lies."""
+    from distributed_tensorflow_tpu.models.kvcache import cached_attention
+
+    heads, dim = 12, 64
+
+    def split_lanes(q, table, position):
+        k = table["k"][3].reshape(_SLOTS, _CACHE_LEN, heads, dim)
+        s = jnp.einsum(
+            "shd,slhd->shl", q, k, preferred_element_type=jnp.float32
+        )
+        seen = jnp.arange(_CACHE_LEN) <= position[:, None]
+        return jnp.where(seen[:, None], s, -1e30)
+
+    def merged_row(q, table, position):
+        return cached_attention(
+            q, jax.tree.map(lambda t: t[3], table), position
+        )
+
+    def struct(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    pages = struct((12, _SLOTS, _CACHE_LEN, heads * dim), jnp.bfloat16)
+    compiled = (
+        jax.jit({"split_lanes": split_lanes, "merged_row": merged_row}[spelling])
+        .lower(
+            struct((_SLOTS, heads, dim), jnp.bfloat16),
+            {"k": pages, "v": pages}, struct((_SLOTS,), jnp.int32),
+        )
+        .compile()
+    )
+    scratch = compiled.memory_analysis().temp_size_in_bytes
+    if spelling == "split_lanes":
+        assert scratch >= 0.2e9, scratch
+    else:
+        assert scratch < 8e6, scratch
+        layer = r"%d,%d,%d" % (_SLOTS, _CACHE_LEN, heads * dim)
+        assert not _made_by(compiled, layer)
 
 
 # -------------------------------------- the MLM head over the masked rows

@@ -1,13 +1,18 @@
 """``CausalLM.decode_step`` against write-then-attend, bit for bit.
 
 decode_step attends the slot table as the step found it, with the new row
-selected in, and writes all layers' rows once at the end by a select over
-the stacked table (models/kvcache.py, "Why decode_step writes by
-select"). The reference here is the plain spelling it replaced: per layer,
-scatter the token with ``.at[idx, position].set(mode="drop")``, attend the
-written table, re-stack. Same operand values, so on the CPU the returned
-tables and the logits must be IDENTICAL — for mixed positions, idle lanes at
-``position == cache_len``, a slot reused after a free, and the int8 pytree.
+selected in, and writes all layers' rows once at the end, as rows of the
+flat table (models/kvcache.py, "How decode_step writes and reads"). The
+reference here is the plain spelling it replaced: per layer, scatter the
+token with ``.at[idx, position].set(mode="drop")``, attend the written
+table, re-stack. Same operand values, so on the CPU the returned tables and
+the logits must be IDENTICAL — for mixed positions, idle lanes at
+``position == cache_len``, a position past it, a slot reused after a free,
+and the int8 pytree.
+
+The attention both sides share contracts over the merged ``heads *
+head_dim`` row; the last test holds it to per-head attention written out in
+plain ``jax.numpy``.
 """
 
 from __future__ import annotations
@@ -21,7 +26,10 @@ from distributed_tensorflow_tpu.models.causal_lm import (
     CausalLM,
     CausalLMConfig,
 )
-from distributed_tensorflow_tpu.models.kvcache import cached_attention
+from distributed_tensorflow_tpu.models.kvcache import (
+    cached_attention,
+    chunk_attention,
+)
 from distributed_tensorflow_tpu.models.quant import quantize_kv
 
 _SLOTS, _CACHE_LEN = 5, 24
@@ -44,7 +52,7 @@ class _WriteThenAttend(CausalLM):
         new_k, new_v = [], []
         for i, layer in enumerate(self.layers):
             att = layer.attention
-            q, k, v = att.query(x), att.key(x), att.value(x)
+            q, k, v = att._qkv(x)  # a cached position is one merged row
             if isinstance(k_cache, dict):
                 kc = jax.tree.map(
                     write, {n: t[i] for n, t in k_cache.items()},
@@ -93,10 +101,7 @@ def _table(cfg, kv, seed):
     """A table every page of which holds something: a write to the wrong
     place, or a missed one, changes a value."""
     rng = np.random.default_rng(seed)
-    pages = (
-        cfg.num_layers, _SLOTS, _CACHE_LEN, cfg.num_heads,
-        cfg.hidden_size // cfg.num_heads,
-    )
+    pages = (cfg.num_layers, _SLOTS, _CACHE_LEN, cfg.hidden_size)
     if kv == "int8":
         return {
             "q": jnp.asarray(rng.integers(-127, 128, pages), jnp.int8),
@@ -116,6 +121,8 @@ _SCENARIOS = {
     "mixed_positions": [[0, 7, 23, 3, 12]],
     "idle_lanes": [[_CACHE_LEN, 5, _CACHE_LEN, 0, _CACHE_LEN]],
     "all_idle": [[_CACHE_LEN] * _SLOTS],
+    # past the sentinel is as idle as the sentinel: nothing matches
+    "past_the_cache": [[_CACHE_LEN + 3, 5, 2 * _CACHE_LEN, 0, 1]],
     # slot 1 decodes at 9 and 10, is freed (idle), and its next occupant
     # starts at 2 under pages the first one left behind
     "slot_reused_after_free": [
@@ -152,3 +159,54 @@ def test_decode_step_is_write_then_attend_bit_for_bit(lm, kv, scenario):
             kept[np.flatnonzero(live), np.asarray(positions)[live]] = False
             np.testing.assert_array_equal(new[:, kept], old[:, kept])
             assert (new[:, ~kept] != old[:, ~kept]).any() or not live.any()
+
+
+def _per_head_attention(q, k, v, position, k_scale=None, v_scale=None):
+    """The attention of one layer written out head by head: ``q [R, C, h,
+    d]``, ``k, v [R, L, h, d]`` (an int8 side comes with its ``[R, L]``
+    scale), query ``[r, c]`` seeing cache positions ``<= position[r, c]``."""
+    f32 = lambda a: jnp.asarray(a, jnp.float32)  # noqa: E731
+    s = jnp.einsum("rchd,rlhd->rhcl", f32(q), f32(k))
+    if k_scale is not None:
+        s = s * k_scale[:, None, None, :]
+    s = s / np.sqrt(q.shape[-1])
+    seen = jnp.arange(k.shape[1]) <= position[:, None, :, None]
+    p = jax.nn.softmax(jnp.where(seen, s, -jnp.inf), axis=-1)
+    if v_scale is not None:
+        p = p * v_scale[:, None, None, :]
+    return jnp.einsum("rhcl,rlhd->rchd", p, f32(v))
+
+
+@pytest.mark.parametrize("kv", ["float32", "bfloat16", "int8"])
+@pytest.mark.parametrize("which", ["cached", "chunk"])
+def test_merged_row_attention_is_per_head_attention(which, kv):
+    """``_attend`` contracts over the merged ``heads * head_dim`` row with a
+    block-diagonal query and keeps each head's own lanes of the context;
+    that is attention per head, for one query a slot and for a chunk."""
+    rows, length, heads, dim, chunk = 4, 24, 3, 8, 1 if which == "cached" else 5
+    rng = np.random.default_rng(11)
+    dtype = jnp.float32 if kv == "int8" else jnp.dtype(kv)
+    q = jnp.asarray(rng.normal(size=(rows, chunk, heads, dim)), dtype)
+    position = jnp.asarray(rng.integers(0, length, (rows, chunk)), jnp.int32)
+    sides, plain = {}, {}
+    for name in ("k", "v"):
+        if kv == "int8":
+            page = rng.integers(-127, 128, (rows, length, heads, dim))
+            scale = jnp.asarray(rng.uniform(0.001, 0.02, (rows, length)), jnp.float32)
+            page = jnp.asarray(page, jnp.int8)
+            sides[name] = {"q": page.reshape(rows, length, -1), "s": scale}
+            plain[name], plain[name + "_scale"] = page, scale
+        else:
+            page = jnp.asarray(rng.normal(size=(rows, length, heads, dim)), dtype)
+            sides[name] = page.reshape(rows, length, -1)
+            plain[name] = page
+    if which == "cached":
+        got = jax.jit(cached_attention)(q[:, 0], sides, position[:, 0])[:, None]
+    else:
+        got = jax.jit(chunk_attention)(q, sides, position)
+    assert got.shape == q.shape and got.dtype == q.dtype
+    want = _per_head_attention(q, plain.pop("k"), plain.pop("v"), position, **plain)
+    np.testing.assert_allclose(
+        np.asarray(got, np.float32), np.asarray(want),
+        atol=3e-2 if kv == "bfloat16" else 1e-5,
+    )

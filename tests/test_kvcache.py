@@ -20,6 +20,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from distributed_tensorflow_tpu.models import kvcache
 from distributed_tensorflow_tpu.models.causal_lm import CausalLMConfig
+from distributed_tensorflow_tpu.models.quant import _EPS
 from distributed_tensorflow_tpu.parallel.mesh import build_mesh
 from distributed_tensorflow_tpu.serve.engine import (
     CausalLMEngine,
@@ -45,13 +46,13 @@ _LAYOUTS = {
     # name: (layout, leaves as (trailing, dtype, spec of a table), bytes/token)
     "dense_bf16": lambda: (
         _lm_base("bfloat16"),
-        [((12, 64), "bfloat16", P(None, None, None, "model", None))] * 2,
+        [((768,), "bfloat16", P(None, None, None, "model"))] * 2,
         2 * 12 * 768 * 2,
     ),
     "int8": lambda: (
         _lm_base("int8"),
         [
-            ((12, 64), "int8", P(None, None, None, "model", None)),
+            ((768,), "int8", P(None, None, None, "model")),
             ((), "float32", P(None, None, None)),
         ] * 2,
         2 * 12 * (768 + 4),
@@ -178,26 +179,58 @@ def test_engine_side_operations_work_over_any_layout(name):
             np.testing.assert_array_equal(got[:, 2], sent[:, 2])
 
 
+def _parent_page(x, kv):
+    """What the parent commit kept, and put on the wire, for fresh ``x [..,
+    heads, head_dim]``: the cast, or int8 under one absmax scale a position
+    taken over ``(heads, head_dim)``. Plain numpy."""
+    if kv != "int8":
+        return np.asarray(jnp.asarray(x).astype(kv))
+    scale = np.maximum(
+        np.abs(x).max(axis=(-2, -1)) / np.float32(127.0), np.float32(_EPS)
+    )
+    q = np.clip(np.round(x / scale[..., None, None]), -127, 127)
+    return {"q": q.astype(np.int8), "s": scale}
+
+
 @pytest.mark.parametrize("kv", ["float32", "bfloat16", "int8"])
 def test_host_boundary_speaks_pages_k_pages_v(kv):
     """What serve/disagg.py and the wire headers are handed is what they were
-    before the cache had one home: ``(pages_k, pages_v)``, plain arrays or the
-    ``{"q", "s"}`` pair, and the payload's geometry and dtype."""
-    layout = kvcache.cache_layout(
-        CausalLMConfig(hidden_size=32, num_heads=2), kv
+    before the cache had one home, and before a cached position became one
+    merged row: ``(pages_k, pages_v)``, plain arrays or the ``{"q", "s"}``
+    pair, ``[.., heads, head_dim]``, the payload's geometry and dtype — and
+    for the same tokens the same bytes the parent exported."""
+    nl, slots, cache_len, heads, head_dim, prompt = 3, 2, 8, 2, 16, 5
+    cfg = CausalLMConfig(
+        hidden_size=heads * head_dim, num_heads=heads, num_layers=nl
     )
-    assert kvcache.page_geometry(layout) == {
-        "heads": 2, "head_dim": 16, "dtype": kv,
+    layout = kvcache.cache_layout(cfg, kv)
+    assert kvcache.page_geometry(cfg, layout) == {
+        "heads": heads, "head_dim": head_dim, "dtype": kv,
     }
     mesh = build_mesh({"data": 1}, devices=jax.devices()[:1])
-    stage = kvcache.zeros(
-        layout, (3, 2, 4), kvcache.shardings(layout, mesh)
-    )
-    pages_k, pages_v = kvcache.split_kv(stage)
+    sharding = kvcache.shardings(layout, mesh)
+    stage = kvcache.zeros(layout, (nl, slots, 4), sharding)
+    pages_k, pages_v = kvcache.split_kv(stage, heads)
     if kv == "int8":
         assert sorted(pages_k) == sorted(pages_v) == ["q", "s"]
-        assert pages_k["q"].shape == (3, 2, 4, 2, 16)
-        assert pages_k["s"].shape == (3, 2, 4)
+        assert pages_k["q"].shape == (nl, slots, 4, heads, head_dim)
+        assert pages_k["s"].shape == (nl, slots, 4)
     else:
-        assert pages_k.shape == pages_v.shape == (3, 2, 4, 2, 16)
+        assert pages_k.shape == pages_v.shape == (nl, slots, 4, heads, head_dim)
     _same(kvcache.join_kv(pages_k, pages_v), stage)
+
+    # a prompt's per-head K and V, written as prefill writes them and taken
+    # out as stream migration takes a slot's lane: the parent's bytes
+    rng = np.random.default_rng(5)
+    fresh = rng.normal(size=(2, nl, 1, prompt, heads, head_dim)).astype(
+        np.float32
+    )
+    rows = jnp.asarray(fresh).reshape(2, nl, 1, prompt, heads * head_dim)
+    cache = kvcache.write_prompt(
+        kvcache.zeros(layout, (nl, slots, cache_len), sharding),
+        jnp.asarray([1], jnp.int32), *rows,
+    )
+    lane = _make_export()(cache, jnp.asarray(1, jnp.int32))
+    for got, x in zip(kvcache.split_kv(lane, heads), fresh, strict=True):
+        got = jax.tree.map(lambda a: np.asarray(a)[:, :prompt], got)
+        _same(got, _parent_page(x[:, 0], kv))

@@ -278,6 +278,17 @@ def test_decode_dispatch_rows_are_the_live_lanes(traced_run):
     assert [s.args["rows"] for s in planned] == spy.live
 
 
+def test_decode_dispatch_counts_the_cache_rows_it_writes(traced_run):
+    """``kv_rows_written``: a row per layer and cache leaf (K and V) for
+    every live lane; an idle lane's sentinel position writes nothing."""
+    spans, spy, *_ = traced_run
+    per_lane = 2 * spy.model.cfg.num_layers
+    dispatched = sorted(spans["engine.decode_dispatch"], key=lambda s: s.t0)
+    assert [s.args["kv_rows_written"] for s in dispatched] == [
+        n * per_lane for n in spy.live
+    ]
+
+
 def test_request_phases_keep_their_three_keys(traced_run):
     spans, _spy, futs, _snap = traced_run
     for f in futs:
