@@ -35,3 +35,8 @@ from distributed_tensorflow_tpu.models.causal_lm import (  # noqa: F401
     make_causal_lm_loss,
     sample_tokens,
 )
+from distributed_tensorflow_tpu.models.sambay import (  # noqa: F401
+    SambaY,
+    SambaYConfig,
+    sambay_init_params,
+)

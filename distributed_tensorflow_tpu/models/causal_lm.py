@@ -21,10 +21,16 @@ Five forwards share one param tree:
   them into its slot cache (``kvcache.write_prompt``, from serve/engine.py
   ``CausalLMEngine``).
 
+- ``prefill_rows(input_ids, attention_mask, lengths) -> (logits [B, V],
+  fresh)`` — what the engine's prefill executable calls: ``prefill`` with
+  the logits at each row's last real position and the K/V as the tree
+  ``kvcache.write_prompt`` takes.
+
 The other three take and return ONE ``cache``: a pytree whose every leaf is
-``[nl, slots, positions, *trailing]``. What the leaves are (dense K/V, or
-int8 payloads with their scales) is models/kvcache.py's business alone; the
-methods here are written once, over its operations.
+``[nl, slots, positions, *trailing]``. What the leaves are
+(dense K/V, or int8 payloads with their scales) is models/kvcache.py's
+business alone; the methods here are written once, over its operations.
+``cache_layout`` and ``param_specs`` are what the engine asks the model for.
 
 - ``decode_step(token [S], position [S], cache) -> (logits [S,V], cache')``
   — ONE token per cache slot: embed at the slot's position, write the new
@@ -174,7 +180,7 @@ class CausalSelfAttention(nn.Module):
         # prefill slot's pages) and its attention clamps — the lane's
         # output is garbage nobody reads.
         q, k, v = self._qkv(x)  # [S, h, d], [S, h * d] x 2
-        rows = kvcache.encode(cache, k, v)
+        rows = kvcache.encode(cache, {"k": k, "v": v})
         with jax.named_scope("cached_attention"):
             read = kvcache.select_rows(cache, rows, position, slot_axis=0)
         ctx = kvcache.cached_attention(q, read, position)
@@ -185,7 +191,7 @@ class CausalSelfAttention(nn.Module):
         # padding lanes -> the scatter drops); cache [B, Lc, ..].
         q, k, v = self._qkv(x)  # [B, C, h, d], [B, C, h * d] x 2
         cache = kvcache.scatter_rows(
-            cache, kvcache.encode(cache, k, v), positions
+            cache, kvcache.encode(cache, {"k": k, "v": v}), positions
         )
         ctx = kvcache.chunk_attention(q, cache, positions)
         return self._finish(x, ctx), cache
@@ -292,6 +298,17 @@ class CausalLM(nn.Module):
             ks.append(k)
             vs.append(v)
         return self._head(x), jnp.stack(ks), jnp.stack(vs)
+
+    def prefill_rows(self, input_ids, attention_mask, lengths):
+        logits, k, v = self.prefill(input_ids, attention_mask)
+        rows = jnp.arange(input_ids.shape[0])
+        return logits[rows, jnp.maximum(lengths, 1) - 1], {"k": k, "v": v}
+
+    def cache_layout(self, kv_dtype: str):
+        return kvcache.cache_layout(self.cfg, kv_dtype)
+
+    def param_specs(self, params, model_axis: str | None = "model"):
+        return causal_param_specs(params, model_axis=model_axis)
 
     def decode_step(self, token, position, cache):
         # Clamp for the position-embedding lookup only; the raw (possibly

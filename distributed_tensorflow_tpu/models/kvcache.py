@@ -1,21 +1,33 @@
 """What a sequence caches between steps — the one module that knows.
 
-A cache is a pytree whose every leaf is ``[layers, slots, positions,
-*trailing]``. Everything outside this module (serve/engine.py above all)
-addresses the first three axes and maps over the leaves; which leaves there
-are, what trails them, their dtype, how they shard and how a fresh row is
-encoded into them is decided here:
+A cache is a pytree of arrays, described leaf by leaf (:class:`Leaf`): every
+leaf is ``[layers, slots, *after, *trailing]``. Leaves that share ``layers``
+and what comes ``after`` the slot axis are a group, and say so: ``POSITIONS``
+(a table of the engine's ``cache_len`` positions: a transferable page per
+position), a ring length (a window's last rows, row ``position % ring``), or
+``None`` (state that has no positions at all). ``CausalLM`` caches one group,
+``kv``; a hybrid model several, side by side. Everything outside this module
+(serve/engine.py above all) addresses the slot axis, asks the layout for
+sizes and maps over the leaves; which leaves there are, what trails them,
+their dtype, how they shard and how a fresh row is encoded into them is
+decided here and by the model's ``cache_layout``:
 
-- :func:`cache_layout` describes the leaves (:class:`Leaf`). Dense K/V is
-  ``{"k", "v"}``, each one row of ``heads * head_dim`` in the store dtype;
-  int8 K/V makes each side the ``{"q", "s"}`` pair of models/quant.py: the
-  int8 payload and its float32 per-position scale, which has no trailing
-  axes.
-- ``specs`` / ``shardings`` / ``structs`` / ``zeros`` / ``bytes_per_token``
-  are one ``tree.map`` over that description each, for any layout.
+- :func:`cache_layout` describes ``CausalLM``'s group. Dense K/V is ``{"k",
+  "v"}``, each one row of ``heads * head_dim`` in the store dtype; int8 K/V
+  makes each side the ``{"q", "s"}`` pair of models/quant.py: the int8
+  payload and its float32 per-position scale, which has no trailing axes.
+  models/sambay.py declares three groups: ``state`` (positionless),
+  ``window`` (a ring) and ``full`` (positions).
+- ``specs`` / ``shardings`` / ``structs`` / ``zeros`` / ``bytes_per_token`` /
+  ``components`` are one pass over that description each, for any layout.
+- :func:`require_pages` is what a mode that moves cached positions about as
+  pages (prefix pool, chunked prefill, verify, export/import, int8, ``model``
+  sharding) calls at construction: it raises, naming the group, for a layout
+  that has a positionless or ring group.
 - The model's reads and writes — ``take_layer``, ``encode``, ``select_rows``,
   ``write_rows``, ``scatter_rows``, ``stack_layers``, ``write_prompt``,
-  ``cached_attention``, ``chunk_attention`` — are written once for every form.
+  ``cached_attention``, ``chunk_attention``, ``paired_attention`` — are
+  written once for every form.
 - ``split_kv`` / ``join_kv`` / ``page_geometry`` convert at the engine's host
   boundary, where serve/disagg.py and the wire format still speak of
   ``pages_k, pages_v`` and of ``[.., heads, head_dim]``: a row-major reshape
@@ -72,79 +84,165 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from distributed_tensorflow_tpu.models.quant import quantize_kv
 
 MASK_VALUE = -1e30
-_LEAD = 3  # layers, slots (or pool blocks), positions
+POSITIONS = "positions"  # Leaf.after: the engine's cache_len follows the slots
 
 
 @dataclasses.dataclass(frozen=True)
 class Leaf:
-    """One array of a cache, by what follows ``[layers, slots, positions]``:
-    the trailing shape (global, before any sharding), the dtype, and one
-    mesh axis name or ``None`` per trailing axis."""
+    """One array of a cache: ``[layers, slots, *after, *shape]``. ``shape`` is
+    what trails (global, before any sharding), ``partition`` one mesh axis
+    name or ``None`` per trailing axis. ``layers``, ``after`` and ``group``
+    are the group's, alike on all its leaves: how many layers keep this,
+    what follows the slot axis — ``POSITIONS``, a ring length, or ``None``
+    for state without positions — and the name the group goes by."""
 
     shape: tuple[int, ...]
     dtype: np.dtype
     partition: tuple[str | None, ...]
+    layers: int
+    after: int | str | None = POSITIONS
+    group: str = "kv"
+
+    def lead(self, axes: tuple[int, ...]) -> tuple[int, ...]:
+        """The leading shape for ``axes = (*front, positions)``: ``(slots,
+        cache_len)`` of a slot table, ``(blocks, block_tokens)`` of a pool,
+        ``(cache_len,)`` of one slot's lane. A ring keeps its own length in
+        place of ``positions``; state has nothing there."""
+        *front, positions = axes
+        after = {None: (), POSITIONS: (positions,)}.get(
+            self.after, (self.after,)
+        )
+        return (self.layers, *front, *after)
+
+    @property
+    def nbytes(self) -> int:
+        """Bytes of one slot's one position (or ring row, or state)."""
+        return self.layers * math.prod(self.shape) * self.dtype.itemsize
 
 
 def cache_layout(cfg, kv_dtype: str):
-    """The leaves ``CausalLM(cfg)`` caches under ``kv_dtype`` (a concrete
+    """The one group ``CausalLM(cfg)`` caches under ``kv_dtype`` (a concrete
     name from ``CausalLMEngine._plan_quant``). Heads split over
     ``cfg.model_axis``; a scale has no axis to split."""
     row = (cfg.hidden_size,)  # heads x head_dim, merged: one contiguous row
     split = (cfg.model_axis,)
+    nl = cfg.num_layers
     if kv_dtype == "int8":
         side = {
-            "q": Leaf(row, np.dtype(np.int8), split),
-            "s": Leaf((), np.dtype(np.float32), ()),
+            "q": Leaf(row, np.dtype(np.int8), split, nl),
+            "s": Leaf((), np.dtype(np.float32), (), nl),
         }
     else:
-        side = Leaf(row, jnp.dtype(kv_dtype), split)
+        side = Leaf(row, jnp.dtype(kv_dtype), split, nl)
     return {"k": side, "v": side}
+
+
+def _by_group(layout) -> dict[str, list[Leaf]]:
+    out = {}
+    for leaf in jax.tree.leaves(layout):
+        out.setdefault(leaf.group, []).append(leaf)
+    return out
+
+
+def require_pages(layout, mode: str) -> None:
+    """``mode`` treats a cached position as a page it may copy, share,
+    quantize or shard by itself. That holds for a table of positions only: a
+    ring forgets, and state has no positions."""
+    for leaf in jax.tree.leaves(layout):
+        if leaf.after != POSITIONS:
+            kind = "positionless" if leaf.after is None else "ring"
+            raise ValueError(
+                f"{mode} needs every cached position to be a transferable "
+                f"page; cache group {leaf.group!r} is {kind} "
+                f"(after={leaf.after!r}): not supported for this model"
+            )
 
 
 # -- any layout: one tree.map over the description each -------------------
 
 
-def specs(layout, lead: int = _LEAD):
-    """PartitionSpec per leaf; ``lead`` unsharded axes come first (3 for a
-    table or a stage of pool pages, 2 for one slot's lane)."""
+def specs(layout, lane: bool = False):
+    """PartitionSpec per leaf of a slot table (or a pool, or a stage of
+    either); ``lane`` for one slot's lane, which drops the slot axis. The
+    leading axes are never sharded."""
     return jax.tree.map(
-        lambda leaf: P(*(None,) * lead, *leaf.partition), layout
+        lambda leaf: P(
+            *(None,) * (len(leaf.lead((0, 0))) - lane), *leaf.partition
+        ),
+        layout,
     )
 
 
-def shardings(layout, mesh, lead: int = _LEAD):
+def shardings(layout, mesh, lane: bool = False):
     return jax.tree.map(
-        lambda spec: NamedSharding(mesh, spec), specs(layout, lead),
+        lambda spec: NamedSharding(mesh, spec), specs(layout, lane),
         is_leaf=lambda x: isinstance(x, P),
     )
 
 
-def structs(layout, lead: tuple[int, ...], sharding):
-    """ShapeDtypeStruct per leaf at leading shape ``lead``; ``sharding`` is
-    the matching tree from :func:`shardings`."""
+def structs(layout, axes: tuple[int, ...], sharding):
+    """ShapeDtypeStruct per leaf for ``axes`` (:meth:`Leaf.lead`);
+    ``sharding`` is the matching tree from :func:`shardings`."""
     return jax.tree.map(
         lambda leaf, s: jax.ShapeDtypeStruct(
-            (*lead, *leaf.shape), leaf.dtype, sharding=s
+            (*leaf.lead(axes), *leaf.shape), leaf.dtype, sharding=s
         ),
         layout, sharding,
     )
 
 
-def zeros(layout, lead: tuple[int, ...], sharding):
+def zeros(layout, axes: tuple[int, ...], sharding):
     return jax.tree.map(
         lambda st: jax.device_put(jnp.zeros(st.shape, st.dtype), st.sharding),
-        structs(layout, lead, sharding),
+        structs(layout, axes, sharding),
     )
 
 
-def bytes_per_token(layout, num_layers: int) -> int:
-    """Bytes ONE cached position occupies across all layers and leaves
-    (K + V, plus scales at int8)."""
-    return num_layers * sum(
-        math.prod(leaf.shape) * leaf.dtype.itemsize
-        for leaf in jax.tree.leaves(layout)
+def bytes_per_token(layout) -> int:
+    """Bytes ONE more cached position occupies across the layers and leaves
+    that keep positions (K + V, plus scales at int8). A ring and state cost
+    the same whatever the length: :func:`components`."""
+    return sum(
+        leaf.nbytes for leaf in jax.tree.leaves(layout)
+        if leaf.after == POSITIONS
     )
+
+
+def components(layout, axes: tuple[int, ...]) -> dict[str, tuple[int, str]]:
+    """``(bytes at axes, storage dtype)`` of each group, by the name the
+    memory registry lists it under: ``cache.<group>``, and ``kv_slot_cache``
+    for the K/V table of a model that caches nothing else, the name it always
+    had. The dtype is that of the group's largest leaf: the int8 payload, not
+    its scale."""
+    out = {}
+    for group, leaves in _by_group(layout).items():
+        biggest = max(leaves, key=lambda leaf: leaf.nbytes)
+        out["kv_slot_cache" if group == "kv" else f"cache.{group}"] = (
+            sum(
+                leaf.nbytes * math.prod(leaf.lead(axes)[1:]) for leaf in leaves
+            ),
+            str(np.dtype(biggest.dtype).name),
+        )
+    return out
+
+
+def step_writes(layout, live: int) -> dict[str, int]:
+    """What ONE decode step writes for ``live`` lanes, as the counters the
+    ``engine.decode_dispatch`` span carries: ``<group>_rows_written``, a row
+    per layer and leaf of a group that has positions or a ring (a scale
+    counts as a row), and ``<group>_bytes_written`` of a positionless group,
+    which is rewritten whole."""
+    out = {}
+    for group, leaves in _by_group(layout).items():
+        if leaves[0].after is None:
+            out[f"{group}_bytes_written"] = live * sum(
+                leaf.nbytes for leaf in leaves
+            )
+        else:
+            out[f"{group}_rows_written"] = live * sum(
+                leaf.layers for leaf in leaves
+            )
+    return out
 
 
 # -- the engine's host boundary -------------------------------------------
@@ -155,18 +253,22 @@ def merge_heads(a):
     return a.reshape(*a.shape[:-2], -1)
 
 
+def _is_side(x) -> bool:
+    return isinstance(x, dict) and set(x) == {"q", "s"}
+
+
 def _payload(side, fn):
     """``fn`` over the leaf of a side that has the row; a scale rides as is."""
-    if isinstance(side, dict):
+    if _is_side(side):
         return {**side, "q": fn(side["q"])}
     return fn(side)
 
 
 def split_kv(tree, heads: int):
-    """A cache-shaped tree as the ``(pages_k, pages_v)`` the host half takes:
-    plain arrays, or the ``{"q", "s"}`` pair each. The wire says ``[..,
-    heads, head_dim]`` where the cache holds one merged row: a row-major
-    reshape, the same bytes."""
+    """A K/V tree (slot table, lane, pool stage) as the ``(pages_k,
+    pages_v)`` the host half takes: plain arrays, or the ``{"q", "s"}`` pair
+    each. The wire says ``[.., heads, head_dim]`` where the cache holds one
+    merged row: a row-major reshape, the same bytes."""
     split = lambda a: a.reshape(*a.shape[:-1], heads, -1)  # noqa: E731
     return _payload(tree["k"], split), _payload(tree["v"], split)
 
@@ -179,12 +281,13 @@ def join_kv(pages_k, pages_v):
 
 
 def page_geometry(cfg, layout) -> dict:
-    """What the wire headers say of a page: the model's heads, and the
-    payload's dtype — int8 pools report int8, so fp32 and int8 peers refuse
-    each other's pages."""
+    """What the wire headers say of a page: the layers that keep one, the
+    model's heads, and the payload's dtype — int8 pools report int8, so fp32
+    and int8 peers refuse each other's pages."""
     side = layout["k"]
-    payload = side["q"] if isinstance(side, dict) else side
+    payload = side["q"] if _is_side(side) else side
     return {
+        "num_layers": int(payload.layers),
         "heads": int(cfg.num_heads),
         "head_dim": int(cfg.hidden_size // cfg.num_heads),
         "dtype": str(np.dtype(payload.dtype).name),
@@ -195,6 +298,7 @@ def page_geometry(cfg, layout) -> dict:
 
 
 def cache_len(cache) -> int:
+    """Positions of a K/V table ``[nl, rows, positions, ..]``."""
     return jax.tree.leaves(cache)[0].shape[2]
 
 
@@ -202,22 +306,30 @@ def take_layer(cache, i: int):
     return jax.tree.map(lambda a: a[i], cache)
 
 
+def put_layer(cache, i: int, layer):
+    """``cache`` with layer ``i`` replaced: the write of state that is
+    rewritten whole each step, in place when ``layer`` was computed from
+    ``take_layer(cache, i)`` of the same value."""
+    return jax.tree.map(lambda a, x: a.at[i].set(x), cache, layer)
+
+
 def _encode(like, fresh):
-    if isinstance(like, dict):
+    if _is_side(like):
         # int8: quantize per position at the write; attention reads the
         # factored per-position scales.
         return dict(zip(("q", "s"), quantize_kv(fresh)))
     return fresh.astype(like.dtype)
 
 
-def encode(like, k, v):
-    """Fresh ``k, v: [..., h * d]`` (the projections' heads merged, as a
-    cached row is) as rows of the form ``like`` (a cache, or one layer of
-    it) stores: a cast, or the int8 ``{"q", "s"}`` pair. Every writer
-    encodes here, so a page is the same bits whichever path — prompt
-    prefill, chunk, verify, decode — wrote it."""
+def encode(like, fresh):
+    """``fresh`` (a tree of arrays by leaf name: ``{"k", "v"}``, each ``[...,
+    h * d]``, the projections' heads merged as a cached row is) as rows of
+    the form ``like`` (a group's leaves, or one layer of them) stores: a
+    cast, or the int8 ``{"q", "s"}`` pair. Every writer encodes here, so a
+    page is the same bits whichever path — prompt prefill, chunk, verify,
+    decode — wrote it."""
     with jax.named_scope("kv_write"):
-        return {"k": _encode(like["k"], k), "v": _encode(like["v"], v)}
+        return jax.tree.map(_encode, like, fresh, is_leaf=_is_side)
 
 
 def select_rows(table, rows, position, slot_axis: int):
@@ -249,7 +361,7 @@ def write_rows(cache, rows, position):
     with jax.named_scope("kv_write"):
         return jax.tree.map(
             lambda t, r: (
-                _scatter_flat(t, r, position) if t.ndim > _LEAD
+                _scatter_flat(t, r, position) if t.ndim > 3
                 else select_rows(t, r, position, slot_axis=1)
             ),
             cache, rows,
@@ -257,7 +369,7 @@ def write_rows(cache, rows, position):
 
 
 def _scatter_flat(t, r, position):
-    nl, s, l = t.shape[:_LEAD]
+    nl, s, l = t.shape[:3]
     lane = jnp.arange(nl * s).reshape(nl, s)  # layer * S + slot
     # an idle lane's index lies past the table, each its own: all dropped,
     # and the indices stay unique as promised
@@ -288,15 +400,17 @@ def stack_layers(layers):
         return jax.tree.map(lambda *xs: jnp.stack(xs), *layers)
 
 
-def write_prompt(cache, slots, k, v):
-    """The slot table with whole prefilled prompts in it: ``k, v: [nl, T, L,
-    h * d]`` fresh from ``CausalLM.prefill`` go to positions ``[0, L)`` of
-    ``slots [T]``, contiguous rows each. A tier's padding rows carry slot
-    index == S (one past the pool), so their writes drop and never dirty a
-    live slot's pages. Encoded by :func:`encode` like every other write: a
+def write_prompt(cache, slots, fresh):
+    """The slot table with whole prefilled prompts in it. ``fresh`` mirrors
+    the cache, arrays ``[layers, T, ..]`` from the model's ``prefill_rows``:
+    positions ``[0, L)`` of a K/V table (contiguous rows each), a ring's rows
+    where they live, a row's state whole — each is what follows ``slots [T]``
+    in its leaf, so one indexed set writes any of them. A tier's padding rows
+    carry slot index == S (one past the pool), so their writes drop and never
+    dirty a live slot. Encoded by :func:`encode` like every other write: a
     prefilled page is bit-identical to one the decode path would have
     written."""
-    rows = encode(cache, k, v)
+    rows = encode(cache, fresh)
     with jax.named_scope("kv_write"):
         return jax.tree.map(
             lambda c, r: c.at[:, slots, : r.shape[2]].set(r, mode="drop"),
@@ -306,7 +420,7 @@ def write_prompt(cache, slots, k, v):
 
 def _operand(side):
     """(what the einsum reads, per-position scale or None)."""
-    if isinstance(side, dict):
+    if _is_side(side):
         return side["q"].astype(jnp.float32), side["s"]
     return side, None
 
@@ -367,3 +481,44 @@ def chunk_attention(q, cache, position):
     # the context comes heads-second, as the product leaves it: the CPU
     # backend has no bf16 dot whose result is transposed ("->bqhc")
     return _attend(q, cache, position, "bqch,blc->bhql", "bhql,blc->bhqc")
+
+
+def paired_attention(q, cache, valid, lam):
+    """Differential attention of one token per row over a layer's K/V rows,
+    grouped-query: ``q [S, 2P, d]`` (query pair ``p`` is heads ``2p, 2p +
+    1``), ``cache {"k", "v"}`` each ``[S, L, 2G * d]`` (K/V pair ``g`` is the
+    lanes ``[2g * d, (2g + 2) * d)``; pair ``p`` reads pair ``p // (P //
+    G)``), ``valid [S, L]`` the rows a query may see — table positions ``<=
+    position``, or the filled rows of a ring, whose order does not matter
+    without positional encoding — and ``lam`` the layer's scalar. Returns
+    ``(softmax(q1 k1) - lam * softmax(q2 k2)) [v1; v2]`` as ``[S, P, 2d]``
+    float32, before the pair norm. Both contractions run over the merged row
+    as the table holds it (module docstring: a lane split copies the layer):
+    the scores against a block-diagonal query, and of the context ``[S, P, 2G
+    * d]`` each pair keeps its K/V pair's 128-aligned lanes."""
+    k, v = cache["k"], cache["v"]
+    n_q, d = q.shape[-2:]
+    c = k.shape[-1]
+    n_kv = c // d
+    per = n_q // n_kv  # query heads a K/V head serves, two pairs' worth
+    head = jnp.arange(n_q)
+    kv_of = 2 * (head // (2 * per)) + head % 2  # [2P]: the K/V head read
+    own = (jnp.arange(c)[:, None] // d) == kv_of  # [c, 2P]
+    qt = jnp.tile(jnp.swapaxes(q, -1, -2), (1, n_kv, 1))  # [S, c, 2P]
+    qb = jnp.where(own, qt, 0)
+    s = jnp.einsum("sch,slc->shl", qb, k, preferred_element_type=jnp.float32)
+    s = s * d ** -0.5
+    seen = valid[:, None, :]
+    s = jnp.where(seen, s, MASK_VALUE)
+    p = jax.nn.softmax(s, axis=-1) * seen  # [S, 2P, L]
+    p = p.reshape(p.shape[0], n_q // 2, 2, -1)
+    w = p[:, :, 0] - lam * p[:, :, 1]  # [S, P, L]
+    ctx = jnp.einsum(
+        "spl,slc->spc", w.astype(v.dtype), v,
+        preferred_element_type=jnp.float32,
+    )  # [S, P, c]
+    n_g = n_kv // 2
+    ctx = ctx.reshape(ctx.shape[0], n_g, per, n_g, 2 * d)
+    mine = jnp.eye(n_g, dtype=bool)[:, None, :, None]
+    out = jnp.sum(jnp.where(mine, ctx, 0), axis=3)  # [S, G, per, 2d]
+    return out.reshape(out.shape[0], n_q // 2, 2 * d)

@@ -1170,6 +1170,10 @@ class ContinuousBatcher:
                 "decode_aliased_bytes": getattr(
                     self._engine, "decode_aliased_bytes", None
                 ),
+                # What the slot table is made of, as /memz names it:
+                # {component: [bytes, storage dtype]} — one K/V table, or a
+                # hybrid model's state, rings and table side by side.
+                "cache_groups": getattr(self._engine, "cache_groups", None),
                 # Emitted tokens per decode/verify step completion: 1.0 on
                 # a plain engine, >1 when speculation is winning.
                 "tokens_per_step": (
@@ -2002,7 +2006,12 @@ class ContinuousBatcher:
                             for i, s in admissions
                         ])
                         # ("prefill", tier, bucket) on a grid engine
-                        span.set(bucket=getattr(handle, "key", (0,))[-1])
+                        span.set(
+                            bucket=getattr(handle, "key", (0,))[-1],
+                            real_tokens=sum(
+                                len(s.full_prompt) for _, s in admissions
+                            ),
+                        )
                 except Exception as e:  # noqa: BLE001 — fail the rows, not the server
                     # Fail ONLY the admitted rows; the step planned below
                     # still dispatches (its bookkeeping already advanced,
@@ -2133,9 +2142,7 @@ class ContinuousBatcher:
                                      rows=len(tags),
                                      slots=len(active)) as span:
                         handle = engine.decode(lengths, active, temps, seeds)
-                        span.set(
-                            kv_rows_written=getattr(handle, "kv_rows", 0)
-                        )
+                        span.set(**getattr(handle, "written", {}))
                 except Exception as e:  # noqa: BLE001
                     self._inflight_sem.release()
                     self._fail_slots(tags, e)

@@ -164,9 +164,10 @@ class InFlightBatch:
     # batch_assemble phase) / jax.device_get returned (ends device).
     t_assembled: float = 0.0
     t_got: float = 0.0
-    # Cache rows a decode step writes: a row per layer and cache leaf for
-    # each active lane (an idle lane's sentinel position writes nothing).
-    kv_rows: int = 0
+    # What a decode step writes into the cache, by group
+    # (kvcache.step_writes): a row per layer and leaf for each active lane,
+    # the bytes of positionless state (an idle lane writes nothing).
+    written: dict = dataclasses.field(default_factory=dict)
 
 
 @dataclasses.dataclass
@@ -727,27 +728,28 @@ class BertInferenceEngine(_AotEngine):
 
 # -- LM grid executable bodies. A cache (slot table, prefix pool, a stage
 # -- of either) is ONE pytree operand whose every leaf is [layers, slots or
-# -- blocks, positions, *trailing]; these bodies address the first three
-# -- axes and map over the leaves. What the leaves are is models/kvcache.py's.
+# -- blocks, ...]; these bodies address the slot axis and map over the
+# -- leaves. What the leaves are, and what follows their slots, is the
+# -- model's (its cache_layout) and models/kvcache.py's. The bodies that
+# -- address a positions axis too (chunk, insert, verify) are compiled only
+# -- for a layout whose every group has one (kvcache.require_pages).
 
 
 def _make_causal_prefill(model):
     """Prefill executable body for one (tier, bucket): run the full causal
-    forward, write every layer's K/V into the admitted rows' slot pages
-    (``kvcache.write_prompt``: padding rows drop, pages are encoded as the
-    decode path would have), and sample each row's FIRST generated token
-    on-device."""
+    forward, write what every group caches of each admitted row AT THE ROW'S
+    OWN LENGTH into its slot (``kvcache.write_prompt``: padding rows drop,
+    pages are encoded as the decode path would have), and sample each row's
+    FIRST generated token on-device."""
 
     def prefill_fn(params, cache, last, ids, mask, slots, lengths, temps,
                    seeds):
         params = dequantize_params(params, model.cfg.dtype)
-        logits, *fresh = model.apply(
-            {"params": params}, ids, mask, method="prefill"
+        last_logits, fresh = model.apply(
+            {"params": params}, ids, mask, lengths, method="prefill_rows"
         )
-        rows = jnp.arange(ids.shape[0])
-        last_logits = logits[rows, jnp.maximum(lengths, 1) - 1]
         tok = sample_tokens(last_logits, temps, seeds, lengths)
-        cache = kvcache.write_prompt(cache, slots, *fresh)
+        cache = kvcache.write_prompt(cache, slots, fresh)
         last = last.at[slots].set(tok, mode="drop")
         return cache, last, tok
 
@@ -967,9 +969,12 @@ class CausalLMEngine(_AotEngine):
     with a paged, slot-addressed KV cache.
 
     The cache is a FIXED pool of per-slot pages — one pytree whose every
-    leaf is ``[num_layers, slots, cache_len, *trailing]`` (which leaves, and
-    what trails them, is models/kvcache.py's ``cache_layout``: dense K and
-    V rows of ``heads * head_dim``, or int8 payloads with their scales) plus a
+    leaf is ``[layers, slots, ...]`` (which leaves in which groups, and
+    what follows the slots, is the model's
+    ``cache_layout``: for ``CausalLM`` one group of dense K and V rows of
+    ``heads * head_dim`` at ``cache_len`` positions, or int8 payloads with
+    their scales; for a hybrid model positionless state, window rings and a
+    K/V table side by side, docs/DEPLOY.md "Hybrid-state models") plus a
     ``last_token [slots]`` vector — living on device for the engine's
     lifetime and threaded functionally through every executable with
     buffer donation, so each step updates the pool in place and slot
@@ -1004,7 +1009,7 @@ class CausalLMEngine(_AotEngine):
 
     Tensor parallelism (a mesh with a ``model`` axis) shards the cache
     leaves as the layout says (the head axis of the pages) and the params
-    per ``causal_param_specs``; batch
+    per the model's ``param_specs``; batch
     inputs replicate (every model shard sees every slot — slot state must
     stay coherent, and decode batches are tiny). Expert/pipeline axes are
     rejected at startup. DP axes likewise replicate: a decode engine is
@@ -1057,6 +1062,20 @@ class CausalLMEngine(_AotEngine):
         ep = self.mesh.shape.get("expert", 1)
         pp = self.mesh.shape.get("pipeline", 1)
         self._model_sharded = tp > 1
+        # Modes that move a cached position about as a page of its own
+        # refuse, at construction and by the group's name, a model that
+        # also caches what is not one (kvcache.require_pages).
+        asked = {
+            "model sharding": tp > 1,
+            "the prefix cache": prefix_cache_mb > 0,
+            "chunked prefill": prefill_chunk > 0,
+            "speculative verify": spec_tokens > 0,
+            "KV-page transfer": bool(kv_transfer),
+            "stream migration": bool(stream_migrate),
+            "int8 K/V": normalize_quant_dtype(kv_dtype, "kv_dtype") == "int8",
+        }
+        for mode in (mode for mode, on in asked.items() if on):
+            kvcache.require_pages(model.cache_layout("float32"), mode)
         serve_cfg = self._serve_config(model.cfg, tp=tp, ep=ep, pp=pp)
         self.model = (
             type(model)(serve_cfg) if serve_cfg is not model.cfg else model
@@ -1103,20 +1122,18 @@ class CausalLMEngine(_AotEngine):
             if self.spec_tokens > 0 else None
         )
 
-        from distributed_tensorflow_tpu.models.causal_lm import (
-            causal_param_specs,
-        )
-
-        # What a sequence caches: the model side declares the leaves, the
-        # engine handles [layers, slots, positions] of whatever they are.
-        self._layout = kvcache.cache_layout(cfg, self.kv_dtype)
-        # rows a decode step writes for each live lane: one a layer and leaf
-        self._kv_rows_per_lane = cfg.num_layers * len(
-            jax.tree.leaves(self._layout)
-        )
-        table = (cfg.num_layers, slots, self.cache_len)
+        # What a sequence caches: the model declares the groups and their
+        # leaves, the engine handles the slot axis of whatever they are and
+        # asks the layout for sizes.
+        self._layout = self.model.cache_layout(self.kv_dtype)
+        # what a decode step writes for each live lane, by group: the
+        # counters the dispatch span carries
+        self._writes_per_lane = kvcache.step_writes(self._layout, 1)
+        table = (slots, self.cache_len)
         if self._model_sharded:
-            self._param_specs = causal_param_specs(params, model_axis="model")
+            self._param_specs = self.model.param_specs(
+                params, model_axis="model"
+            )
             self._param_sharding = jax.tree.map(
                 lambda s: NamedSharding(self.mesh, s),
                 self._param_specs,
@@ -1135,17 +1152,21 @@ class CausalLMEngine(_AotEngine):
             "lm_params", self.params, dtype=self.weight_dtype,
             fp32_nbytes=fp32_equiv_nbytes(self.params),
         )
-        fp32_per_token = kvcache.bytes_per_token(
-            kvcache.cache_layout(cfg, "float32"), cfg.num_layers
-        )
-        kv_bytes = tree_nbytes(self._cache)
-        self.memory.register(
-            "kv_slot_cache", kv_bytes, dtype=self.kv_dtype,
-            fp32_nbytes=slots * self.cache_len * fp32_per_token,
-        )
-        # Per-slot share of the slot-table KV cache: the batcher multiplies
-        # this by slots_active so /statusz and /memz agree on active bytes.
-        self.slot_page_bytes = kv_bytes // slots
+        fp32_layout = self.model.cache_layout("float32")
+        fp32 = kvcache.components(fp32_layout, table)
+        #: bytes and storage dtype of each cache group, by the name /memz
+        #: and /statusz show it under (``kv_slot_cache``, or ``cache.state``
+        #: / ``cache.window`` / ``cache.full`` side by side)
+        self.cache_groups = kvcache.components(self._layout, table)
+        for component, (nbytes, dtype) in self.cache_groups.items():
+            self.memory.register(
+                component, nbytes, dtype=dtype,
+                fp32_nbytes=fp32[component][0],
+            )
+        # Per-slot share of the slot table, every group: the batcher
+        # multiplies this by slots_active so /statusz and /memz agree on
+        # active bytes.
+        self.slot_page_bytes = tree_nbytes(self._cache) // slots
 
         # Prefix-cache / chunked-prefill plumbing. Legacy mode (both knobs
         # 0) compiles the original monolithic prefill grid; chunked mode
@@ -1176,7 +1197,7 @@ class CausalLMEngine(_AotEngine):
                 )
             else:
                 n_blocks = 1  # dummy pool keeps one chunk operand layout
-            pool = (cfg.num_layers, n_blocks, self.block_tokens)
+            pool = (n_blocks, self.block_tokens)
             self._pool_blocks = n_blocks
             # Orders every dispatch that DONATES the pool (insert/import,
             # decode-loop thread) against the one that reads it from
@@ -1191,7 +1212,8 @@ class CausalLMEngine(_AotEngine):
             self.memory.register(
                 "kv_prefix_pool", tree_nbytes(self._pool),
                 dtype=self.kv_dtype,
-                fp32_nbytes=n_blocks * self.block_tokens * fp32_per_token,
+                fp32_nbytes=n_blocks * self.block_tokens
+                * kvcache.bytes_per_token(fp32_layout),
             )
         else:
             self.prefill_chunk_size = 0
@@ -1296,8 +1318,7 @@ class CausalLMEngine(_AotEngine):
                     ),
                     (0,), pool_s,
                     kvcache.structs(
-                        self._layout,
-                        (cfg.num_layers, M, self.block_tokens),
+                        self._layout, (M, self.block_tokens),
                         self._cache_sharding,
                     ),
                     i32(M),
@@ -1341,9 +1362,9 @@ class CausalLMEngine(_AotEngine):
             )
         if self.stream_migrate:
             # One slot's lane drops the slot axis: [nl, cache_len, ..].
-            lane = kvcache.specs(self._layout, lead=2)
+            lane = kvcache.specs(self._layout, lane=True)
             self._lane_sharding = kvcache.shardings(
-                self._layout, self.mesh, lead=2
+                self._layout, self.mesh, lane=True
             )
             # Slot export reads the live cache between decode steps — the
             # cache is NOT donated (the stream may stay resident if the
@@ -1361,11 +1382,11 @@ class CausalLMEngine(_AotEngine):
                 ),
                 (0, 1), table_s, i32(slots),
                 kvcache.structs(
-                    self._layout, (cfg.num_layers, self.cache_len),
-                    self._lane_sharding,
+                    self._layout, (self.cache_len,), self._lane_sharding,
                 ),
                 i32(), i32(),
             )
+        self._load_grid()
         logger.info(
             "causal-LM engine ready: layout=%s slots=%d cache_len=%d "
             "buckets=%s tiers=%s chunk=%s pool_blocks=%s spec_k=%s "
@@ -1380,6 +1401,37 @@ class CausalLMEngine(_AotEngine):
             + (1 if self.prefix_cache is not None else 0)
             + (2 if self._kv_transfer else 0) + n_spec_cells + n_mig_cells,
         )
+
+    def _load_grid(self) -> None:
+        """Execute every prefill (or chunk) cell and the step executables
+        once, over padding rows and idle lanes, before anything is served.
+
+        A compiled program's FIRST execution loads it onto the device, and
+        with a large model that is not free: the first tier-2 admission of
+        a long prompt, arriving with a hundred streams live, held all of
+        them and the arrivals behind it for 0.3 s (PERF.md section 6,
+        PR 35). Paid here it is half a second of start-up for the whole
+        grid. A padding row's slot index is out of the pool and an idle
+        lane's position is past the cache, so no slot is written; the
+        staging buffers this allocates are the ones serving reuses. The
+        page-moving cells (insert, export, import, slot export / import)
+        still load at their first use."""
+        zeros = np.zeros((self.slots,), np.int32)
+        for T, L in self._prefill_compiled:
+            pad = {"slot": self.slots, "input_ids": np.zeros((L,), np.int32)}
+            self.fetch_step(self.prefill([pad] * T))
+        for T, C in self._chunk_compiled:
+            pad = {"slot": self.slots, "input_ids": np.zeros((C,), np.int32),
+                   "start": 0, "n_tokens": C, "length": C}
+            self.fetch_step(self.prefill_chunks([pad] * T))
+        self.fetch_step(self.decode(
+            zeros, zeros.astype(bool), zeros.astype(np.float32), zeros
+        ))
+        if self._verify_compiled is not None:
+            self.fetch_step(self.verify(
+                np.zeros((self.slots, self.spec_tokens), np.int32), zeros,
+                zeros, zeros.astype(np.float32), zeros,
+            ))
 
     @staticmethod
     def _serve_config(cfg, tp: int = 1, ep: int = 1, pp: int = 1):
@@ -1432,7 +1484,7 @@ class CausalLMEngine(_AotEngine):
         kv = normalize_quant_dtype(kv_dtype, "kv_dtype") \
             or str(np.dtype(cfg.dtype).name)
         bytes_per_block = block_tokens * kvcache.bytes_per_token(
-            kvcache.cache_layout(cfg, kv), cfg.num_layers
+            kvcache.cache_layout(cfg, kv)
         )
         n_blocks = int(prefix_cache_mb * 2**20 // bytes_per_block)
         if prefix_cache_mb > 0 and n_blocks < 1:
@@ -1500,12 +1552,12 @@ class CausalLMEngine(_AotEngine):
         return (w or default, k or default)
 
     def kv_bytes_per_token(self) -> int:
-        """Slot-cache bytes ONE cached token occupies (K + V across all
-        layers, plus scales at int8) — the `serve_kv_bytes_per_token{dtype=}`
-        gauge and DEPLOY.md's sizing math both read this."""
-        return kvcache.bytes_per_token(
-            self._layout, self.model.cfg.num_layers
-        )
+        """Slot-cache bytes ONE more cached token occupies (K + V across
+        the layers that keep positions, plus scales at int8; a ring and
+        positionless state cost the same at any length) — the
+        `serve_kv_bytes_per_token{dtype=}` gauge and DEPLOY.md's sizing math
+        both read this."""
+        return kvcache.bytes_per_token(self._layout)
 
     def _rep_struct(self, shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=self._rep)
@@ -1889,7 +1941,6 @@ class CausalLMEngine(_AotEngine):
         if self.prefix_cache is None:
             raise RuntimeError("engine has no prefix cache")
         return {
-            "num_layers": int(self.model.cfg.num_layers),
             "block_tokens": int(self.block_tokens),
             **kvcache.page_geometry(self.model.cfg, self._layout),
             "max_chain": int(self._max_chain),
@@ -1945,7 +1996,6 @@ class CausalLMEngine(_AotEngine):
         iff these match (``cache_len`` may differ: the receiver re-pads,
         refusing only streams longer than its own lanes)."""
         return {
-            "num_layers": int(self.model.cfg.num_layers),
             "cache_len": int(self.cache_len),
             **kvcache.page_geometry(self.model.cfg, self._layout),
         }
@@ -1980,7 +2030,9 @@ class CausalLMEngine(_AotEngine):
         return InFlightBatch(
             out={"tok": tok}, key=key, n=n, meta=None,
             buffers=buffers, layout=self.layout, t_assembled=t_assembled,
-            kv_rows=n * self._kv_rows_per_lane,
+            written={
+                name: n * one for name, one in self._writes_per_lane.items()
+            },
         )
 
     def verify(self, drafts, lengths, n_input, temps, seeds) -> InFlightBatch:

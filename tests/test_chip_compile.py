@@ -303,6 +303,72 @@ def test_reading_a_merged_row_per_head_copies_the_layer(one_chip, spelling):
         assert not _made_by(compiled, layer)
 
 
+# ------------------------- a hybrid model's three cache groups, in place
+
+def test_hybrid_decode_step_updates_all_three_groups_in_place(one_chip):
+    """benchmarks/workloads/phi4_mini_flash.reason_steady: SambaY at
+    Phi-4-mini-flash-reasoning's sizes (3.85 B parameters in bf16), 128 slots,
+    cache 1,536. The donated cache — Mamba state, window rings, the one full
+    K/V table, 4.10 GB — is aliased to the step's output, every leaf, and the
+    program's scratch stays under half a gigabyte: the scan state is read
+    and rewritten as one chain (reading the step's input while writing its
+    output copied the 0.38 GB table: 0.498 GB of scratch, PERF.md PR 35),
+    the rings' rows are the flat scatter of PR 33, and no reader of the full
+    table makes a copy of it. A compile, not a time (about 20 s)."""
+    from distributed_tensorflow_tpu.models import kvcache
+    from distributed_tensorflow_tpu.models.sambay import (
+        SambaY,
+        SambaYConfig,
+        sambay_init_params,
+    )
+    from distributed_tensorflow_tpu.serve.engine import _make_causal_decode
+
+    slots, cache_len = 128, 1536
+    model = SambaY(SambaYConfig(dtype=jnp.bfloat16))
+
+    def struct(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params = jax.tree.map(
+        lambda x: struct(x.shape, jnp.bfloat16),
+        jax.eval_shape(
+            lambda: sambay_init_params(model, jax.random.PRNGKey(0))
+        ),
+    )
+    layout = model.cache_layout("bfloat16")
+    table = kvcache.structs(
+        layout, (slots, cache_len), jax.tree.map(lambda _: one_chip, layout)
+    )
+    compiled = (
+        jax.jit(_make_causal_decode(model, cache_len), donate_argnums=(1, 2))
+        .lower(
+            params, table,
+            struct((slots,), jnp.int32), struct((slots,), jnp.int32),
+            struct((slots,), jnp.bool_), struct((slots,), jnp.float32),
+            struct((slots,), jnp.int32),
+        )
+        .compile()
+    )
+    by_group = {
+        name: nbytes
+        for name, (nbytes, _) in kvcache.components(
+            layout, (slots, cache_len)
+        ).items()
+    }
+    assert by_group == {
+        "cache.state": 412_876_800, "cache.window": 2_684_354_560,
+        "cache.full": 1_006_632_960,
+    }
+    ma = compiled.memory_analysis()
+    assert ma.alias_size_in_bytes >= sum(by_group.values())
+    assert ma.temp_size_in_bytes < 0.5e9, ma.temp_size_in_bytes
+    # the five leaves, each an output that aliases its own parameter
+    text = compiled.as_text()
+    aliased = re.search(r"input_output_alias=\{([^\n]*)\}, entry", text)
+    assert aliased and aliased.group(1).count("may-alias") \
+        + aliased.group(1).count("must-alias") >= 6, aliased
+
+
 # -------------------------------------- the MLM head over the masked rows
 
 def test_mlm_head_gathers_its_rows_at_the_cell_shape(one_chip):

@@ -1,7 +1,9 @@
 """The seam of models/kvcache.py: the engine's half works for ANY layout.
 
 serve/engine.py handles a cache as a pytree whose every leaf is ``[layers,
-slots, positions, *trailing]`` and never looks past the third axis. So its
+slots, *after, *trailing]``, addresses the slot axis and asks the layout for
+sizes (a group of leaves says how many layers it has and what follows its
+slots: positions here, a ring or nothing in tests/test_sambay.py). So its
 generic operations — struct / zeros / take and put slots / take and put
 blocks / publish / the one ``_wrap`` — must work unchanged over a layout the
 repo does not ship: ``latent`` below is one leaf with trailing shape ``(r,)``
@@ -58,7 +60,7 @@ _LAYOUTS = {
         2 * 12 * (768 + 4),
     ),
     "latent": lambda: (
-        {"c": kvcache.Leaf((40,), jnp.dtype(jnp.bfloat16), (None,))},
+        {"c": kvcache.Leaf((40,), jnp.dtype(jnp.bfloat16), (None,), _NL)},
         [((40,), "bfloat16", P(None, None, None, None))],
         12 * 40 * 2,
     ),
@@ -87,13 +89,13 @@ def _same(a, b):
 def test_engine_side_operations_work_over_any_layout(name):
     layout, want, per_token = _LAYOUTS[name]()
     mesh = build_mesh({"model": 2}, devices=jax.devices()[:2])
-    table, pool = (_NL, _SLOTS, _CACHE_LEN), (_NL, _BLOCKS, _BT)
+    table, pool = (_SLOTS, _CACHE_LEN), (_BLOCKS, _BT)
 
     # -- one tree.map over the description each
-    assert kvcache.bytes_per_token(layout, _NL) == per_token
+    assert kvcache.bytes_per_token(layout) == per_token
     sharding = kvcache.shardings(layout, mesh)
     spec = kvcache.specs(layout)
-    lane_sharding = kvcache.shardings(layout, mesh, lead=2)
+    lane_sharding = kvcache.shardings(layout, mesh, lane=True)
     cache = kvcache.zeros(layout, table, sharding)
     structs = kvcache.structs(layout, table, sharding)
     for leaf, st, sp, lane, (trailing, dtype, pspec) in zip(
@@ -101,7 +103,7 @@ def test_engine_side_operations_work_over_any_layout(name):
         jax.tree.leaves(spec, is_leaf=lambda x: isinstance(x, P)),
         jax.tree.leaves(lane_sharding), want, strict=True,
     ):
-        assert leaf.shape == st.shape == table + trailing
+        assert leaf.shape == st.shape == (_NL, *table, *trailing)
         assert leaf.dtype == st.dtype == jnp.dtype(dtype)
         assert sp == pspec and lane.spec == P(*tuple(pspec)[1:])
         assert leaf.sharding == st.sharding == NamedSharding(mesh, pspec)
@@ -109,7 +111,7 @@ def test_engine_side_operations_work_over_any_layout(name):
 
     # -- the executable bodies, plain and under the one _wrap
     sharded = types.SimpleNamespace(mesh=mesh, _model_sharded=True)
-    rep, lane = P(), kvcache.specs(layout, lead=2)
+    rep, lane = P(), kvcache.specs(layout, lane=True)
 
     def both(fn, in_specs, out_specs):
         wrapped = CausalLMEngine._wrap(sharded, fn, in_specs, out_specs)
@@ -205,11 +207,11 @@ def test_host_boundary_speaks_pages_k_pages_v(kv):
     )
     layout = kvcache.cache_layout(cfg, kv)
     assert kvcache.page_geometry(cfg, layout) == {
-        "heads": heads, "head_dim": head_dim, "dtype": kv,
+        "num_layers": nl, "heads": heads, "head_dim": head_dim, "dtype": kv,
     }
     mesh = build_mesh({"data": 1}, devices=jax.devices()[:1])
     sharding = kvcache.shardings(layout, mesh)
-    stage = kvcache.zeros(layout, (nl, slots, 4), sharding)
+    stage = kvcache.zeros(layout, (slots, 4), sharding)
     pages_k, pages_v = kvcache.split_kv(stage, heads)
     if kv == "int8":
         assert sorted(pages_k) == sorted(pages_v) == ["q", "s"]
@@ -227,8 +229,8 @@ def test_host_boundary_speaks_pages_k_pages_v(kv):
     )
     rows = jnp.asarray(fresh).reshape(2, nl, 1, prompt, heads * head_dim)
     cache = kvcache.write_prompt(
-        kvcache.zeros(layout, (nl, slots, cache_len), sharding),
-        jnp.asarray([1], jnp.int32), *rows,
+        kvcache.zeros(layout, (slots, cache_len), sharding),
+        jnp.asarray([1], jnp.int32), dict(zip(("k", "v"), rows)),
     )
     lane = _make_export()(cache, jnp.asarray(1, jnp.int32))
     for got, x in zip(kvcache.split_kv(lane, heads), fresh, strict=True):
