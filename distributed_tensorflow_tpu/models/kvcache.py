@@ -26,8 +26,8 @@ decided here and by the model's ``cache_layout``:
   that has a positionless or ring group.
 - The model's reads and writes — ``take_layer``, ``encode``, ``select_rows``,
   ``write_rows``, ``scatter_rows``, ``stack_layers``, ``write_prompt``,
-  ``cached_attention``, ``chunk_attention``, ``paired_attention`` — are
-  written once for every form.
+  ``cached_attention``, ``chunk_attention``, ``paired_attention``,
+  ``prefix_attention`` — are written once for every form.
 - ``split_kv`` / ``join_kv`` / ``page_geometry`` convert at the engine's host
   boundary, where serve/disagg.py and the wire format still speak of
   ``pages_k, pages_v`` and of ``[.., heads, head_dim]``: a row-major reshape
@@ -57,6 +57,15 @@ donated; ``memory_analysis().temp_size_in_bytes``; times are in PERF.md, PR
 - the read with the lane axis split (``table[i].reshape(S, L, h, d)`` and the
   per-head ``shd,slhd->shl``): the layer is sliced, copied to ``{2,3,1,0}``
   and converted to float32 — 0.227 GB a layer. Do not retry it;
+- the read by a prefix length of a table that is written before it is read
+  (:func:`prefix_attention`, PR 36; a hybrid's one full table and its eight
+  readers): ops/decode_attention.py, a Mosaic custom call whose operand is
+  the layer where it lies (``take_layer`` of a one-layer leaf is a bitcast).
+  The kernel copies a slot's live blocks HBM -> VMEM itself and splits the
+  lanes there, where a lane-tile slice costs nothing — 0 B, no fusion, copy
+  or convert of the table, the hybrid step's scratch 129.6 MB with all five
+  leaves aliased. Both reads above pass over every position of every slot
+  whatever it holds; this one moves ``ceil(length / block)`` blocks a slot;
 - a leaf without trailing axes (the int8 scale, ``[nl, S, L]``, 2.4 MB) has
   the position minor-most again; it keeps the select, a pass over 0.3% of what
   the payload's was.
@@ -82,6 +91,7 @@ import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from distributed_tensorflow_tpu.models.quant import quantize_kv
+from distributed_tensorflow_tpu.ops import decode_attention
 
 MASK_VALUE = -1e30
 POSITIONS = "positions"  # Leaf.after: the engine's cache_len follows the slots
@@ -102,6 +112,12 @@ class Leaf:
     layers: int
     after: int | str | None = POSITIONS
     group: str = "kv"
+    # Layers whose decode step reads this leaf through :func:`prefix_attention`
+    # (0: every reader passes over all of it), and the positions such a read
+    # moves at a time where the kernel applies to the model's heads (0: it
+    # does not). What :func:`step_reads` counts from.
+    prefix_readers: int = 0
+    prefix_block: int = 0
 
     def lead(self, axes: tuple[int, ...]) -> tuple[int, ...]:
         """The leading shape for ``axes = (*front, positions)``: ``(slots,
@@ -242,6 +258,44 @@ def step_writes(layout, live: int) -> dict[str, int]:
             out[f"{group}_rows_written"] = live * sum(
                 leaf.layers for leaf in leaves
             )
+    return out
+
+
+def _kernel_block(block: int, cache_len: int) -> int:
+    """``block`` where a prefix read of a table of ``cache_len`` positions
+    goes through the kernel — it applies to the heads (``block`` > 0) and
+    the table is whole blocks — else 0: the mask form."""
+    return block if block and cache_len % block == 0 else 0
+
+
+def prefix_reads(layout, cache_len: int) -> dict[str, tuple[int, int, int]]:
+    """``{group: (positions a read moves at a time, reads a slot: layers x
+    leaves, blocks a slot)}`` of the groups whose decode-step readers take a
+    prefix length: what :func:`step_reads` needs, fixed at the engine's
+    construction."""
+    out = {}
+    for group, leaves in _by_group(layout).items():
+        if leaves[0].prefix_readers:
+            # the mask form passes over the whole slot
+            block = _kernel_block(leaves[0].prefix_block, cache_len) or cache_len
+            out[group] = (
+                block, leaves[0].prefix_readers * len(leaves),
+                cache_len // block,
+            )
+    return out
+
+
+def step_reads(reads, lengths) -> dict[str, int]:
+    """What ONE decode step reads of the groups in ``reads``
+    (:func:`prefix_reads`), beside :func:`step_writes` on the
+    ``engine.decode_dispatch`` span: ``<group>_blocks_read`` for ``lengths
+    [S]`` (a lane's position + 1, and 0 for an idle lane) and
+    ``<group>_blocks_total``, what a step that stopped nowhere would move.
+    Their ratio is the share of the table the step touched."""
+    out = {}
+    for group, (block, sides, a_slot) in reads.items():
+        out[f"{group}_blocks_read"] = sides * int(np.sum(-(-lengths // block)))
+        out[f"{group}_blocks_total"] = sides * len(lengths) * a_slot
     return out
 
 
@@ -522,3 +576,21 @@ def paired_attention(q, cache, valid, lam):
     mine = jnp.eye(n_g, dtype=bool)[:, None, :, None]
     out = jnp.sum(jnp.where(mine, ctx, 0), axis=3)  # [S, G, per, 2d]
     return out.reshape(out.shape[0], n_q // 2, 2 * d)
+
+
+def prefix_attention(q, cache, lengths, lam):
+    """:func:`paired_attention` where row ``s`` sees the table's first
+    ``lengths[s]`` positions (0: nothing, and zeros come back). Where the
+    table admits it — rows that are whole lane tiles for these heads, a
+    ``cache_len`` of whole blocks — ops/decode_attention.py reads each slot's
+    live blocks where they lie and nothing else; any other table takes the
+    mask form, a pass over every position. The caller has a length, not a
+    mask: the table is written before it is read, so no row is selected in."""
+    k = cache["k"]
+    block = decode_attention.block_for(*q.shape[-2:], k.shape[-1])
+    if _kernel_block(block, k.shape[1]):
+        return decode_attention.table_attention(
+            q, k, cache["v"], lengths, lam
+        )
+    valid = jnp.arange(k.shape[1]) < lengths[:, None]
+    return paired_attention(q, cache, valid, lam)

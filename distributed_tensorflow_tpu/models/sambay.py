@@ -52,8 +52,10 @@ Forwards (one param tree):
   at ``position % window`` and into the full table at ``position``, every
   window layer attending its ring as the step found it with the new row
   selected in (models/kvcache.py, "How decode_step writes and reads"), every
-  reader of the full table attending it after its one writer. An idle lane
-  (``position == cache_len``) writes nothing in any group.
+  reader of the full table attending it after its one writer, as far as the
+  slot's length (``kvcache.prefix_attention``: the slot's live blocks only,
+  where the table admits the kernel). An idle lane (``position ==
+  cache_len``) writes nothing in any group and reads nothing of the table.
 - ``prefill_chunk`` / ``verify_step`` refuse: a recurrence has no page to
   resume from (``kvcache.require_pages`` stops the engine first).
 """
@@ -70,6 +72,7 @@ from flax import linen as nn
 
 from distributed_tensorflow_tpu.models import kvcache
 from distributed_tensorflow_tpu.models.kvcache import Leaf
+from distributed_tensorflow_tpu.ops import decode_attention
 
 
 @dataclasses.dataclass(frozen=True)
@@ -364,9 +367,17 @@ class DiffAttention(nn.Module):
         return self.finish(o.reshape(b, l, n_q // 2, 2 * d))
 
     def cached(self, q, table, valid):
-        """One query a row over a layer's table or ring ``[S, L, 2G * d]``."""
+        """One query a row over the rows ``valid [S, L]`` of a layer's ring
+        or of a prompt's K/V ``[S, L, 2G * d]``."""
         return self.finish(
             kvcache.paired_attention(q, table, valid, self._lam())
+        )
+
+    def prefix(self, q, table, lengths):
+        """One query a row over the first ``lengths [S]`` positions of a
+        table ``[S, L, 2G * d]`` that already holds them."""
+        return self.finish(
+            kvcache.prefix_attention(q, table, lengths, self._lam())
         )
 
 
@@ -506,16 +517,17 @@ class SambaY(nn.Module):
         cfg = self.cfg
         kinds = layer_kinds(cfg)
 
-        def group(name, layers, after, leaves):
+        def group(name, layers, after, leaves, **reads):
             return {
                 key: Leaf(
                     shape, jnp.dtype(dtype), (None,) * len(shape),
-                    layers=layers, after=after, group=name,
+                    layers=layers, after=after, group=name, **reads,
                 )
                 for key, (shape, dtype) in leaves.items()
             }
 
-        row = ((cfg.num_kv_heads * cfg.head_dim,), kv_dtype)
+        lanes = cfg.num_kv_heads * cfg.head_dim
+        row = ((lanes,), kv_dtype)
         return {
             "state": group("state", kinds.count("mamba"), None, {
                 "ssm": ((cfg.d_state, cfg.d_inner), cfg.state_dtype),
@@ -525,7 +537,13 @@ class SambaY(nn.Module):
                 "window", kinds.count("window"), cfg.sliding_window,
                 {"k": row, "v": row},
             ),
-            "full": group("full", 1, kvcache.POSITIONS, {"k": row, "v": row}),
+            "full": group(
+                "full", 1, kvcache.POSITIONS, {"k": row, "v": row},
+                prefix_readers=kinds.count("full") + kinds.count("cross"),
+                prefix_block=decode_attention.block_for(
+                    cfg.num_heads, cfg.head_dim, lanes
+                ),
+            ),
         }
 
     def decode_step(self, token, position, cache):
@@ -536,7 +554,7 @@ class SambaY(nn.Module):
         ring_at = jnp.where(idle, ring, position % ring)  # ring: no row
         full_at = jnp.where(idle, cache_len, position)
         ring_seen = jnp.arange(ring) < jnp.minimum(position + 1, ring)[:, None]
-        full_seen = jnp.arange(cache_len) <= position[:, None]
+        full_len = jnp.where(idle, 0, position + 1)  # an idle lane reads nothing
 
         x = self._embed(token)
         memory = table = None
@@ -570,14 +588,15 @@ class SambaY(nn.Module):
                 else:
                     if kind == "full":
                         # one writer, many readers: the row goes in first
-                        # and every reader attends the table it is in
+                        # and every reader attends the table it is in, as
+                        # far as the slot's length
                         rows = jax.tree.map(
                             lambda a: a[None], kvcache.encode(full, kv)
                         )
                         full = kvcache.write_rows(full, rows, full_at)
                         table = kvcache.take_layer(full, 0)
                     with jax.named_scope("full_attention"):
-                        mixed = layer.mixer.cached(q, table, full_seen)
+                        mixed = layer.mixer.prefix(q, table, full_len)
             x = layer.finish(x, mixed)
         window = kvcache.write_rows(
             window, kvcache.stack_layers(ring_rows), ring_at

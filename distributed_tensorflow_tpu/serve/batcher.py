@@ -2142,7 +2142,7 @@ class ContinuousBatcher:
                                      rows=len(tags),
                                      slots=len(active)) as span:
                         handle = engine.decode(lengths, active, temps, seeds)
-                        span.set(**getattr(handle, "written", {}))
+                        span.set(**getattr(handle, "moved", {}))
                 except Exception as e:  # noqa: BLE001
                     self._inflight_sem.release()
                     self._fail_slots(tags, e)

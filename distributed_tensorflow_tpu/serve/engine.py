@@ -166,8 +166,10 @@ class InFlightBatch:
     t_got: float = 0.0
     # What a decode step writes into the cache, by group
     # (kvcache.step_writes): a row per layer and leaf for each active lane,
-    # the bytes of positionless state (an idle lane writes nothing).
-    written: dict = dataclasses.field(default_factory=dict)
+    # the bytes of positionless state (an idle lane writes nothing) — and
+    # what it reads of a group whose readers stop at the lane's length
+    # (kvcache.step_reads): blocks read, and blocks there are.
+    moved: dict = dataclasses.field(default_factory=dict)
 
 
 @dataclasses.dataclass
@@ -1129,6 +1131,7 @@ class CausalLMEngine(_AotEngine):
         # what a decode step writes for each live lane, by group: the
         # counters the dispatch span carries
         self._writes_per_lane = kvcache.step_writes(self._layout, 1)
+        self._prefix_reads = kvcache.prefix_reads(self._layout, self.cache_len)
         table = (slots, self.cache_len)
         if self._model_sharded:
             self._param_specs = self.model.param_specs(
@@ -2027,12 +2030,15 @@ class CausalLMEngine(_AotEngine):
             jax.device_put(btmp, self._rep), jax.device_put(bseed, self._rep),
         )
         n = int(np.sum(bact))
+        moved = {name: n * one for name, one in self._writes_per_lane.items()}
+        if self._prefix_reads:
+            # as the step sees them: position + 1, and 0 for an idle lane
+            seen = np.where(bact, np.minimum(blen, self.cache_len - 1) + 1, 0)
+            moved.update(kvcache.step_reads(self._prefix_reads, seen))
         return InFlightBatch(
             out={"tok": tok}, key=key, n=n, meta=None,
             buffers=buffers, layout=self.layout, t_assembled=t_assembled,
-            written={
-                name: n * one for name, one in self._writes_per_lane.items()
-            },
+            moved=moved,
         )
 
     def verify(self, drafts, lengths, n_input, temps, seeds) -> InFlightBatch:

@@ -18,6 +18,9 @@ prints, per scope, the summed duration of the first chip's ops that carry it
 and the same per execution of the module ``--per`` names; with ``--stats`` also
 which metadata stats the ops carry and one example, and with ``--host`` the
 totals of the host lines' events whose names match (the loops' spans). With
+``--host`` a span's integer keys are summed too (``engine.decode_dispatch``'s
+``rows``, ``full_rows_written``, ``full_blocks_read``, ``full_blocks_total``:
+what the capture's steps moved, and the share of the table they touched). With
 ``--per`` and ``--host`` together it also says what the host did in the idle
 gaps between consecutive executions of that module: for each matching host
 event, the part of it that lies inside a gap, summed and per gap. Host and
@@ -106,10 +109,23 @@ def _host_report(plane, rx, gaps, per) -> None:
             t[1] += dur
             t[2] = line
             t[3] += sum(max(0, min(start + dur, g1) - max(start, g0)) for g0, g1 in gaps)
+    stat_names = {k: v.name for k, v in plane.stat_metadata.items()}
+    counters = defaultdict(lambda: defaultdict(int))  # a span's integer keys
+    for line in plane.lines:
+        for e in line.events:
+            name = plane.event_metadata[e.metadata_id].name
+            if rx.search(name):
+                for st in e.stats:
+                    if st.WhichOneof("value") in ("int64_value", "uint64_value"):
+                        counters[name][stat_names.get(st.metadata_id, "?")] += (
+                            st.int64_value or st.uint64_value)
     for name, (n, dur, line, in_gaps) in sorted(totals.items(), key=lambda kv: -kv[1][1]):
         gap_part = f"  in the gaps {in_gaps * 1e-9 / len(gaps):7.3f} ms a gap" if gaps else ""
         print(f"  host {name:26s} x{n:<6d} {dur * 1e-12:9.4f} s  mean {dur * 1e-9 / n:8.3f} ms"
               f"{gap_part}  (line {line!r})")
+        if counters[name]:
+            print("       summed keys: " + ", ".join(
+                f"{k}={v}" for k, v in sorted(counters[name].items())))
     if gaps and totals:
         print(f"  {len(gaps)} gaps between executions of {per.pattern}, "
               f"mean {sum(b - a for a, b in gaps) * 1e-9 / len(gaps):.3f} ms")

@@ -305,7 +305,9 @@ def test_reading_a_merged_row_per_head_copies_the_layer(one_chip, spelling):
 
 # ------------------------- a hybrid model's three cache groups, in place
 
-def test_hybrid_decode_step_updates_all_three_groups_in_place(one_chip):
+def test_hybrid_decode_step_updates_all_three_groups_in_place(
+    one_chip, monkeypatch
+):
     """benchmarks/workloads/phi4_mini_flash.reason_steady: SambaY at
     Phi-4-mini-flash-reasoning's sizes (3.85 B parameters in bf16), 128 slots,
     cache 1,536. The donated cache — Mamba state, window rings, the one full
@@ -314,7 +316,10 @@ def test_hybrid_decode_step_updates_all_three_groups_in_place(one_chip):
     and rewritten as one chain (reading the step's input while writing its
     output copied the 0.38 GB table: 0.498 GB of scratch, PERF.md PR 35),
     the rings' rows are the flat scatter of PR 33, and no reader of the full
-    table makes a copy of it. A compile, not a time (about 20 s)."""
+    table makes a copy of it: its eight readers are the length-aware kernel
+    of ops/decode_attention.py (PR 36), each a custom call that takes the
+    table where it lies, so nothing of the table's size is made by a fusion,
+    copy or convert. A compile, not a time (about 20 s)."""
     from distributed_tensorflow_tpu.models import kvcache
     from distributed_tensorflow_tpu.models.sambay import (
         SambaY,
@@ -323,6 +328,11 @@ def test_hybrid_decode_step_updates_all_three_groups_in_place(one_chip):
     )
     from distributed_tensorflow_tpu.serve.engine import _make_causal_decode
 
+    from distributed_tensorflow_tpu.ops import decode_attention
+
+    # the process is held to the CPU, where the kernel would be interpreted:
+    # compile it as the chip would
+    monkeypatch.setattr(decode_attention, "_use_interpret", lambda: False)
     slots, cache_len = 128, 1536
     model = SambaY(SambaYConfig(dtype=jnp.bfloat16))
 
@@ -367,6 +377,16 @@ def test_hybrid_decode_step_updates_all_three_groups_in_place(one_chip):
     aliased = re.search(r"input_output_alias=\{([^\n]*)\}, entry", text)
     assert aliased and aliased.group(1).count("may-alias") \
         + aliased.group(1).count("must-alias") >= 6, aliased
+    # the table's eight readers, and nothing else that holds a table
+    kernels = re.findall(
+        r"^\s*%(table_attention[.\d]*) = f32\[128,20,128\]\S* custom-call\(",
+        text, re.M,
+    )
+    assert len(kernels) == 8, kernels
+    made = _made_by(compiled, r"(?:1,)?%d,%d,1280" % (slots, cache_len))
+    assert set(made) <= {
+        "parameter", "get-tuple-element", "tuple", "bitcast",
+    }, made
 
 
 # -------------------------------------- the MLM head over the masked rows

@@ -20,6 +20,7 @@ import pytest
 
 from benchmarks.references import phi4_mini_flash as reference
 from distributed_tensorflow_tpu.models import kvcache
+from distributed_tensorflow_tpu.models.causal_lm import CausalLMConfig
 from distributed_tensorflow_tpu.models.sambay import (
     SambaY,
     SambaYConfig,
@@ -91,6 +92,17 @@ def test_published_size_is_3_85_billion_and_caches_three_groups():
         "window_rows_written": 3 * 8 * 2,
         "full_rows_written": 3 * 2,
     }
+    # eight layers read the table's K and V, in blocks of 128 positions where
+    # the table is whole blocks; any other table is passed over whole
+    assert kvcache.prefix_reads(layout, 1536) == {"full": (128, 8 * 2, 12)}
+    assert kvcache.prefix_reads(layout, 1500) == {"full": (1500, 8 * 2, 1)}
+    seen = np.asarray([0, 1, 256, 257, 1536])  # position + 1; 0: an idle lane
+    assert kvcache.step_reads({"full": (256, 16, 6)}, seen) == {
+        "full_blocks_read": (0 + 1 + 1 + 2 + 6) * 16,
+        "full_blocks_total": 5 * 6 * 16,
+    }
+    assert kvcache.prefix_reads(kvcache.cache_layout(
+        CausalLMConfig(vocab_size=64), "bfloat16"), 384) == {}
 
 
 @pytest.mark.parametrize(
@@ -202,6 +214,46 @@ def test_prefill_then_cached_steps_match_the_full_forward(tiny):
         pos = jnp.asarray([cache_len, at, cache_len], jnp.int32)
         logits, cache = step(params, tok, pos, cache)
         np.testing.assert_allclose(np.asarray(logits[1]), want[at], atol=5e-6)
+
+
+def test_steps_through_the_table_kernel_match_the_full_forward():
+    """Heads the kernel admits (8 query / 4 K/V heads of 64: rows of 256
+    lanes) and a table of three blocks, so the two readers' read is
+    ops/decode_attention.py, interpreted: a prompt of 250, then teacher-forced
+    steps over the block's edge at 256, against the reference's one forward;
+    the idle lanes beside it (length 0: nothing read) stay finite."""
+    cfg = SambaYConfig(
+        vocab_size=128, hidden_size=512, intermediate_size=128, num_layers=8,
+        num_heads=8, num_kv_heads=4, sliding_window=_WINDOW,
+    )
+    ref_cfg = {**REF_CFG, "hidden_size": 512, "num_attention_heads": 8,
+               "num_key_value_heads": 4}
+    model = SambaY(cfg)
+    params = sambay_init_params(model, jax.random.PRNGKey(3))
+    prompt, steps, slots, cache_len = 250, 9, 3, 384
+    layout = model.cache_layout("float32")
+    assert kvcache.prefix_reads(layout, cache_len) == {"full": (128, 2 * 2, 3)}
+    rng = np.random.default_rng(12)
+    ids = rng.integers(5, cfg.vocab_size, (1, prompt + steps)).astype(np.int32)
+    mask = np.arange(256)[None] < prompt
+    _, fresh = _jitted(model, "prefill_rows")(
+        params, ids[:, :256] * mask, mask, jnp.asarray([prompt], jnp.int32)
+    )
+    cache = _cache_from((model, params), fresh, slots, cache_len, slot=1)
+    want = np.asarray(reference.forward(
+        ref_cfg, params, ids, np.ones_like(ids, bool)
+    ))[0]
+    step = _jitted(model, "decode_step")
+    assert "pallas_call" in str(jax.make_jaxpr(step)(
+        params, jnp.zeros((slots,), jnp.int32),
+        jnp.zeros((slots,), jnp.int32), cache,
+    ))
+    for at in range(prompt, prompt + steps):
+        tok = jnp.asarray([0, ids[0, at], 0], jnp.int32)
+        pos = jnp.asarray([cache_len, at, cache_len], jnp.int32)
+        logits, cache = step(params, tok, pos, cache)
+        assert np.isfinite(np.asarray(logits)).all()
+        np.testing.assert_allclose(np.asarray(logits[1]), want[at], atol=2e-5)
 
 
 @pytest.mark.parametrize("group", _GROUPS)

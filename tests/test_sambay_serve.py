@@ -142,9 +142,15 @@ def test_streams_through_the_batcher_are_the_solo_streams(served):
     assert sorted(per_lane) == [
         "full_rows_written", "state_bytes_written", "window_rows_written",
     ]
+    # two layers read the one table's K and V up to each lane's length; at
+    # these toy heads the mask form passes over a slot whole, so a live lane
+    # reads its slot and an idle one nothing
+    assert engine._prefix_reads == {"full": (engine.cache_len, 2 * 2, 1)}
     for sp in spans["engine.decode_dispatch"]:
         for counter, one in per_lane.items():
             assert sp.args[counter] == sp.args["rows"] * one
+        assert sp.args["full_blocks_read"] == sp.args["rows"] * 4
+        assert sp.args["full_blocks_total"] == _SLOTS * 4
     admitted = spans["engine.prefill_dispatch"]
     assert sum(sp.args["real_tokens"] for sp in admitted) == sum(
         len(p["input_ids"]) for p in payloads

@@ -295,7 +295,11 @@ host = space.planes.add(name="/host:CPU")
 meta(host, 1, "batcher.deliver"); meta(host, 2, "engine.decode_dispatch"); meta(host, 3, "other")
 line = host.lines.add(name="python3", timestamp_ns=1000)
 line.events.add(metadata_id=1, offset_ps=38 * 10**6, duration_ps=6 * 10**6)   # 4 us in the gap
-line.events.add(metadata_id=2, offset_ps=45 * 10**6, duration_ps=4 * 10**6)   # all in the gap
+host.stat_metadata[5].name = "full_blocks_read"; host.stat_metadata[6].name = "full_blocks_total"
+for off, dur, read in ((45, 4, 7), (90, 1, 9)):  # the first all in the gap; each carries its counters
+    ev = line.events.add(metadata_id=2, offset_ps=off * 10**6, duration_ps=dur * 10**6)
+    for key, value in ((5, read), (6, 48)):
+        st = ev.stats.add(); st.metadata_id = key; st.int64_value = value
 line.events.add(metadata_id=3, offset_ps=41 * 10**6, duration_ps=1 * 10**6)
 open({str(out)!r}, "wb").write(space.SerializeToString())
 sys.argv = ["trace_scopes.py", {str(out)!r}, "--per", "^jit_decode_fn", "--host", "^(batcher|engine)[.]"]
@@ -315,6 +319,8 @@ runpy.run_path({str(script)!r}, run_name="__main__")
     assert "in the gaps   0.004 ms a gap" in lines["batcher.deliver"]
     assert "in the gaps   0.004 ms a gap" in lines["engine.decode_dispatch"]
     assert "1 gaps between executions" in text and "mean 0.010 ms" in text
+    # a span's integer keys, summed over the capture
+    assert "summed keys: full_blocks_read=16, full_blocks_total=96" in text
 
 
 # ------------------------------------------- request phases through serving
