@@ -40,3 +40,8 @@ from distributed_tensorflow_tpu.models.sambay import (  # noqa: F401
     SambaYConfig,
     sambay_init_params,
 )
+from distributed_tensorflow_tpu.models.olmo_hybrid import (  # noqa: F401
+    OlmoHybrid,
+    OlmoHybridConfig,
+    olmo_hybrid_init_params,
+)

@@ -21,9 +21,11 @@ decided here and by the model's ``cache_layout``:
 - ``specs`` / ``shardings`` / ``structs`` / ``zeros`` / ``bytes_per_token`` /
   ``components`` are one pass over that description each, for any layout.
 - :func:`require_pages` is what a mode that moves cached positions about as
-  pages (prefix pool, chunked prefill, verify, export/import, int8, ``model``
-  sharding) calls at construction: it raises, naming the group, for a layout
-  that has a positionless or ring group.
+  pages (prefix pool, verify, export/import, int8, ``model`` sharding) calls
+  at construction: it raises, naming the group, for a layout that has a
+  positionless or ring group. Chunked prefill asks :func:`require_carry`
+  instead: positionless state is what a prompt's chunks hand on, and only a
+  ring refuses.
 - The model's reads and writes — ``take_layer``, ``encode``, ``select_rows``,
   ``write_rows``, ``scatter_rows``, ``stack_layers``, ``write_prompt``,
   ``cached_attention``, ``chunk_attention``, ``paired_attention``,
@@ -160,17 +162,38 @@ def _by_group(layout) -> dict[str, list[Leaf]]:
     return out
 
 
+def _refuse(leaf: Leaf, needs: str) -> ValueError:
+    kind = "positionless" if leaf.after is None else "ring"
+    return ValueError(
+        f"{needs}; cache group {leaf.group!r} is {kind} "
+        f"(after={leaf.after!r}): not supported for this model"
+    )
+
+
 def require_pages(layout, mode: str) -> None:
     """``mode`` treats a cached position as a page it may copy, share,
     quantize or shard by itself. That holds for a table of positions only: a
     ring forgets, and state has no positions."""
     for leaf in jax.tree.leaves(layout):
         if leaf.after != POSITIONS:
-            kind = "positionless" if leaf.after is None else "ring"
-            raise ValueError(
-                f"{mode} needs every cached position to be a transferable "
-                f"page; cache group {leaf.group!r} is {kind} "
-                f"(after={leaf.after!r}): not supported for this model"
+            raise _refuse(
+                leaf, f"{mode} needs every cached position to be a "
+                "transferable page"
+            )
+
+
+def require_carry(layout) -> None:
+    """Chunked prefill needs a carry between a prompt's chunks, not a page: a
+    table of positions takes each chunk's rows where they belong, and
+    positionless state IS the carry (the chunk program gathers a row's slot,
+    hands it to the model's ``prefill_chunk`` and scatters it back). A ring
+    refuses: a chunk's later rows would overwrite what its earlier queries
+    still have to read."""
+    for leaf in jax.tree.leaves(layout):
+        if leaf.after not in (POSITIONS, None):
+            raise _refuse(
+                leaf, "chunked prefill needs every cache group to be a table "
+                "of positions or state carried between chunks"
             )
 
 
@@ -534,7 +557,53 @@ def chunk_attention(q, cache, position):
     softmax weight exactly 0 under the causal mask."""
     # the context comes heads-second, as the product leaves it: the CPU
     # backend has no bf16 dot whose result is transposed ("->bqhc")
+    if q.shape[-1] % 128 == 0 and not _is_side(cache["k"]):
+        return _attend_tiled_heads(q, cache, position)
     return _attend(q, cache, position, "bqch,blc->bhql", "bhql,blc->bhqc")
+
+
+_QUERY_BLOCK = 128  # queries whose scores _attend_tiled_heads holds at a time
+
+
+def _attend_tiled_heads(q, cache, position):
+    """:func:`chunk_attention` where a head is whole lane tiles (``head_dim``
+    a multiple of 128): the merged row splits into heads on tile boundaries,
+    so each head contracts over its own lanes only. :func:`_attend`'s
+    block-diagonal query spends ``heads`` times the operations — nothing
+    beside a decode step's memory traffic, but for a chunk of hundreds of
+    queries over thousands of positions (30 heads of 128: 1.1 TFLOP a layer
+    and row) more than the rest of the model. The copy of a row's table that
+    the split costs is a chunk's, not a step's. A long chunk goes
+    ``_QUERY_BLOCK`` queries at a time, one after another: the float32 scores
+    of 512 queries x 30 heads x 4,608 positions are 283 MB a row, and several
+    of them live at once. Same masking, scaling and float32 accumulation."""
+    k, v = cache["k"], cache["v"]
+    b, c, h, d = q.shape
+    k, v = (a.reshape(*a.shape[:2], h, d) for a in (k, v))
+    position = jnp.minimum(position, k.shape[1] - 1)
+
+    def block(q, position):  # [B, n, h, d], [B, n]
+        s = jnp.einsum(
+            "bqhd,blhd->bhql", q, k, preferred_element_type=jnp.float32
+        ) * d ** -0.5
+        valid = (jnp.arange(k.shape[1]) <= position[..., None])[:, None]
+        s = jnp.where(valid, s, MASK_VALUE)
+        p = jax.nn.softmax(s, axis=-1) * valid
+        ctx = jnp.einsum(
+            "bhql,blhd->bhqd", p.astype(v.dtype), v,
+            preferred_element_type=jnp.float32,
+        )
+        return jnp.swapaxes(ctx, 1, 2).astype(q.dtype)
+
+    if c <= _QUERY_BLOCK or c % _QUERY_BLOCK:
+        return block(q, position)
+    n = c // _QUERY_BLOCK
+    ctx = jax.lax.map(
+        lambda xs: block(*xs),
+        (jnp.moveaxis(q.reshape(b, n, _QUERY_BLOCK, h, d), 1, 0),
+         jnp.moveaxis(position.reshape(b, n, _QUERY_BLOCK), 1, 0)),
+    )
+    return jnp.moveaxis(ctx, 0, 1).reshape(q.shape)
 
 
 def paired_attention(q, cache, valid, lam):

@@ -2030,8 +2030,14 @@ class ContinuousBatcher:
                     self._inflight_sem.acquire()
                 tags = [(i, s.gen) for i, s, *_ in chunk_rows]
                 try:
-                    with tracer.span("engine.chunk_dispatch", "serve",
-                                     rows=len(chunk_rows)):
+                    with tracer.span(
+                        "engine.chunk_dispatch", "serve",
+                        rows=len(chunk_rows),
+                        real_tokens=sum(n for _, _, _, n, _, _ in chunk_rows),
+                        first_chunks=sum(
+                            first for _, _, _, _, first, _ in chunk_rows
+                        ),
+                    ):
                         handle = engine.prefill_chunks([
                             {
                                 "slot": i,

@@ -733,8 +733,10 @@ class BertInferenceEngine(_AotEngine):
 # -- blocks, ...]; these bodies address the slot axis and map over the
 # -- leaves. What the leaves are, and what follows their slots, is the
 # -- model's (its cache_layout) and models/kvcache.py's. The bodies that
-# -- address a positions axis too (chunk, insert, verify) are compiled only
-# -- for a layout whose every group has one (kvcache.require_pages).
+# -- address a positions axis too (insert, verify) are compiled only for a
+# -- layout whose every group has one (kvcache.require_pages); the chunk
+# -- body also for one with positionless state, which it hands from chunk
+# -- to chunk as it finds it in the row's slot (kvcache.require_carry).
 
 
 def _make_causal_prefill(model):
@@ -830,10 +832,18 @@ def _make_causal_verify(model, cache_len: int, k: int):
     return verify_fn
 
 
-def _make_causal_chunk_prefill(model, cache_len: int, block_tokens: int):
+def _make_causal_chunk_prefill(model, cache_len: int, block_tokens: int,
+                               pooled: bool = True):
     """Chunk-prefill executable body for one (tier, chunk bucket): a fused
     page-gather prologue + one absolute-position prompt chunk + on-device
     first-token sampling where the chunk completes its row's prompt.
+
+    Every leaf of the rows' slots goes to the model's ``prefill_chunk`` and
+    comes back: a table of positions with the chunk's rows in it, positionless
+    state as the chunk left it — the carry a recurrence resumes from. An
+    engine without a prefix pool (``pooled`` false: every layout that holds
+    such state, whose leaves no pool page could fill) has no chain to gather
+    and compiles no prologue: its one-block dummy pool rides along unread.
 
     The prologue materializes each row's matched prefix chain (pool block
     ids in ``chain``, first ``n_gather`` entries real) into the row's slot
@@ -850,7 +860,11 @@ def _make_causal_chunk_prefill(model, cache_len: int, block_tokens: int):
     whole padding rows, which also carry slot index == S) write nowhere.
     ``is_last`` rows sample their first token at the prompt's final lane,
     keyed on absolute position exactly like the monolithic prefill — bit
-    parity with the cold path follows."""
+    parity with the cold path follows. A model whose head is too wide to
+    apply at every lane returns that lane's logits alone, ``[T, V]`` (the
+    last lane whose position is in range: models/olmo_hybrid.py); from
+    ``[T, C, V]`` (``CausalLM``, whose verify step reads every column) the
+    lane is taken here."""
 
     def chunk_fn(params, cache, last, pool, ids, starts, lengths, chain,
                  n_gather, slots, temps, seeds):
@@ -870,7 +884,8 @@ def _make_causal_chunk_prefill(model, cache_len: int, block_tokens: int):
             return r.at[:, :, :span].set(jnp.where(sel, g, r[:, :, :span]))
 
         rows = jax.tree.map(lambda a: a[:, slots], cache)  # padding ix clamps
-        rows = jax.tree.map(blend, rows, pool)
+        if pooled:
+            rows = jax.tree.map(blend, rows, pool)
         pos = starts[:, None] + jnp.arange(C)[None, :]
         wpos = jnp.where(pos < lengths[:, None], pos, cache_len)
         logits, rows = model.apply(
@@ -881,9 +896,9 @@ def _make_causal_chunk_prefill(model, cache_len: int, block_tokens: int):
         )
         is_last = starts + C >= lengths
         li = jnp.clip(lengths - 1 - starts, 0, C - 1)
-        tok = sample_tokens(
-            logits[jnp.arange(T), li], temps, seeds, lengths
-        )
+        if logits.ndim == 3:
+            logits = logits[jnp.arange(T), li]
+        tok = sample_tokens(logits, temps, seeds, lengths)
         upd = jnp.where(is_last, tok, jnp.take(last, slots, mode="clip"))
         last = last.at[slots].set(upd, mode="drop")
         return cache, last, tok
@@ -1070,7 +1085,6 @@ class CausalLMEngine(_AotEngine):
         asked = {
             "model sharding": tp > 1,
             "the prefix cache": prefix_cache_mb > 0,
-            "chunked prefill": prefill_chunk > 0,
             "speculative verify": spec_tokens > 0,
             "KV-page transfer": bool(kv_transfer),
             "stream migration": bool(stream_migrate),
@@ -1078,6 +1092,9 @@ class CausalLMEngine(_AotEngine):
         }
         for mode in (mode for mode, on in asked.items() if on):
             kvcache.require_pages(model.cache_layout("float32"), mode)
+        if prefill_chunk > 0:
+            # a carry, not a page: positionless state goes from chunk to chunk
+            kvcache.require_carry(model.cache_layout("float32"))
         serve_cfg = self._serve_config(model.cfg, tp=tp, ep=ep, pp=pp)
         self.model = (
             type(model)(serve_cfg) if serve_cfg is not model.cfg else model
@@ -1189,11 +1206,11 @@ class CausalLMEngine(_AotEngine):
                 | {self.prefill_chunk_size}
             ))
             self._max_chain = max(1, self.buckets[-1] // self.block_tokens)
-            n_blocks, self._bytes_per_block = self._plan_prefix_cache(
-                cfg, tp=tp, prefix_cache_mb=prefix_cache_mb,
-                block_tokens=self.block_tokens, kv_dtype=self.kv_dtype,
-            )
             if prefix_cache_mb > 0:
+                n_blocks, self._bytes_per_block = self._plan_prefix_cache(
+                    cfg, tp=tp, prefix_cache_mb=prefix_cache_mb,
+                    block_tokens=self.block_tokens, kv_dtype=self.kv_dtype,
+                )
                 self.prefix_cache = KVBlockPool(
                     n_blocks, self.block_tokens, self._bytes_per_block,
                     dtype=self.kv_dtype,
@@ -1282,7 +1299,8 @@ class CausalLMEngine(_AotEngine):
             M = self._max_chain
             fn = self._wrap(
                 _make_causal_chunk_prefill(
-                    self.model, self.cache_len, self.block_tokens
+                    self.model, self.cache_len, self.block_tokens,
+                    pooled=self.prefix_cache is not None,
                 ),
                 (self._param_specs, cache, rep, cache) + (rep,) * 8,
                 (cache, rep, rep),
@@ -1435,6 +1453,16 @@ class CausalLMEngine(_AotEngine):
                 np.zeros((self.slots, self.spec_tokens), np.int32), zeros,
                 zeros, zeros.astype(np.float32), zeros,
             ))
+
+    def release_cache(self) -> None:
+        """Give the slot table (and the page pool) back to the device; the
+        engine serves nothing afterwards. For a caller that has closed its
+        batcher and needs the room while the client, the batcher's threads
+        or a status hook still point at the engine: the benchmark scores a
+        hybrid model's streams against a float32 reference that does not fit
+        beside 5 GB of cache (benchmarks/runners/serve_olmo_hybrid.py)."""
+        for leaf in jax.tree.leaves((self._cache, getattr(self, "_pool", ()))):
+            leaf.delete()
 
     @staticmethod
     def _serve_config(cfg, tp: int = 1, ep: int = 1, pp: int = 1):

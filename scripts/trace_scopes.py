@@ -4,7 +4,8 @@ The program names its device work with ``jax.named_scope`` (``flash_fwd``,
 ``flash_dq``, ``flash_dkv``, ``mlm_head``, ``grad_reduce``, ``clip``,
 ``optimizer`` in the training step; ``kv_write``, ``cached_attention``,
 ``lm_head``, ``sample`` in the decode and prefill cells, and a hybrid model's
-``ssm_scan``, ``ssm_step``, ``gmu``, ``window_attention``, ``full_attention``).
+``ssm_scan``, ``ssm_step``, ``gmu``, ``window_attention``, ``full_attention``,
+or ``delta_chunk``, ``delta_step``, ``short_conv``).
 The TPU profiler
 keeps an op's ``op_name`` in the *metadata* of its events, which
 ``jax.profiler.ProfileData`` does not expose (it gives an event's own stats
@@ -41,7 +42,10 @@ SCOPES = ("flash_fwd", "flash_dq", "flash_dkv", "mlm_head", "grad_reduce", "clip
           "optimizer", "kv_write", "cached_attention", "lm_head", "sample",
           # models/sambay.py: the prompt's scan, the step's recurrence, the gated
           # memory units, the ring readers and the readers of the one full table
-          "ssm_scan", "ssm_step", "gmu", "window_attention", "full_attention")
+          "ssm_scan", "ssm_step", "gmu", "window_attention", "full_attention",
+          # models/olmo_hybrid.py: the delta rule over a prompt chunk's blocks
+          # and one token a slot, and the convolution before both
+          "delta_chunk", "delta_step", "short_conv")
 SCOPE_RX = re.compile(r"[/(](" + "|".join(SCOPES) + r")[/)]")
 
 

@@ -389,6 +389,97 @@ def test_hybrid_decode_step_updates_all_three_groups_in_place(
     }, made
 
 
+# ------------- a matrix state and four K/V tables, a step and a prompt chunk
+
+def test_delta_rule_hybrid_step_and_chunk_fit_the_chip(one_chip):
+    """benchmarks/workloads/olmo_hybrid_7b.longdoc_steady: Olmo-Hybrid-7B's
+    first 16 layers at the published widths (4.10 B parameters in bf16), 16
+    slots, cache 4,608. The decode step aliases the whole donated cache —
+    twelve layers' float32 matrix state and conv tails, four K/V tables,
+    5.1 GB as the chip pads it — and reserves tens of megabytes: the state is
+    read and rewritten as one chain, the tables take the flat row scatter and
+    no reader copies one. The chunk program (one row of 512 positions, no
+    prefix pool, so no gather prologue) holds a row's slot twice over and a
+    quarter of the chunk's scores at a time: 1.15 GB of scratch, which with
+    13.3 GB of operands the chip has. Two rows reserve 2.7 GB and more,
+    which it has not: the cell's ``max_batch`` is 1 for that. A
+    compile, not a time (about 30 s)."""
+    import json
+    from pathlib import Path
+
+    from benchmarks.runners import serve_olmo_hybrid as runner
+    from distributed_tensorflow_tpu.models import kvcache
+    from distributed_tensorflow_tpu.models.olmo_hybrid import (
+        OlmoHybrid,
+        olmo_hybrid_init_params,
+    )
+    from distributed_tensorflow_tpu.serve.engine import (
+        _make_causal_chunk_prefill,
+        _make_causal_decode,
+    )
+
+    config = json.loads((
+        Path(__file__).resolve().parents[1]
+        / "benchmarks/configs/olmo_hybrid_7b.json"
+    ).read_text())
+    slots, cache_len, chunk = 16, 4608, config["serving"]["prefill_chunk"]
+    model = OlmoHybrid(runner.model_config(config))
+
+    def struct(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params = jax.tree.map(
+        lambda x: struct(x.shape, jnp.bfloat16),
+        jax.eval_shape(
+            lambda: olmo_hybrid_init_params(model, jax.random.PRNGKey(0))
+        ),
+    )
+    layout = model.cache_layout("bfloat16")
+    on_chip = jax.tree.map(lambda _: one_chip, layout)
+    table = kvcache.structs(layout, (slots, cache_len), on_chip)
+    i32 = lambda *shape: struct(shape, jnp.int32)  # noqa: E731
+    step = (
+        jax.jit(_make_causal_decode(model, cache_len), donate_argnums=(1, 2))
+        .lower(
+            params, table, i32(slots), i32(slots),
+            struct((slots,), jnp.bool_), struct((slots,), jnp.float32),
+            i32(slots),
+        )
+        .compile()
+    )
+    held = sum(
+        nbytes for nbytes, _ in kvcache.components(
+            layout, (slots, cache_len)
+        ).values()
+    )
+    assert held == 4_967_792_640
+    ma = step.memory_analysis()
+    assert ma.alias_size_in_bytes >= held
+    assert ma.temp_size_in_bytes < 64e6, ma.temp_size_in_bytes
+    made = _made_by(step, r"(?:4,)?%d,%d,3840" % (slots, cache_len))
+    assert set(made) <= {
+        "parameter", "get-tuple-element", "tuple", "bitcast",
+    }, made
+
+    rows = 1
+    prompt_chunk = (
+        jax.jit(
+            _make_causal_chunk_prefill(model, cache_len, 16, pooled=False),
+            donate_argnums=(1, 2),
+        )
+        .lower(
+            params, table, i32(slots),
+            kvcache.structs(layout, (1, 16), on_chip), i32(rows, chunk),
+            i32(rows), i32(rows), i32(rows, 256), i32(rows), i32(rows),
+            struct((rows,), jnp.float32), i32(rows),
+        )
+        .compile()
+    )
+    ma = prompt_chunk.memory_analysis()
+    assert ma.alias_size_in_bytes >= held
+    assert ma.temp_size_in_bytes < 1.3e9, ma.temp_size_in_bytes
+
+
 # -------------------------------------- the MLM head over the masked rows
 
 def test_mlm_head_gathers_its_rows_at_the_cell_shape(one_chip):
