@@ -168,12 +168,13 @@ class CausalSelfAttention(nn.Module):
         rows = kvcache.merge_heads
         return self._finish(x, ctx), rows(k), rows(v)
 
-    def decode(self, x, cache, position):
-        """One token per slot against this layer's table AS THE STEP FOUND
-        IT. Returns ``(x', rows)``: the rows ``[S, ..]``, in the table's
-        form, that the step has to write at ``position``, which
-        ``CausalLM.decode_step`` writes for all layers at once. Attention
-        reads the table with the rows selected in, the operand values of
+    def decode(self, x, cache, layer: int, position):
+        """One token per slot against layer ``layer`` of the stacked table
+        AS THE STEP FOUND IT. Returns ``(x', rows)``: the rows ``[S, ..]``,
+        in the table's form, that the step has to write at ``position``,
+        which ``CausalLM.decode_step`` writes for all layers at once.
+        Attention reads the table and the rows beside it
+        (``kvcache.cached_attention``): the operand values of
         write-then-attend without the write."""
         # position == Lmax marks an idle lane: no cache position matches, so
         # nothing is written (writing anywhere could corrupt a mid-chunk-
@@ -181,9 +182,10 @@ class CausalSelfAttention(nn.Module):
         # output is garbage nobody reads.
         q, k, v = self._qkv(x)  # [S, h, d], [S, h * d] x 2
         rows = kvcache.encode(cache, {"k": k, "v": v})
-        with jax.named_scope("cached_attention"):
-            read = kvcache.select_rows(cache, rows, position, slot_axis=0)
-        ctx = kvcache.cached_attention(q, read, position)
+        ctx = kvcache.cached_attention(
+            q, cache, position, rows, layer=layer,
+            sharded=self.cfg.model_axis is not None,
+        )
         return self._finish(x, ctx), rows
 
     def prefill_chunk(self, x, positions, cache):
@@ -230,8 +232,8 @@ class CausalLmLayer(nn.Module):
         x, k, v = self.attention(x, pad_mask)
         return self._ffn(x), k, v
 
-    def decode(self, x, cache, position):
-        x, rows = self.attention.decode(x, cache, position)
+    def decode(self, x, cache, layer: int, position):
+        x, rows = self.attention.decode(x, cache, layer, position)
         return self._ffn(x), rows
 
     def prefill_chunk(self, x, positions, cache):
@@ -318,7 +320,7 @@ class CausalLM(nn.Module):
         )  # [S, H]
         rows = []
         for i, layer in enumerate(self.layers):
-            x, row = layer.decode(x, kvcache.take_layer(cache, i), position)
+            x, row = layer.decode(x, cache, i, position)
             rows.append(row)
         # Every layer read the step's INPUT table; the [nl, S, ..] of new
         # rows go into it here, once and in place (models/kvcache.py, "How

@@ -68,16 +68,34 @@ donated; ``memory_analysis().temp_size_in_bytes``; times are in PERF.md, PR
   or convert of the table, the hybrid step's scratch 129.6 MB with all five
   leaves aliased. Both reads above pass over every position of every slot
   whatever it holds; this one moves ``ceil(length / block)`` blocks a slot;
+- the read of a table that does NOT hold the step's row yet
+  (:func:`cached_attention` given ``rows``, PR 38; ``CausalLM`` and the four
+  full layers of models/olmo_hybrid.py): the same kernel's plain-heads form,
+  ``decode_attention.row_attention``. It moves a slot's blocks below
+  ``position`` and takes the row as an operand, which starts the online
+  softmax (its score the first maximum, its weight 1). Its table operands are
+  the STACKED leaves and the layer an index into them (the DMA reads
+  ``k_hbm.at[layer, slot, rows]``): 0 B at the long-document cell's ``[4, 16,
+  4608, 3840]``, four custom calls in a step that reserves under 64 MB. Two
+  spellings of the same read copy, do not retry them: the select on its way
+  into the custom call — ``where(position_hit, new_row, table[i])`` cannot
+  fuse into a Mosaic call, so the layer is made, both sides: 1,132,655,616 B
+  — and ``take_layer`` of a leaf of several layers — a one-layer leaf's
+  slice is a bitcast (above), a four-layer leaf's is two ``slice``
+  instructions of 566 MB: 1,132,623,360 B. Where the heads, the dtype or the
+  ``cache_len`` do not admit the kernel, the mask form below;
 - a leaf without trailing axes (the int8 scale, ``[nl, S, L]``, 2.4 MB) has
   the position minor-most again; it keeps the select, a pass over 0.3% of what
   the payload's was.
 
-Each layer attends ``where(position_hit, new_row, table[i])`` of the step's
-INPUT table (the select fuses into the attention's read; no layer table
-exists), so the operand values are those of write-then-attend, bit for bit
-(tests/test_decode_kv_write.py), and the rows go in once, after the last
-read. The whole step: 26 MB of scratch, both tables aliased, no fusion, copy,
-slice or convert of a table (tests/test_chip_compile.py keeps it so).
+In the mask form each layer attends ``where(position_hit, new_row,
+table[i])`` of the step's INPUT table (the select fuses into the attention's
+read; no layer table exists), so the operand values are those of
+write-then-attend, bit for bit (tests/test_decode_kv_write.py); in the kernel
+form they are the same values from two operands. Either way the rows go in
+once, after the last read. The whole step: 26 MB of scratch, both tables
+aliased, no fusion, copy, slice or convert of a table
+(tests/test_chip_compile.py keeps it so).
 ``prefill_chunk`` / ``verify_step`` still slice a layer out, scatter into it
 and re-stack, and have the copies by construction.
 """
@@ -114,10 +132,11 @@ class Leaf:
     layers: int
     after: int | str | None = POSITIONS
     group: str = "kv"
-    # Layers whose decode step reads this leaf through :func:`prefix_attention`
-    # (0: every reader passes over all of it), and the positions such a read
-    # moves at a time where the kernel applies to the model's heads (0: it
-    # does not). What :func:`step_reads` counts from.
+    # Layers whose decode step reads this leaf up to the slot's length —
+    # :func:`prefix_attention`, or :func:`cached_attention` given the step's
+    # rows — (0: every reader passes over all of it), and the positions such a
+    # read moves at a time where the kernel applies to the model's heads (0:
+    # it does not). What :func:`step_reads` counts from.
     prefix_readers: int = 0
     prefix_block: int = 0
 
@@ -151,7 +170,17 @@ def cache_layout(cfg, kv_dtype: str):
             "s": Leaf((), np.dtype(np.float32), (), nl),
         }
     else:
-        side = Leaf(row, jnp.dtype(kv_dtype), split, nl)
+        # every layer's decode read is cached_attention given the step's
+        # rows: up to the slot's length where the kernel applies, which a
+        # table split over a mesh axis does not ask for
+        block = 0 if cfg.model_axis else decode_attention.block_for(
+            cfg.num_heads, cfg.hidden_size // cfg.num_heads, cfg.hidden_size,
+            paired=False,
+        )
+        side = Leaf(
+            row, jnp.dtype(kv_dtype), split, nl,
+            prefix_readers=nl if block else 0, prefix_block=block,
+        )
     return {"k": side, "v": side}
 
 
@@ -314,7 +343,10 @@ def step_reads(reads, lengths) -> dict[str, int]:
     ``engine.decode_dispatch`` span: ``<group>_blocks_read`` for ``lengths
     [S]`` (a lane's position + 1, and 0 for an idle lane) and
     ``<group>_blocks_total``, what a step that stopped nowhere would move.
-    Their ratio is the share of the table the step touched."""
+    Their ratio is the share of the table the step touched. A read that
+    takes the step's row as an operand stops one position earlier: where
+    that position is a block's first (one step in ``block``) this counts a
+    block more than it moved."""
     out = {}
     for group, (block, sides, a_slot) in reads.items():
         out[f"{group}_blocks_read"] = sides * int(np.sum(-(-lengths // block)))
@@ -543,10 +575,42 @@ def _attend(q, cache, position, qk: str, pv: str):
     return out.reshape(q.shape).astype(q.dtype)
 
 
-def cached_attention(q, cache, position):
-    """One token per slot: ``q: [S, h, d]``, the layer's cache ``[S, Lmax,
-    ..]``, ``position: [S]`` the index the newest token sits at."""
+def cached_attention(
+    q, cache, position, rows=None, layer: int | None = None,
+    sharded: bool = False,
+):
+    """One token per slot: ``q: [S, h, d]``, ``position: [S]`` the index the
+    newest token sits at, over one layer's table ``[S, Lmax, ..]`` — ``cache``
+    itself, or with ``layer`` that layer of the stacked ``[nl, S, Lmax,
+    ..]``. ``rows [S, ..]`` are the step's own rows, encoded
+    (:func:`encode`) and not in the table yet: the read sees them at
+    ``position`` (``None``: the table holds them already). Where the table
+    admits it — the stacked leaf, plain arrays (not the int8 pair) of whole
+    blocks, heads that are whole lane tiles
+    (``decode_attention.head_window_lanes``), not ``sharded`` over a mesh
+    axis — ops/decode_attention.py reads each slot's blocks below
+    ``position`` where they lie and takes the row as an operand; any other
+    table takes the mask form, a pass over every position with the rows
+    selected in."""
     with jax.named_scope("cached_attention"):
+        k = cache["k"]
+        if (
+            rows is not None and layer is not None and not sharded
+            and not _is_side(k)
+            and _kernel_block(
+                decode_attention.block_for(
+                    *q.shape[-2:], k.shape[-1], paired=False
+                ),
+                k.shape[2],
+            )
+        ):
+            return decode_attention.row_attention(
+                q, k, cache["v"], position, rows["k"], rows["v"], layer=layer
+            ).astype(q.dtype)
+        if layer is not None:
+            cache = take_layer(cache, layer)
+        if rows is not None:
+            cache = select_rows(cache, rows, position, slot_axis=0)
         return _attend(q, cache, position, "sch,slc->shl", "shl,slc->shc")
 
 

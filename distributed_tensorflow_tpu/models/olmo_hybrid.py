@@ -77,6 +77,7 @@ from flax import linen as nn
 
 from distributed_tensorflow_tpu.models import kvcache
 from distributed_tensorflow_tpu.models.kvcache import Leaf
+from distributed_tensorflow_tpu.ops import decode_attention
 
 LINEAR, FULL = "linear_attention", "full_attention"
 _EXACT = jax.lax.Precision.HIGHEST  # the delta rule's own products: float32
@@ -534,11 +535,11 @@ class OlmoHybrid(nn.Module):
         cfg = self.cfg
         kinds = layer_kinds(cfg)
 
-        def group(name, layers, after, leaves):
+        def group(name, layers, after, leaves, **reads):
             return {
                 key: Leaf(
                     shape, jnp.dtype(dtype), (None,) * len(shape),
-                    layers=layers, after=after, group=name,
+                    layers=layers, after=after, group=name, **reads,
                 )
                 for key, (shape, dtype) in leaves.items()
             }
@@ -554,6 +555,13 @@ class OlmoHybrid(nn.Module):
             "full": group(
                 "full", kinds.count(FULL), kvcache.POSITIONS,
                 {"k": row, "v": row},
+                # each full layer's decode read stops at the slot's length
+                # where kvcache.cached_attention takes the kernel
+                prefix_readers=kinds.count(FULL),
+                prefix_block=decode_attention.block_for(
+                    cfg.num_heads, cfg.head_dim, cfg.hidden_size,
+                    paired=False,
+                ),
             ),
         }
 
@@ -611,16 +619,14 @@ class OlmoHybrid(nn.Module):
                 state = kvcache.put_layer(state, n_linear, new)
                 n_linear += 1
             else:
-                # the table AS THE STEP FOUND IT with the new row selected
-                # in; the four layers' rows go in once, below
+                # the table AS THE STEP FOUND IT and the new row beside it;
+                # the four layers' rows go in once, below
                 q, kv = layer.mixer.project(x)
-                table = kvcache.take_layer(full, len(rows))
-                row = kvcache.encode(table, kv)
+                row = kvcache.encode(full, kv)
                 with jax.named_scope("full_attention"):
-                    read = kvcache.select_rows(
-                        table, row, position, slot_axis=0
+                    ctx = kvcache.cached_attention(
+                        q, full, position, row, layer=len(rows)
                     )
-                    ctx = kvcache.cached_attention(q, read, position)
                     mixed = layer.mixer.out(ctx.reshape(ctx.shape[0], -1))
                 rows.append(row)
             x = layer.finish(x, mixed)

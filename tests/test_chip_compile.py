@@ -224,7 +224,7 @@ def _made_by(compiled, shape):
 
 
 @pytest.mark.parametrize("kv", ["bfloat16", "int8"])
-def test_decode_step_never_moves_its_slot_table(one_chip, kv):
+def test_decode_step_never_moves_its_slot_table(one_chip, kv, monkeypatch):
     """A cached position is one contiguous ``heads * head_dim`` row, the
     table's default layout is the one it lives in, and the step writes its
     ``layers x slots`` rows as rows of the flat table, in place
@@ -233,8 +233,23 @@ def test_decode_step_never_moves_its_slot_table(one_chip, kv):
     concatenate or convert, and not by a fusion — the select PR 28 wrote
     with was two, a pass over 3.6 GB a step (PERF.md, PR 33). This is the
     guard that keeps it so when someone touches the layer loop or the
-    attention's contractions. A compile, not a time."""
+    attention's contractions. Since PR 38 the bfloat16 table's twelve reads
+    are the new-row kernel of ops/decode_attention.py, custom calls over the
+    stacked leaves where they lie; int8 K/V keeps the mask form's fusions. A
+    compile, not a time."""
+    from distributed_tensorflow_tpu.ops import decode_attention
+
+    # held to the CPU the kernel would be interpreted: compile it as the
+    # chip would
+    monkeypatch.setattr(decode_attention, "_use_interpret", lambda: False)
     compiled, (layers, row), cache_bytes = _compile_decode(one_chip, kv)
+    stacked = r"bf16\[%d,%d,%d,%d\]" % (layers, _SLOTS, _CACHE_LEN, row)
+    kernels = re.findall(
+        r"^\s*%%(row_attention[.\d]*) = f32\[%d,16,128\]\S* custom-call\("
+        r"(?=.*%s.*%s)" % (_SLOTS, stacked, stacked),
+        compiled.as_text(), re.M,
+    )
+    assert len(kernels) == (layers if kv == "bfloat16" else 0), kernels
     # one layer's pages or all layers', whatever the element type and layout
     table = _made_by(
         compiled, r"(?:%d,|1,)?%d,%d,%d" % (layers, _SLOTS, _CACHE_LEN, row)
@@ -391,14 +406,16 @@ def test_hybrid_decode_step_updates_all_three_groups_in_place(
 
 # ------------- a matrix state and four K/V tables, a step and a prompt chunk
 
-def test_delta_rule_hybrid_step_and_chunk_fit_the_chip(one_chip):
+def test_delta_rule_hybrid_step_and_chunk_fit_the_chip(one_chip, monkeypatch):
     """benchmarks/workloads/olmo_hybrid_7b.longdoc_steady: Olmo-Hybrid-7B's
     first 16 layers at the published widths (4.10 B parameters in bf16), 16
     slots, cache 4,608. The decode step aliases the whole donated cache —
     twelve layers' float32 matrix state and conv tails, four K/V tables,
     5.1 GB as the chip pads it — and reserves tens of megabytes: the state is
     read and rewritten as one chain, the tables take the flat row scatter and
-    no reader copies one. The chunk program (one row of 512 positions, no
+    no reader copies one: each full layer's read is the new-row kernel of
+    ops/decode_attention.py (PR 38), a custom call whose operands are the
+    stacked leaves where they lie and the step's rows. The chunk program (one row of 512 positions, no
     prefix pool, so no gather prologue) holds a row's slot twice over and a
     quarter of the chunk's scores at a time: 1.15 GB of scratch, which with
     13.3 GB of operands the chip has. Two rows reserve 2.7 GB and more,
@@ -413,11 +430,15 @@ def test_delta_rule_hybrid_step_and_chunk_fit_the_chip(one_chip):
         OlmoHybrid,
         olmo_hybrid_init_params,
     )
+    from distributed_tensorflow_tpu.ops import decode_attention
     from distributed_tensorflow_tpu.serve.engine import (
         _make_causal_chunk_prefill,
         _make_causal_decode,
     )
 
+    # held to the CPU the kernel would be interpreted: compile it as the
+    # chip would
+    monkeypatch.setattr(decode_attention, "_use_interpret", lambda: False)
     config = json.loads((
         Path(__file__).resolve().parents[1]
         / "benchmarks/configs/olmo_hybrid_7b.json"
@@ -460,6 +481,13 @@ def test_delta_rule_hybrid_step_and_chunk_fit_the_chip(one_chip):
     assert set(made) <= {
         "parameter", "get-tuple-element", "tuple", "bitcast",
     }, made
+    # the four full layers' reads, each over both stacked leaves whole
+    kernels = re.findall(
+        r"^\s*%(row_attention[.\d]*) = f32\[16,32,128\]\S* custom-call\("
+        r"(?=.*bf16\[4,16,4608,3840\].*bf16\[4,16,4608,3840\])",
+        step.as_text(), re.M,
+    )
+    assert len(kernels) == 4, kernels
 
     rows = 1
     prompt_chunk = (

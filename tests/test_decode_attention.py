@@ -1,7 +1,9 @@
 """ops/decode_attention.py, interpreted on the CPU, against the plain
-statement it replaces for a prefix: ``kvcache.paired_attention`` with the
-mask ``arange(L) < lengths``. Times and the compile for the chip are
-elsewhere (scripts/table_attention_bench.py, tests/test_chip_compile.py)."""
+statements it replaces: for a prefix ``kvcache.paired_attention`` with the
+mask ``arange(L) < lengths``, and for plain heads over a table that lacks the
+step's own row ``kvcache._attend`` over ``select_rows(table, rows,
+position)``. Times and the compile for the chip are elsewhere
+(scripts/table_attention_bench.py, tests/test_chip_compile.py)."""
 
 from __future__ import annotations
 
@@ -11,7 +13,10 @@ import pytest
 
 from distributed_tensorflow_tpu.models import kvcache
 from distributed_tensorflow_tpu.ops import decode_attention
-from distributed_tensorflow_tpu.ops.decode_attention import table_attention
+from distributed_tensorflow_tpu.ops.decode_attention import (
+    row_attention,
+    table_attention,
+)
 
 _CACHE_LEN = 64
 # (query heads, K/V heads, head size): the reasoning cell's, the least the
@@ -74,9 +79,162 @@ def test_the_kernel_applies_to_whole_groups_over_whole_lane_tiles(
     )
 
 
-def test_a_table_of_part_blocks_is_refused_by_the_kernel_itself():
-    q = jnp.zeros((2, 8, 64))
-    table = jnp.zeros((2, 48, 256))
+@pytest.mark.parametrize("form", ["paired", "new_row"])
+def test_a_table_of_part_blocks_is_refused_by_the_kernel_itself(form):
+    n = jnp.zeros((2,), jnp.int32)
     with pytest.raises(ValueError, match="does not apply"):
-        table_attention(q, table, table, jnp.zeros((2,), jnp.int32), 0.5,
-                        block=32)
+        if form == "paired":
+            table = jnp.zeros((2, 48, 256))
+            table_attention(jnp.zeros((2, 8, 64)), table, table, n, 0.5,
+                            block=32)
+        else:
+            table, row = jnp.zeros((1, 2, 48, 512)), jnp.zeros((2, 512))
+            row_attention(jnp.zeros((2, 8, 64)), table, table, n, row, row,
+                          layer=0, block=32)
+
+
+# ---------------- plain heads, the step's own row an operand of the kernel
+
+_ROW_LEN, _ROW_BLOCK = 256, 128
+# (heads, head size): the long-document cell's (three groups of eight and one
+# of six), the chat cell's (a head is half a lane tile: a group and a half),
+# and one short group alone
+_PLAIN = {"cell": (30, 128), "chat": (12, 64), "short": (3, 128)}
+_POSITIONS = {
+    # a first token, a block's last row and the next block's first, the
+    # table's last, the idle sentinel and past it, two mid-block
+    "ragged": [0, 127, 128, _ROW_LEN - 1, _ROW_LEN, _ROW_LEN + 7, 200, 129],
+    "all_idle": [_ROW_LEN] * 4,
+}
+
+
+def _mask_form(q, table, rows, position):
+    read = kvcache.select_rows(table, rows, position, slot_axis=0)
+    return kvcache._attend(q, read, position, "sch,slc->shl", "shl,slc->shc")
+
+
+@pytest.mark.parametrize("positions", sorted(_POSITIONS))
+@pytest.mark.parametrize("dtype", sorted(_TOLERANCE))
+@pytest.mark.parametrize("heads", sorted(_PLAIN))
+def test_row_attention_is_attention_over_the_table_with_the_row_selected_in(
+    heads, dtype, positions
+):
+    """Layer 1 of a stacked table of two. The reference reads a CLEAN table;
+    the kernel one whose every row at and past a slot's position holds a
+    prior occupant's large values (the stale row too), and whose other layer
+    is NaN: neither shows. Idle lanes come back as zeros."""
+    n_q, d = _PLAIN[heads]
+    position = np.asarray(_POSITIONS[positions])
+    slots, lanes = len(position), n_q * d
+    rng = np.random.default_rng(n_q)
+    q = jnp.asarray(rng.normal(size=(slots, n_q, d)), dtype)
+    k, v = (
+        jnp.asarray(rng.normal(size=(slots, _ROW_LEN, lanes)), dtype)
+        for _ in range(2)
+    )
+    rows = {
+        name: jnp.asarray(rng.normal(size=(slots, lanes)), dtype)
+        for name in ("k", "v")
+    }
+    want = np.asarray(
+        _mask_form(q, {"k": k, "v": v}, rows, jnp.asarray(position)),
+        np.float32,
+    )
+    stale = (np.arange(_ROW_LEN) >= position[:, None])[..., None]
+    k, v = (
+        jnp.stack([jnp.full_like(a, jnp.nan), jnp.where(stale, 3e4, a)])
+        for a in (k, v)
+    )
+    got = np.asarray(row_attention(
+        q, k, v, jnp.asarray(position, jnp.int32), rows["k"], rows["v"],
+        layer=1, block=_ROW_BLOCK,
+    ))
+    assert got.shape == (slots, n_q, d) and got.dtype == np.float32
+    assert np.isfinite(got).all()
+    live = position < _ROW_LEN
+    np.testing.assert_allclose(got[live], want[live], atol=_TOLERANCE[dtype])
+    assert not got[~live].any()
+    if live.any():
+        assert np.abs(want[live]).max() > 0.5
+
+
+@pytest.mark.parametrize(
+    "n_q, d, lanes, window",
+    [
+        (30, 128, 3840, 1024),  # Olmo-Hybrid: the last group is six heads
+        (12, 64, 768, 512),     # lm_base: a group and a half
+        (3, 128, 384, 1024),    # one short group
+        (8, 256, 2048, 2048),   # a head is two lane tiles
+        (9, 64, 576, None),     # the last group ends mid-tile
+        (12, 32, 384, None),    # a head is a quarter of a tile
+        (4, 16, 64, None),      # the tests' toy models
+        (40, 64, 1280, None),   # grouped-query: the row is not heads x size
+    ],
+)
+def test_the_new_row_form_applies_to_plain_heads_on_whole_lane_tiles(
+    n_q, d, lanes, window
+):
+    assert decode_attention.head_window_lanes(n_q, d, lanes) == window
+    assert decode_attention.block_for(n_q, d, lanes, paired=False) == (
+        decode_attention.BLOCK if window else 0
+    )
+
+
+@pytest.mark.parametrize(
+    "case", ["kernel", "part_blocks", "sharded", "int8", "one_layer", "toy"]
+)
+def test_cached_attention_takes_the_kernel_where_the_table_admits_it(
+    case, monkeypatch
+):
+    """``kvcache.cached_attention`` with the step's rows: the kernel for the
+    stacked leaf of whole blocks and admitted heads, and for anything else the
+    mask form, bit for bit what the callers spelled out before."""
+    from distributed_tensorflow_tpu.models.quant import quantize_kv
+
+    n_q, d = (4, 16) if case == "toy" else (3, 128)
+    cache_len = 100 if case == "part_blocks" else 128
+    slots, lanes, layer = 3, n_q * d, 1
+    rng = np.random.default_rng(7)
+    position = jnp.asarray([5, cache_len, 77])
+    q = jnp.asarray(rng.normal(size=(slots, n_q, d)), jnp.float32)
+    table = {
+        name: jnp.asarray(
+            rng.normal(size=(2, slots, cache_len, lanes)), jnp.float32
+        )
+        for name in ("k", "v")
+    }
+    fresh = {
+        name: jnp.asarray(rng.normal(size=(slots, lanes)), jnp.float32)
+        for name in ("k", "v")
+    }
+    if case == "int8":
+        table = {
+            name: dict(zip(("q", "s"), quantize_kv(t)))
+            for name, t in table.items()
+        }
+    rows = kvcache.encode(table, fresh)
+    want = _mask_form(q, kvcache.take_layer(table, layer), rows, position)
+    calls = []
+    kernel = decode_attention.row_attention
+    monkeypatch.setattr(
+        decode_attention, "row_attention",
+        lambda *a, **kw: calls.append(kw) or kernel(*a, **kw),
+    )
+    if case == "one_layer":
+        got = kvcache.cached_attention(
+            q, kvcache.take_layer(table, layer), position, rows
+        )
+    else:
+        got = kvcache.cached_attention(
+            q, table, position, rows, layer=layer, sharded=case == "sharded"
+        )
+    assert got.shape == q.shape and got.dtype == q.dtype
+    if case == "kernel":
+        assert calls == [{"layer": layer}]
+        live = np.asarray(position) < cache_len
+        np.testing.assert_allclose(
+            np.asarray(got)[live], np.asarray(want)[live], atol=2e-6
+        )
+    else:
+        assert not calls
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))

@@ -124,6 +124,11 @@ def test_published_size_is_7_43_billion_and_the_cut_4_10():
         "state_bytes_written": 12 * (2_211_840 + 69_120),
         "full_rows_written": 8,
     }
+    # four layers read K and V below each lane's position through the
+    # new-row kernel, 36 blocks of 128 a slot; a table of part blocks takes
+    # the mask form, a pass over the whole slot
+    assert kvcache.prefix_reads(layout, 4608) == {"full": (128, 4 * 2, 36)}
+    assert kvcache.prefix_reads(layout, 4600) == {"full": (4600, 4 * 2, 1)}
 
 
 @pytest.mark.parametrize("lengths", [(13, 7), (4, 1), (21, 16)])
@@ -325,9 +330,15 @@ def test_streams_through_the_batcher_are_the_solo_streams(served):
         spans.setdefault(sp.name, []).append(sp)
     per_lane = engine._writes_per_lane
     assert sorted(per_lane) == ["full_rows_written", "state_bytes_written"]
+    # two layers read K and V; at these toy heads the mask form passes over
+    # a slot whole, so a live lane reads its slot and an idle one nothing
+    assert engine._prefix_reads == {"full": (engine.cache_len, 2 * 2, 1)}
     for sp in spans["engine.decode_dispatch"]:
         for counter, one in per_lane.items():
             assert sp.args[counter] == sp.args["rows"] * one
+        assert sp.args["full_blocks_read"] == sp.args["rows"] * 4
+        assert sp.args["full_blocks_read"] <= sp.args["full_blocks_total"]
+        assert sp.args["full_blocks_total"] == _SLOTS * 4
     sent = spans["engine.chunk_dispatch"]
     assert sum(sp.args["real_tokens"] for sp in sent) == sum(
         len(p["input_ids"]) for p in payloads)
@@ -380,7 +391,9 @@ def test_chunked_prefill_takes_positions_and_state_and_refuses_a_ring(tiny):
     model, _params = tiny
     kvcache.require_carry(model.cache_layout("float32"))  # admitted
     kvcache.require_carry(kvcache.cache_layout(
-        type("Cfg", (), dict(hidden_size=8, model_axis=None, num_layers=1)),
+        type("Cfg", (), dict(
+            hidden_size=8, num_heads=2, model_axis=None, num_layers=1,
+        )),
         "float32",
     ))
     with pytest.raises(ValueError, match=r"positionless"):

@@ -101,8 +101,16 @@ def test_published_size_is_3_85_billion_and_caches_three_groups():
         "full_blocks_read": (0 + 1 + 1 + 2 + 6) * 16,
         "full_blocks_total": 5 * 6 * 16,
     }
+    # lm_base's 12 layers read K and V through the new-row form of the kernel
+    # (PR 38); int8 K/V and the tests' toy heads pass over the whole table
+    lm_base = CausalLMConfig(vocab_size=64)
+    assert kvcache.prefix_reads(
+        kvcache.cache_layout(lm_base, "bfloat16"), 384
+    ) == {"kv": (128, 12 * 2, 3)}
+    assert kvcache.prefix_reads(kvcache.cache_layout(lm_base, "int8"), 384) == {}
     assert kvcache.prefix_reads(kvcache.cache_layout(
-        CausalLMConfig(vocab_size=64), "bfloat16"), 384) == {}
+        CausalLMConfig(vocab_size=64, hidden_size=48, num_heads=4), "bfloat16"
+    ), 384) == {}
 
 
 @pytest.mark.parametrize(
