@@ -168,7 +168,8 @@ class InFlightBatch:
     # (kvcache.step_writes): a row per layer and leaf for each active lane,
     # the bytes of positionless state (an idle lane writes nothing) — and
     # what it reads of a group whose readers stop at the lane's length
-    # (kvcache.step_reads): blocks read, and blocks there are.
+    # (kvcache.step_reads): blocks read, and blocks there are; and whether
+    # the step's inputs went up from the host (``inputs_uploaded``, 0 or 1).
     moved: dict = dataclasses.field(default_factory=dict)
 
 
@@ -767,10 +768,19 @@ def _make_causal_decode(model, cache_len: int):
     idle lanes carry the out-of-bounds position ``cache_len`` so their
     garbage K/V scatters DROP — a mid-chunk-prefill slot rides decode
     steps inactive, and a stray write would corrupt pages its earlier
-    chunks already filled (chunked prefill never re-writes them)."""
+    chunks already filled (chunked prefill never re-writes them).
 
-    def decode_fn(params, cache, last, lengths, active, temps, seeds):
+    The per-lane inputs are ONE ``int32[4, slots]`` operand ``step`` —
+    lengths, live lanes (1 or 0), temperatures (float32 bits) and seeds —
+    and come back advanced by the step, each live lane's length + 1: the
+    next step's operand wherever the batcher's plan held, so it stays on
+    the device beside ``cache`` and ``last`` (``CausalLMEngine.decode``)."""
+
+    def decode_fn(params, cache, last, step):
         params = dequantize_params(params, model.cfg.dtype)
+        lengths, live, seeds = step[0], step[1], step[3]
+        temps = jax.lax.bitcast_convert_type(step[2], jnp.float32)
+        active = live != 0
         pos = jnp.where(
             active, jnp.minimum(lengths, cache_len - 1), cache_len
         )
@@ -779,7 +789,7 @@ def _make_causal_decode(model, cache_len: int):
         )
         tok = sample_tokens(logits, temps, seeds, lengths + 1)
         last = jnp.where(active, tok, last)
-        return cache, last, tok
+        return cache, last, tok, step.at[0].add(live)
 
     return decode_fn
 
@@ -1017,7 +1027,9 @@ class CausalLMEngine(_AotEngine):
     ``last_token`` stays device-resident, so step k+1 dispatches against
     step k's un-fetched output — the host fetch of sampled tokens (finish
     detection, streaming) overlaps the next step's device compute via the
-    batcher's completion thread.
+    batcher's completion thread. So do the decode step's per-lane inputs,
+    advanced by the step itself: a step whose plan did not change uploads
+    nothing (:meth:`decode`).
 
     Sampling is greedy at ``temperature == 0`` and seeded-categorical
     otherwise, keyed on (seed, absolute position) only — a request's token
@@ -1348,12 +1360,13 @@ class CausalLMEngine(_AotEngine):
             "decode",
             self._wrap(
                 _make_causal_decode(self.model, self.cache_len),
-                (self._param_specs, cache, rep) + (rep,) * 4,
-                (cache, rep, rep),
+                (self._param_specs, cache, rep, rep), (cache, rep, rep, rep),
             ),
-            (1, 2), self.params, table_s, i32(slots), i32(slots),
-            self._rep_struct((slots,), jnp.bool_), f32(slots), i32(slots),
+            (1, 2, 3), self.params, table_s, i32(slots), i32(4, slots),
         )
+        # The decode step's operand on the device, and the host's copy of
+        # it as the step left it: None while nothing is there (decode).
+        self._step_inputs = self._step_mirror = None
         # What the decode program reserves beside its operands, and how much
         # of the donated slot table it updates in place (per device; None
         # where the backend reports no memory analysis). Decided at compile
@@ -2034,31 +2047,42 @@ class CausalLMEngine(_AotEngine):
     def decode(self, lengths, active, temps, seeds) -> InFlightBatch:
         """Dispatch ONE decode step over the full slot table (host arrays
         are snapshots; the batcher advances its lengths at dispatch so
-        steps pipeline). Returns without blocking."""
+        steps pipeline). Returns without blocking.
+
+        The step's inputs stay on the device: the program hands them back
+        advanced by the step, and where the planned arrays equal that — the
+        plan held, every live lane one longer — the step runs on them and
+        uploads nothing. Any other plan (an admission, a finish, a lane back
+        from verify, a new temperature or seed) is one upload. The
+        comparison is exact, so a reused operand is the upload bit for bit;
+        the span counter ``inputs_uploaded`` says which it was."""
         key = ("decode",)
 
         def _make():
-            s = self.slots
-            return (
-                np.zeros((s,), np.int32),
-                np.zeros((s,), bool),
-                np.zeros((s,), np.float32),
-                np.zeros((s,), np.int32),
-            )
+            return (np.zeros((4, self.slots), np.int32),)
 
-        blen, bact, btmp, bseed = buffers = self._take_buffers(key, _make)
+        (plan,) = buffers = self._take_buffers(key, _make)
+        blen, bact = plan[0], plan[1]
         np.copyto(blen, lengths)
         np.copyto(bact, active)
-        np.copyto(btmp, temps)
-        np.copyto(bseed, seeds)
-        t_assembled = time.monotonic()
-        self._cache, self._last_token, tok = self._decode_compiled(
-            self.params, self._cache, self._last_token,
-            jax.device_put(blen, self._rep), jax.device_put(bact, self._rep),
-            jax.device_put(btmp, self._rep), jax.device_put(bseed, self._rep),
+        np.copyto(plan[2].view(np.float32), temps)
+        np.copyto(plan[3], seeds)
+        upload = self._step_mirror is None or not np.array_equal(
+            plan, self._step_mirror
         )
+        t_assembled = time.monotonic()
+        step = jax.device_put(plan, self._rep) if upload else self._step_inputs
+        # donated: nothing is on the device until the call returns
+        self._step_inputs = self._step_mirror = None
+        (self._cache, self._last_token, tok,
+         self._step_inputs) = self._decode_compiled(
+            self.params, self._cache, self._last_token, step,
+        )
+        self._step_mirror = plan.copy()
+        self._step_mirror[0] += bact
         n = int(np.sum(bact))
         moved = {name: n * one for name, one in self._writes_per_lane.items()}
+        moved["inputs_uploaded"] = int(upload)
         if self._prefix_reads:
             # as the step sees them: position + 1, and 0 for an idle lane
             seen = np.where(bact, np.minimum(blen, self.cache_len - 1) + 1, 0)

@@ -192,13 +192,11 @@ def _compile_decode(one_chip, kv):
     )
     compiled = (
         jax.jit(
-            _make_causal_decode(model, _CACHE_LEN), donate_argnums=(1, 2)
+            _make_causal_decode(model, _CACHE_LEN), donate_argnums=(1, 2, 3)
         )
         .lower(
             params, table,
-            struct((_SLOTS,), jnp.int32), struct((_SLOTS,), jnp.int32),
-            struct((_SLOTS,), jnp.bool_), struct((_SLOTS,), jnp.float32),
-            struct((_SLOTS,), jnp.int32),
+            struct((_SLOTS,), jnp.int32), struct((4, _SLOTS), jnp.int32),
         )
         .compile()
     )
@@ -365,12 +363,12 @@ def test_hybrid_decode_step_updates_all_three_groups_in_place(
         layout, (slots, cache_len), jax.tree.map(lambda _: one_chip, layout)
     )
     compiled = (
-        jax.jit(_make_causal_decode(model, cache_len), donate_argnums=(1, 2))
+        jax.jit(
+            _make_causal_decode(model, cache_len), donate_argnums=(1, 2, 3)
+        )
         .lower(
             params, table,
-            struct((slots,), jnp.int32), struct((slots,), jnp.int32),
-            struct((slots,), jnp.bool_), struct((slots,), jnp.float32),
-            struct((slots,), jnp.int32),
+            struct((slots,), jnp.int32), struct((4, slots), jnp.int32),
         )
         .compile()
     )
@@ -387,11 +385,12 @@ def test_hybrid_decode_step_updates_all_three_groups_in_place(
     ma = compiled.memory_analysis()
     assert ma.alias_size_in_bytes >= sum(by_group.values())
     assert ma.temp_size_in_bytes < 0.5e9, ma.temp_size_in_bytes
-    # the five leaves, each an output that aliases its own parameter
+    # the five leaves, ``last`` and the step's inputs, each an output that
+    # aliases its own parameter
     text = compiled.as_text()
     aliased = re.search(r"input_output_alias=\{([^\n]*)\}, entry", text)
     assert aliased and aliased.group(1).count("may-alias") \
-        + aliased.group(1).count("must-alias") >= 6, aliased
+        + aliased.group(1).count("must-alias") >= 7, aliased
     # the table's eight readers, and nothing else that holds a table
     kernels = re.findall(
         r"^\s*%(table_attention[.\d]*) = f32\[128,20,128\]\S* custom-call\(",
@@ -460,12 +459,10 @@ def test_delta_rule_hybrid_step_and_chunk_fit_the_chip(one_chip, monkeypatch):
     table = kvcache.structs(layout, (slots, cache_len), on_chip)
     i32 = lambda *shape: struct(shape, jnp.int32)  # noqa: E731
     step = (
-        jax.jit(_make_causal_decode(model, cache_len), donate_argnums=(1, 2))
-        .lower(
-            params, table, i32(slots), i32(slots),
-            struct((slots,), jnp.bool_), struct((slots,), jnp.float32),
-            i32(slots),
+        jax.jit(
+            _make_causal_decode(model, cache_len), donate_argnums=(1, 2, 3)
         )
+        .lower(params, table, i32(slots), i32(4, slots))
         .compile()
     )
     held = sum(

@@ -202,7 +202,9 @@ def test_modes_that_need_pages_refuse_at_construction_naming_the_group(
 # a layer's table is sliced out inside kvcache.cached_attention, after the
 # projections and not before them: the same instructions (the text sorted,
 # value names aside, is the parent's) in another order, so other digests.
-_LM_BASE_DECODE = {"bfloat16": "157d0724734ac2b1", "int8": "c7f4bad3d5003e62"}
+# The step's per-lane inputs are now one packed operand that the step hands
+# back advanced (engine._make_causal_decode): the digests of that program.
+_LM_BASE_DECODE = {"bfloat16": "35827c7b9ddca85a", "int8": "adafd8bd05be4496"}
 
 
 _GRIDS = {
@@ -267,12 +269,11 @@ def test_lm_base_decode_lowers_to_the_program_it_was(kv):
         ),
         kvcache.cache_layout(cfg, kv),
     )
-    vec = lambda dtype: jax.ShapeDtypeStruct((slots,), dtype)  # noqa: E731
     lowered = jax.jit(
-        _make_causal_decode(model, cache_len), donate_argnums=(1, 2)
+        _make_causal_decode(model, cache_len), donate_argnums=(1, 2, 3)
     ).lower(
-        params, table, vec(jnp.int32), vec(jnp.int32), vec(jnp.bool_),
-        vec(jnp.float32), vec(jnp.int32),
+        params, table, jax.ShapeDtypeStruct((slots,), jnp.int32),
+        jax.ShapeDtypeStruct((4, slots), jnp.int32),
     )
     digest = hashlib.sha256(lowered.as_text().encode()).hexdigest()[:16]
     assert digest == _LM_BASE_DECODE[kv]

@@ -289,6 +289,141 @@ def test_decode_dispatch_counts_the_cache_rows_it_writes(traced_run):
     ]
 
 
+# ------------------------- the decode step's inputs stay on the device
+
+
+_PROMPT_A, _PROMPT_B = [5, 9, 2, 44, 17], [7, 3, 30, 11]
+#: the steps of _drive_plan that change its plan: the first, an admission,
+#: a finish, a new temperature, a new seed
+_PLANNED_UPLOADS = [1, 0, 0, 1, 0, 0, 1, 0, 1, 0, 1, 0]
+
+
+def _drive_plan(engine, stale: bool):
+    """Drive ``engine`` by hand through a plan the batcher could make:
+    request A alone for three steps, B admitted beside it for three, A
+    finished for two, then B's temperature and, after two steps, its seed
+    changed. Returns each step's ``inputs_uploaded`` and every slot's
+    tokens (the prefill's first, then one a step). ``stale`` forgets what
+    the device holds before every step, so each step uploads its plan."""
+    S = engine.slots
+    live = {}  # slot -> [length, temperature, seed], as the batcher keeps them
+    tokens = {0: [], 1: []}
+    uploads = []
+
+    def admit(slot, prompt):
+        tok = engine.fetch_step(engine.prefill(
+            [{"slot": slot, "input_ids": np.asarray(prompt)}]
+        ))
+        tokens[slot].append(int(tok[0]))
+        live[slot] = [len(prompt), 0.0, 0]
+
+    def step(n=1):
+        for _ in range(n):
+            lengths, active, temps, seeds = [0] * S, [False] * S, [0.0] * S, [0] * S
+            for i, (length, temp, seed) in live.items():
+                lengths[i], active[i], temps[i], seeds[i] = length, True, temp, seed
+                live[i][0] += 1  # advances at dispatch
+            if stale:
+                engine._step_mirror = None
+            handle = engine.decode(lengths, active, temps, seeds)
+            uploads.append(handle.moved["inputs_uploaded"])
+            tok = engine.fetch_step(handle)
+            for i in live:
+                tokens[i].append(int(tok[i]))
+
+    admit(0, _PROMPT_A)
+    step(3)
+    admit(1, _PROMPT_B)
+    step(3)
+    del live[0]
+    step(2)
+    live[1][1] = 0.7
+    step(2)
+    live[1][2] = 123
+    step(2)
+    return uploads, tokens
+
+
+@pytest.fixture(scope="module")
+def planned_drives(tiny_lm):
+    """One fresh engine driven through ``_drive_plan`` twice: as planned,
+    then with every step's operand forgotten."""
+    from distributed_tensorflow_tpu.serve import CausalLMEngine
+
+    model, params = tiny_lm
+    engine = CausalLMEngine(
+        model, params, buckets=(8, 16), slots=3, max_batch=2,
+        max_new_tokens=8,
+    )
+    return _drive_plan(engine, stale=False), _drive_plan(engine, stale=True)
+
+
+def test_decode_uploads_its_inputs_only_where_the_plan_changed(planned_drives):
+    """The engine keeps the step's lengths, live lanes, temperatures and
+    seeds on the device, advanced by the step: steps whose plan held upload
+    nothing, and the first step, an admission, a finish, a new temperature
+    and a new seed each upload once. Forgetting the device's copy makes
+    every step upload."""
+    (planned, _), (stale, _) = planned_drives
+    assert planned == _PLANNED_UPLOADS
+    assert stale == [1] * len(_PLANNED_UPLOADS)
+
+
+def test_reused_step_inputs_sample_the_tokens_of_fresh_uploads(
+    planned_drives, tiny_lm
+):
+    """A step that ran on the resident operand sampled what the same step
+    fed its plan afresh samples, greedy and seeded alike, and the greedy
+    stream is the full-forward reference's."""
+    (_, planned), (_, stale) = planned_drives
+    assert planned == stale
+    assert len(planned[0]) == 7 and len(planned[1]) == 10
+    model, params = tiny_lm
+    assert planned[0] == _ref_greedy(model, params, _PROMPT_A, 7)
+    # B stays greedy until its temperature changes, four steps before the end
+    assert planned[1][:6] == _ref_greedy(model, params, _PROMPT_B, 6)
+
+
+def test_resident_step_inputs_serve_the_reference_two_steps_in_flight(
+    decode_engine, tiny_lm
+):
+    """Through ``Client`` with two steps in flight — a step dispatched on an
+    operand the step before it is still computing — every stream is the
+    plain reference's, and the dispatch spans count steps that reused the
+    resident inputs beside steps that uploaded theirs."""
+    from distributed_tensorflow_tpu.obs.trace import Tracer
+
+    model, params = tiny_lm
+    rng = np.random.default_rng(2)
+    reqs = [
+        {
+            "input_ids": rng.integers(5, 64, size=int(rng.integers(3, 14))),
+            "max_new_tokens": int(rng.integers(4, 9)),
+        }
+        for _ in range(6)
+    ]
+    refs = [
+        _ref_greedy(model, params, r["input_ids"], r["max_new_tokens"])
+        for r in reqs
+    ]
+    tracer = Tracer(1 << 14)
+    client = Client(
+        decode_engine,
+        BatcherConfig(max_batch=2, max_queue=32, max_in_flight=2),
+        tracer=tracer,
+    )
+    try:
+        futs = [client.submit(r) for r in reqs]
+        assert [f.result(timeout=120)["tokens"] for f in futs] == refs
+    finally:
+        client.close()
+    uploaded = [
+        s.args["inputs_uploaded"] for s in tracer.drain()
+        if s.name == "engine.decode_dispatch"
+    ]
+    assert set(uploaded) == {0, 1}, uploaded
+
+
 def test_request_phases_keep_their_three_keys(traced_run):
     spans, _spy, futs, _snap = traced_run
     for f in futs:
