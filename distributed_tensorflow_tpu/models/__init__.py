@@ -45,3 +45,8 @@ from distributed_tensorflow_tpu.models.olmo_hybrid import (  # noqa: F401
     OlmoHybridConfig,
     olmo_hybrid_init_params,
 )
+from distributed_tensorflow_tpu.models.deepseek_v2 import (  # noqa: F401
+    DeepseekV2,
+    DeepseekV2Config,
+    deepseek_v2_init_params,
+)

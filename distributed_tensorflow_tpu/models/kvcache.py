@@ -17,19 +17,22 @@ decided here and by the model's ``cache_layout``:
   makes each side the ``{"q", "s"}`` pair of models/quant.py: the int8
   payload and its float32 per-position scale, which has no trailing axes.
   models/sambay.py declares three groups: ``state`` (positionless),
-  ``window`` (a ring) and ``full`` (positions).
+  ``window`` (a ring) and ``full`` (positions); models/deepseek_v2.py one,
+  ``latent``: a table of positions whose row serves every head, no K/V page
+  (``Leaf.pages`` false), read by :func:`latent_attention`.
 - ``specs`` / ``shardings`` / ``structs`` / ``zeros`` / ``bytes_per_token`` /
   ``components`` are one pass over that description each, for any layout.
 - :func:`require_pages` is what a mode that moves cached positions about as
   pages (prefix pool, verify, export/import, int8, ``model`` sharding) calls
   at construction: it raises, naming the group, for a layout that has a
-  positionless or ring group. Chunked prefill asks :func:`require_carry`
-  instead: positionless state is what a prompt's chunks hand on, and only a
-  ring refuses.
+  positionless, ring or latent group. Chunked prefill asks
+  :func:`require_carry` instead: positionless state is what a prompt's
+  chunks hand on, and only a ring refuses.
 - The model's reads and writes — ``take_layer``, ``encode``, ``select_rows``,
   ``write_rows``, ``scatter_rows``, ``stack_layers``, ``write_prompt``,
   ``cached_attention``, ``chunk_attention``, ``paired_attention``,
-  ``prefix_attention`` — are written once for every form.
+  ``prefix_attention``, ``latent_attention`` — are written once for every
+  form.
 - ``split_kv`` / ``join_kv`` / ``page_geometry`` convert at the engine's host
   boundary, where serve/disagg.py and the wire format still speak of
   ``pages_k, pages_v`` and of ``[.., heads, head_dim]``: a row-major reshape
@@ -139,6 +142,10 @@ class Leaf:
     # it does not). What :func:`step_reads` counts from.
     prefix_readers: int = 0
     prefix_block: int = 0
+    # Whether a position of this group is a K/V page the host half (the
+    # prefix pool, the wire, ``split_kv``) can move: ``{"k", "v"}`` rows of
+    # heads. A latent row (models/deepseek_v2.py) is one row for every head.
+    pages: bool = True
 
     def lead(self, axes: tuple[int, ...]) -> tuple[int, ...]:
         """The leading shape for ``axes = (*front, positions)``: ``(slots,
@@ -192,7 +199,9 @@ def _by_group(layout) -> dict[str, list[Leaf]]:
 
 
 def _refuse(leaf: Leaf, needs: str) -> ValueError:
-    kind = "positionless" if leaf.after is None else "ring"
+    kind = {None: "positionless", POSITIONS: "latent rows, not K/V pages"}.get(
+        leaf.after, "ring"
+    )
     return ValueError(
         f"{needs}; cache group {leaf.group!r} is {kind} "
         f"(after={leaf.after!r}): not supported for this model"
@@ -201,10 +210,11 @@ def _refuse(leaf: Leaf, needs: str) -> ValueError:
 
 def require_pages(layout, mode: str) -> None:
     """``mode`` treats a cached position as a page it may copy, share,
-    quantize or shard by itself. That holds for a table of positions only: a
-    ring forgets, and state has no positions."""
+    quantize or shard by itself. That holds for a table of K/V positions
+    only: a ring forgets, state has no positions, and a latent row is no
+    page of heads."""
     for leaf in jax.tree.leaves(layout):
-        if leaf.after != POSITIONS:
+        if leaf.after != POSITIONS or not leaf.pages:
             raise _refuse(
                 leaf, f"{mode} needs every cached position to be a "
                 "transferable page"
@@ -351,6 +361,29 @@ def step_reads(reads, lengths) -> dict[str, int]:
     for group, (block, sides, a_slot) in reads.items():
         out[f"{group}_blocks_read"] = sides * int(np.sum(-(-lengths // block)))
         out[f"{group}_blocks_total"] = sides * len(lengths) * a_slot
+    return out
+
+
+def whole_reads(layout, cache_len: int) -> dict[str, int]:
+    """``{group: cache_len}`` of the tables of positions whose decode-step
+    readers take no prefix length: each read passes over every position of
+    every slot (the mask form). What :func:`step_positions` counts from."""
+    return {
+        group: cache_len for group, leaves in _by_group(layout).items()
+        if leaves[0].after == POSITIONS and not leaves[0].prefix_readers
+    }
+
+
+def step_positions(whole, lengths) -> dict[str, int]:
+    """Beside :func:`step_reads`, for the groups of :func:`whole_reads`:
+    ``<group>_positions_live``, the positions ``lengths [S]`` hold (a lane's
+    position + 1, 0 for an idle lane), and ``<group>_positions_total``, what
+    the step passes over. Their ratio is the share a read that stopped at
+    each slot's length would touch."""
+    out = {}
+    for group, cache_len in whole.items():
+        out[f"{group}_positions_live"] = int(np.sum(lengths))
+        out[f"{group}_positions_total"] = len(lengths) * cache_len
     return out
 
 
@@ -612,6 +645,33 @@ def cached_attention(
         if rows is not None:
             cache = select_rows(cache, rows, position, slot_axis=0)
         return _attend(q, cache, position, "sch,slc->shl", "shl,slc->shc")
+
+
+def latent_attention(q, table, position, row, scale: float):
+    """One token per slot, every head over ONE cached row a position (the
+    absorbed form of multi-head latent attention, models/deepseek_v2.py):
+    ``q [S, h, r]`` the heads' queries in the row's own coordinates, ``table
+    [S, L, r]`` one layer's rows as the step found them, ``row [S, r]`` the
+    step's own, encoded and not in the table yet, seen at ``position [S]``
+    (``L`` or more on an idle lane: nothing is selected in, and what it
+    reads nobody uses). Scores ``scale * q . row`` over positions ``<=
+    position``, float32 softmax, and the context over the whole row ``[S,
+    h, r]`` float32: the caller keeps the lanes that are values. The mask
+    form: the select fuses into both contractions' read, a pass over every
+    position of every slot."""
+    with jax.named_scope("latent_attention"):
+        table = select_rows(table, row, position, slot_axis=0)
+        position = jnp.minimum(position, table.shape[1] - 1)
+        s = jnp.einsum(
+            "shr,slr->shl", q, table, preferred_element_type=jnp.float32
+        ) * scale
+        valid = (jnp.arange(table.shape[1]) <= position[:, None])[:, None]
+        s = jnp.where(valid, s, MASK_VALUE)
+        p = jax.nn.softmax(s, axis=-1) * valid
+        return jnp.einsum(
+            "shl,slr->shr", p.astype(table.dtype), table,
+            preferred_element_type=jnp.float32,
+        )
 
 
 def chunk_attention(q, cache, position):

@@ -25,6 +25,13 @@ Capacity semantics are the standard Switch Transformer rules: each expert
 processes at most ``capacity = ceil(capacity_factor * N / E)`` tokens, in
 token order; overflow tokens are dropped (their output is 0 — pair MoE
 blocks with residual connections, as transformers do).
+
+Serving cannot drop: a served token's result must not depend on its
+batch-mates. :func:`moe_dropless` routes top-k over every expert with no
+capacity and computes the part of the result that the experts it holds
+give, their rows sorted by expert through one grouped matmul
+(``jax.lax.ragged_dot``) — the layer that expert parallelism needs on each
+chip, run without its exchange where one chip holds them all.
 """
 
 from __future__ import annotations
@@ -382,6 +389,67 @@ def moe_apply_a2a(
     # Reassemble the replicated [N, H] layout (rank-ordered slices).
     y = lax.all_gather(y_loc, axis_name, axis=0, tiled=True)
     return y, aux
+
+
+def moe_dropless(
+    x: jax.Array,
+    router_logits: jax.Array,
+    experts: dict,
+    k: int,
+    *,
+    first: int = 0,
+    renormalize: bool = False,
+    scale: float = 1.0,
+):
+    """Dropless top-``k`` of a softmax router over ALL experts, computed for
+    the experts this chip holds: ``sum_{e in top_k, held} g_e FFN_e(x)``
+    with ``FFN_e(u) = W_down,e (silu(W_gate,e u) * W_up,e u)``.
+
+    Args:
+      x: tokens ``[N, H]`` in the type the experts' matmuls take.
+      router_logits: ``[N, E]`` over every expert (float32).
+      experts: the held experts' stacked weights, ``gate_up [e, H, 2F]``
+        (gate | up) and ``down [e, F, H]``; experts ``first .. first + e - 1``.
+      k: experts per token.
+      renormalize: divide the chosen gates by their sum (``norm_topk_prob``);
+        DeepSeek-V2 does not.
+      scale: ``routed_scaling_factor``.
+
+    Returns ``(y [N, H] float32, choice [N, k])``: the held experts' partial
+    result (the absent experts' part is left out, as another chip adds it)
+    and every token's chosen experts. No capacity: every (token, choice) row
+    whose expert is held is computed, whatever its batch-mates chose. The
+    rows are sorted by expert (the others last, outside every group) and go
+    through one ``ragged_dot`` per matrix, which computes each row against
+    its own expert only."""
+    n = x.shape[0]
+    held = experts["down"].shape[0]
+    with jax.named_scope("moe_route"):
+        probs = jax.nn.softmax(router_logits.astype(jnp.float32), axis=-1)
+        gate, choice = lax.top_k(probs, k)  # greedy, [N, k]
+        if renormalize:
+            gate = gate / jnp.sum(gate, axis=-1, keepdims=True)
+        local = choice.reshape(-1) - first
+        mine = (local >= 0) & (local < held)
+        group = jnp.where(mine, local, held)  # held: after every group
+        order = jnp.argsort(group, stable=True)
+        sizes = jnp.bincount(group, length=held + 1)[:held].astype(jnp.int32)
+        rows = x[order // k]
+        weight = jnp.where(mine, gate.reshape(-1) * scale, 0.0)[order]
+    with jax.named_scope("moe_experts"):
+        h = lax.ragged_dot(rows, experts["gate_up"], sizes,
+                           preferred_element_type=jnp.float32)
+        g, u = jnp.split(h, 2, axis=-1)
+        h = (jax.nn.silu(g) * u).astype(x.dtype)
+        y = lax.ragged_dot(h, experts["down"], sizes,
+                           preferred_element_type=jnp.float32)
+        # rows outside every group are zeros; their weight is 0 besides
+        y = y * weight[:, None]
+        back = jnp.zeros_like(order).at[order].set(
+            jnp.arange(order.shape[0], dtype=order.dtype)
+        )
+        y = jnp.sum(y[back].reshape(n, k, -1), axis=1)
+    return y, choice
 
 
 def stack_expert_params(per_expert_params: list) -> Any:

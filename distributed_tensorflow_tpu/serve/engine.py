@@ -168,7 +168,10 @@ class InFlightBatch:
     # (kvcache.step_writes): a row per layer and leaf for each active lane,
     # the bytes of positionless state (an idle lane writes nothing) — and
     # what it reads of a group whose readers stop at the lane's length
-    # (kvcache.step_reads): blocks read, and blocks there are; and whether
+    # (kvcache.step_reads): blocks read, and blocks there are — or, of a
+    # group read whole, the positions live and passed over
+    # (kvcache.step_positions); what the model counts a live lane for (its
+    # ``decode_counters``); and whether
     # the step's inputs went up from the host (``inputs_uploaded``, 0 or 1).
     moved: dict = dataclasses.field(default_factory=dict)
 
@@ -1157,10 +1160,15 @@ class CausalLMEngine(_AotEngine):
         # leaves, the engine handles the slot axis of whatever they are and
         # asks the layout for sizes.
         self._layout = self.model.cache_layout(self.kv_dtype)
-        # what a decode step writes for each live lane, by group: the
+        # what a decode step writes for each live lane, by group, and what
+        # else the model counts one for (its ``decode_counters``): the
         # counters the dispatch span carries
-        self._writes_per_lane = kvcache.step_writes(self._layout, 1)
+        self._writes_per_lane = {
+            **kvcache.step_writes(self._layout, 1),
+            **getattr(self.model, "decode_counters", dict)(),
+        }
         self._prefix_reads = kvcache.prefix_reads(self._layout, self.cache_len)
+        self._whole_reads = kvcache.whole_reads(self._layout, self.cache_len)
         table = (slots, self.cache_len)
         if self._model_sharded:
             self._param_specs = self.model.param_specs(
@@ -2083,10 +2091,11 @@ class CausalLMEngine(_AotEngine):
         n = int(np.sum(bact))
         moved = {name: n * one for name, one in self._writes_per_lane.items()}
         moved["inputs_uploaded"] = int(upload)
-        if self._prefix_reads:
+        if self._prefix_reads or self._whole_reads:
             # as the step sees them: position + 1, and 0 for an idle lane
             seen = np.where(bact, np.minimum(blen, self.cache_len - 1) + 1, 0)
             moved.update(kvcache.step_reads(self._prefix_reads, seen))
+            moved.update(kvcache.step_positions(self._whole_reads, seen))
         return InFlightBatch(
             out={"tok": tok}, key=key, n=n, meta=None,
             buffers=buffers, layout=self.layout, t_assembled=t_assembled,

@@ -505,6 +505,98 @@ def test_delta_rule_hybrid_step_and_chunk_fit_the_chip(one_chip, monkeypatch):
     assert ma.temp_size_in_bytes < 1.3e9, ma.temp_size_in_bytes
 
 
+# ---------- a latent row a position and 64 routed experts, a step and a chunk
+
+def test_latent_attention_step_and_chunk_fit_the_chip(one_chip):
+    """benchmarks/workloads/deepseek_v2_lite.docqa_steady: DeepSeek-V2-Lite's
+    first 7 layers at the published widths (4.01 B parameters in bf16), 128
+    slots, cache 4,608. The decode step aliases the donated latent table —
+    7 layers of one row a position, 576 lanes held as 640, 5.28 GB — and
+    makes nothing of its size: each layer's absorbed read fuses the step's
+    row into its two passes over the layer, and the rows go in as rows of
+    the flat table (a 576-lane row could not: the compiler copies the whole
+    table to write one, models/deepseek_v2.py::row_width). The routed
+    experts are grouped matmuls whose FLOPs are the rows' own, not every row
+    through every expert. The chunk program (one row of 512) reserves a
+    quarter of a gigabyte; two rows reserve 3 GB, past what the chip has
+    beside 13.3 GB of operands: the cell's ``max_batch`` is 1. A compile,
+    not a time (about 30 s)."""
+    import json
+    from pathlib import Path
+
+    from benchmarks.runners import serve_deepseek_v2 as runner
+    from distributed_tensorflow_tpu.models import kvcache
+    from distributed_tensorflow_tpu.models.deepseek_v2 import (
+        DeepseekV2,
+        deepseek_v2_init_params,
+    )
+    from distributed_tensorflow_tpu.serve.engine import (
+        _make_causal_chunk_prefill,
+        _make_causal_decode,
+    )
+
+    config = json.loads((
+        Path(__file__).resolve().parents[1]
+        / "benchmarks/configs/deepseek_v2_lite.json"
+    ).read_text())
+    slots, cache_len, chunk = 128, 4608, config["serving"]["prefill_chunk"]
+    model = DeepseekV2(runner.model_config(config))
+
+    def struct(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params = jax.tree.map(
+        lambda x: struct(x.shape, jnp.bfloat16),
+        jax.eval_shape(
+            lambda: deepseek_v2_init_params(model, jax.random.PRNGKey(0))
+        ),
+    )
+    layout = model.cache_layout("bfloat16")
+    on_chip = jax.tree.map(lambda _: one_chip, layout)
+    table = kvcache.structs(layout, (slots, cache_len), on_chip)
+    i32 = lambda *shape: struct(shape, jnp.int32)  # noqa: E731
+    step = (
+        jax.jit(
+            _make_causal_decode(model, cache_len), donate_argnums=(1, 2, 3)
+        )
+        .lower(params, table, i32(slots), i32(4, slots))
+        .compile()
+    )
+    held = kvcache.components(layout, (slots, cache_len))["cache.latent"][0]
+    assert held == 7 * slots * cache_len * 640 * 2 == 5_284_823_040
+    ma = step.memory_analysis()
+    assert ma.alias_size_in_bytes >= held
+    assert ma.temp_size_in_bytes < 200e6, ma.temp_size_in_bytes
+    made = _made_by(step, r"(?:7,)?%d,%d,640" % (slots, cache_len))
+    assert set(made) <= {
+        "parameter", "get-tuple-element", "tuple", "bitcast",
+    }, made
+    # 6 MoE layers x 768 rows x (2,048 x 2,816 + 1,408 x 2,048) x 2: the
+    # rows' own experts; every row through all 64 would be 64 times that
+    routed = 6 * 768 * 3 * 2048 * 1408 * 2
+    cost = step.cost_analysis()
+    cost = cost[0] if isinstance(cost, list) else cost
+    assert routed < cost["flops"] < 8 * routed, cost["flops"]
+
+    rows = 1
+    prompt_chunk = (
+        jax.jit(
+            _make_causal_chunk_prefill(model, cache_len, 16, pooled=False),
+            donate_argnums=(1, 2),
+        )
+        .lower(
+            params, table, i32(slots),
+            kvcache.structs(layout, (1, 16), on_chip), i32(rows, chunk),
+            i32(rows), i32(rows), i32(rows, 256), i32(rows), i32(rows),
+            struct((rows,), jnp.float32), i32(rows),
+        )
+        .compile()
+    )
+    ma = prompt_chunk.memory_analysis()
+    assert ma.alias_size_in_bytes >= held
+    assert ma.temp_size_in_bytes < 400e6, ma.temp_size_in_bytes
+
+
 # -------------------------------------- the MLM head over the masked rows
 
 def test_mlm_head_gathers_its_rows_at_the_cell_shape(one_chip):

@@ -9,6 +9,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import optax
+import pytest
 from jax.sharding import PartitionSpec as P
 
 from distributed_tensorflow_tpu.data import (
@@ -296,3 +297,122 @@ def test_moe_apply_topk2_matches_single_shard(devices8):
     np.testing.assert_allclose(
         np.asarray(y_a2a), np.asarray(y_ref), atol=1e-5
     )
+
+
+# -- moe_dropless: top-k with no capacity, the held experts' part ----------
+
+F = 24  # an expert's FFN width in the dropless cases
+
+
+def _gated_experts(key, e=E):
+    k1, k2 = jax.random.split(key)
+    return {
+        "gate_up": jax.random.normal(k1, (e, H, 2 * F)) * 0.3,
+        "down": jax.random.normal(k2, (e, F, H)) * 0.3,
+    }
+
+
+def _dense_topk(x, logits, experts, k, renormalize=False):
+    """One token at a time: its k largest softmax probabilities, each
+    chosen expert's SiLU-gated FFN weighted by its probability."""
+    x, experts = np.asarray(x, np.float64), jax.tree.map(np.asarray, experts)
+    z = np.exp(logits - logits.max(-1, keepdims=True))
+    probs = z / z.sum(-1, keepdims=True)
+    out = np.zeros_like(x)
+    for t in range(x.shape[0]):
+        chosen = np.argsort(-probs[t], kind="stable")[:k]
+        gates = probs[t, chosen]
+        if renormalize:
+            gates = gates / gates.sum()
+        for e, g in zip(chosen, gates):
+            h = x[t] @ experts["gate_up"][e]
+            a, b = h[:F], h[F:]
+            out[t] += g * ((a / (1 + np.exp(-a)) * b) @ experts["down"][e])
+    return out
+
+
+def test_moe_dropless_is_a_dense_per_token_top_k():
+    from distributed_tensorflow_tpu.parallel.moe import moe_dropless
+
+    rng = np.random.default_rng(11)
+    x = jnp.asarray(rng.normal(size=(40, H)), jnp.float32)
+    logits = rng.normal(size=(40, E)).astype(np.float32)
+    experts = _gated_experts(jax.random.key(3))
+    y, choice = moe_dropless(x, jnp.asarray(logits), experts, 3)
+    np.testing.assert_allclose(np.asarray(y), _dense_topk(x, logits, experts, 3),
+                               atol=1e-5)
+    assert choice.shape == (40, 3)
+
+
+def test_moe_dropless_keeps_rows_over_any_would_be_capacity():
+    """Every token's first choice is expert 5: 48 rows of one expert where a
+    capacity of 1.25 x 48 / 8 = 8 would keep eight. All are computed."""
+    from distributed_tensorflow_tpu.parallel.moe import moe_dropless
+
+    rng = np.random.default_rng(12)
+    x = jnp.asarray(rng.normal(size=(48, H)), jnp.float32)
+    logits = rng.normal(size=(48, E)).astype(np.float32)
+    logits[:, 5] += 8.0
+    experts = _gated_experts(jax.random.key(4))
+    y, choice = moe_dropless(x, jnp.asarray(logits), experts, 2)
+    assert (np.asarray(choice)[:, 0] == 5).all()
+    np.testing.assert_allclose(np.asarray(y), _dense_topk(x, logits, experts, 2),
+                               atol=1e-5)
+    y_capped, _ = moe_apply(
+        lambda p, t: _gated_ffn(p, t), experts, jnp.asarray(logits), x,
+        axis_name=None, capacity_factor=1.25, topk=2,
+    )
+    assert not np.allclose(np.asarray(y_capped),
+                           _dense_topk(x, logits, experts, 2, renormalize=True),
+                           atol=1e-3)
+
+
+def _gated_ffn(p, t):
+    g, u = jnp.split(t @ p["gate_up"], 2, axis=-1)
+    return (jax.nn.silu(g) * u) @ p["down"]
+
+
+def test_moe_dropless_does_not_renormalise_the_gates():
+    """The chosen probabilities as they are (DeepSeek-V2's
+    ``norm_topk_prob: false``), times the scaling factor; renormalised only
+    when asked."""
+    from distributed_tensorflow_tpu.parallel.moe import moe_dropless
+
+    rng = np.random.default_rng(13)
+    x = jnp.asarray(rng.normal(size=(16, H)), jnp.float32)
+    logits = rng.normal(size=(16, E)).astype(np.float32)
+    experts = _gated_experts(jax.random.key(5))
+    plain, _ = moe_dropless(x, jnp.asarray(logits), experts, 3)
+    scaled, _ = moe_dropless(x, jnp.asarray(logits), experts, 3, scale=2.5)
+    norm, _ = moe_dropless(x, jnp.asarray(logits), experts, 3, renormalize=True)
+    np.testing.assert_allclose(np.asarray(scaled), 2.5 * np.asarray(plain),
+                               rtol=1e-5, atol=1e-6)
+    np.testing.assert_allclose(
+        np.asarray(norm), _dense_topk(x, logits, experts, 3, renormalize=True),
+        atol=1e-5)
+    assert not np.allclose(np.asarray(norm), np.asarray(plain), atol=1e-3)
+
+
+@pytest.mark.parametrize("held", [1, 2, 4])
+def test_moe_dropless_shares_sum_to_the_uncut_layer(held):
+    """The ``model-configs`` guide's share test: the partial results of the
+    held ranges (``E / held`` chips of ``held`` experts each), with the
+    shared expert that every chip computes alike counted once, add up to
+    the uncut layer."""
+    from distributed_tensorflow_tpu.parallel.moe import moe_dropless
+
+    rng = np.random.default_rng(14)
+    x = jnp.asarray(rng.normal(size=(32, H)), jnp.float32)
+    logits = jnp.asarray(rng.normal(size=(32, E)), jnp.float32)
+    experts = _gated_experts(jax.random.key(6))
+    shared = jax.tree.map(lambda a: a[0], _gated_experts(jax.random.key(7), 1))
+    whole, _ = moe_dropless(x, logits, experts, 3)
+    whole = whole + _gated_ffn(shared, x)
+    parts = [
+        moe_dropless(x, logits, jax.tree.map(lambda a: a[lo:lo + held], experts),
+                     3, first=lo)[0]
+        for lo in range(0, E, held)
+    ]
+    np.testing.assert_allclose(np.asarray(sum(parts) + _gated_ffn(shared, x)),
+                               np.asarray(whole), atol=1e-5)
+    assert all(float(jnp.abs(p).sum()) > 0 for p in parts)
