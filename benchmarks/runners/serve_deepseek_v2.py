@@ -44,7 +44,7 @@ the reference's two largest logits lie closer than the error a logit carries,
 the served arg-max is the other one, and the gap is their distance.
 ``check.logit_tolerance`` sits between the largest mean the served path
 shows over its seeds on the chip and the least that a wrong computation shows
-(PERF.md section 6, PR 43; ``scripts/deepseek_v2_sabotage.py``).
+(PERF.md, Findings; ``scripts/deepseek_v2_sabotage.py``).
 
 The module imports the model before anything touches the device, so a
 checkout that lacks models/deepseek_v2.py fails at once, with a non-zero exit.
@@ -202,7 +202,7 @@ def run(run: common.Run):
         compiles_total=compiles.total, compiles_in_window=compiles.in_window,
         memory_stats_peak_bytes=window_peak_bytes,
         program_temp_bytes=scratch,
-        # a pause of the machine (PERF.md section 6, PR 28) shows here: every
+        # a pause of the machine (PERF.md, Findings) shows here: every
         # request due while it lasted is submitted late by what was left of it
         lateness_max_ms=1e3 * max(r["t_submit"] - r["due"] for r in records),
         batcher_status=status, check=check, **about_engine, **red,

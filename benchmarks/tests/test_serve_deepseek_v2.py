@@ -8,6 +8,7 @@ wrong computations of scripts/deepseek_v2_sabotage.py must fail."""
 
 import importlib.util
 import json
+import os
 import time
 from pathlib import Path
 
@@ -20,7 +21,9 @@ CELL = "deepseek_v2_lite.docqa_steady"
 WORKLOAD = json.loads((ROOT / "workloads" / f"{CELL}.json").read_text())
 CONFIG = json.loads((ROOT / "configs" / "deepseek_v2_lite.json").read_text())
 BENCH = json.loads((ROOT.parent / "BENCHMARK.json").read_text())
-CATALOG = Path("/opt/skills/guides/model-configs/architectures.jsonl")
+# A JSON-lines catalog of published architectures, one row per model with its
+# ``name``, ``source_url`` and ``config``; the catalog check runs only where it is given.
+CATALOG = Path(os.environ.get("MODEL_CATALOG", ""))
 NEW_METRICS = ("engine.moe_experts_ms", "engine.latent_attention_ms")
 SHARED_METRICS = ("batcher.decode_gap_ms", "engine.decode_device_ms",
                   "device.idle_share.serve", "engine.chunk_per_decode_step_ms")
@@ -60,7 +63,7 @@ def test_cell_names_a_config_a_runner_and_metrics_that_exist():
         assert (m["workloads"], m["moves"], m["unit"]) == ([CELL], "tpot_p95_ms", "ms")
 
 
-def test_traffic_is_the_issue_s():
+def test_traffic_is_the_cell_s():
     t = WORKLOAD["traffic"]
     assert t["prompt_len"] == {"median": 2048, "sigma": 0.5, "min": 512, "max": 4096}
     assert t["output_len"] == {"median": 256, "sigma": 0.5, "min": 64, "max": 512}
@@ -106,7 +109,7 @@ def test_configuration_holds_the_catalog_s_keys_and_cuts_only_the_depth():
     assert (CONFIG["num_hidden_layers"], CONFIG["published"]["num_hidden_layers"]) == (7, 27)
     assert CONFIG["deployment"].startswith("layers 0-6")
     if not CATALOG.is_file():
-        pytest.skip("no catalog beside the model-configs guide here")
+        pytest.skip("MODEL_CATALOG names no catalog file")
     row = next(r for r in map(json.loads, CATALOG.read_text().splitlines())
                if r["name"] == "DeepSeek-V2-Lite")
     assert CONFIG["source"] == row["source_url"]

@@ -1,5 +1,5 @@
 """Does the comparison that decides ``correct`` in the cell
-``deepseek_v2_lite.docqa_steady`` catch a wrong computation? (PERF.md, PR 43.)
+``deepseek_v2_lite.docqa_steady`` catch a wrong computation? (PERF.md, Findings.)
 
 :func:`sabotaged` makes models/deepseek_v2.py compute one thing wrong for as
 long as the ``with`` lasts: the attention's scale without YaRN's ``m^2``,

@@ -123,7 +123,7 @@ def test_yarn_follows_the_published_formulas():
 
 
 def test_published_size_is_15_71_billion_and_the_cut_4_01():
-    """Shapes only (``eval_shape``): the parameter counts ISSUE 43 derives,
+    """Shapes only (``eval_shape``): the published parameter counts,
     what a position caches, and what a live lane routes."""
     def count(**kw):
         model = DeepseekV2(DeepseekV2Config(dtype=jnp.bfloat16, **kw))

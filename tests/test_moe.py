@@ -395,7 +395,7 @@ def test_moe_dropless_does_not_renormalise_the_gates():
 
 @pytest.mark.parametrize("held", [1, 2, 4])
 def test_moe_dropless_shares_sum_to_the_uncut_layer(held):
-    """The ``model-configs`` guide's share test: the partial results of the
+    """The share test of expert-sharded MoE: the partial results of the
     held ranges (``E / held`` chips of ``held`` experts each), with the
     shared expert that every chip computes alike counted once, add up to
     the uncut layer."""
