@@ -108,6 +108,11 @@ def test_configuration_holds_the_catalog_s_keys_and_cuts_only_the_depth():
     assert entry["source"] == CONFIG["source"] == list(CONFIG.values())[1]
     assert (CONFIG["num_hidden_layers"], CONFIG["published"]["num_hidden_layers"]) == (7, 27)
     assert CONFIG["deployment"].startswith("layers 0-6")
+    # the file's own record of the published model: every key but the cut
+    # one is served as published
+    for key, value in CONFIG["published"].items():
+        if key not in CONFIG["reduced"]:
+            assert CONFIG[key] == value, key
     if not CATALOG.is_file():
         pytest.skip("MODEL_CATALOG names no catalog file")
     row = next(r for r in map(json.loads, CATALOG.read_text().splitlines())

@@ -365,12 +365,13 @@ def step_reads(reads, lengths) -> dict[str, int]:
 
 
 def whole_reads(layout, cache_len: int) -> dict[str, int]:
-    """``{group: cache_len}`` of the tables of positions whose decode-step
-    readers take no prefix length: each read passes over every position of
-    every slot (the mask form). What :func:`step_positions` counts from."""
+    """``{group: cache_len}`` of the latent tables (``Leaf.pages`` false),
+    whose decode-step read passes over every position of every slot (the
+    mask form of :func:`latent_attention`). What :func:`step_positions`
+    counts from."""
     return {
         group: cache_len for group, leaves in _by_group(layout).items()
-        if leaves[0].after == POSITIONS and not leaves[0].prefix_readers
+        if leaves[0].after == POSITIONS and not leaves[0].pages
     }
 
 

@@ -8,7 +8,10 @@ position inside the chunk instead of its absolute one, the latent's RMSNorm
 (``kv_a_layernorm``) left out, the top-6 gates renormalised, the shared
 experts left out, the routed experts through ``moe_apply``'s capacity of
 1.25 (rows over it dropped), the latent table in float8_e4m3 (the type below
-the configured bfloat16). The reference is not touched, so a run of the
+the configured bfloat16); and, as the control of the precision, the whole
+served model one precision below its configured bfloat16
+(``precision_below``: every weight rounded to float8_e4m3 and the latent
+table in it). The reference is not touched, so a run of the
 cell's runner inside it must print ``correct: false``
 (benchmarks/tests/test_serve_deepseek_v2.py does that at a toy size on the
 CPU).
@@ -18,7 +21,8 @@ seed, then for the path as it is and for each wrong one a small engine (the
 cell's chunk of 512, eight slots) serves ``--streams`` prompts of 1,800-2,300
 tokens for 256 tokens each through ``serve.Client``, and their streams are
 scored by the runner's ``reference_gaps`` as the cell scores its own. One JSON
-line a variant: ``score_gaps``, to be read against ``check.logit_tolerance``.
+line a variant: ``score_gaps``, to be read against ``check.logit_tolerance``
+(or the variant's ``error``: a variant that fails does not stop the rest).
 
     python scripts/deepseek_v2_sabotage.py [--seed N] [--only served,plain_rope] [--streams 8]
 """
@@ -27,6 +31,7 @@ from __future__ import annotations
 
 import argparse
 import contextlib
+import gc
 import json
 import sys
 import time
@@ -38,12 +43,28 @@ sys.path.insert(0, str(ROOT))
 CELL = "deepseek_v2_lite.docqa_steady"
 VARIANTS = ("served", "sigma_without_m2", "plain_rope", "k_pe_at_chunk_position",
             "kv_a_norm_left_out", "topk_renormalised", "shared_experts_left_out",
-            "capacity_dropping", "latent_fp8")
+            "capacity_dropping", "latent_fp8", "precision_below")
+
+
+def _to_float8(params):
+    """Every floating weight rounded to float8_e4m3fn, kept in its own type,
+    in the place of ``params``: one weight at a time, donated, so the chip
+    holds one copy of the 8 GB and one weight's scratch."""
+    import jax
+    import jax.numpy as jnp
+
+    rounded = jax.jit(lambda x: x.astype(jnp.float8_e4m3fn).astype(x.dtype),
+                      donate_argnums=0)
+    return jax.tree.map(
+        lambda x: rounded(x) if jnp.issubdtype(x.dtype, jnp.floating) else x,
+        params)
 
 
 @contextlib.contextmanager
 def sabotaged(variant: str):
-    """models/deepseek_v2.py with ``variant`` wrong, restored on the way out."""
+    """models/deepseek_v2.py with ``variant`` wrong, restored on the way out.
+    Yields what the served weights become; ``precision_below``'s takes the
+    caller's, who draws them again from the seed for the reference."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -103,16 +124,53 @@ def sabotaged(variant: str):
                                  capacity_factor=1.25, topk=k)
             return y.astype(jnp.float32), jax.lax.top_k(logits, k)[1]
         m.moe_dropless = capped
-    elif variant == "latent_fp8":
+    elif variant in ("latent_fp8", "precision_below"):
         m.DeepseekV2.cache_layout = lambda self, kv_dtype: plain["cache_layout"](
             self, "float8_e4m3fn")
     elif variant != "served":
         raise SystemExit(f"no variant {variant!r}")
     try:
-        yield
+        yield _to_float8 if variant == "precision_below" else (lambda p: p)
     finally:
         for (owner, name), fn in saved.items():
             setattr(owner, name, fn)
+
+
+def _serve(variant, config, params, prompts, tokens):
+    """``[(prompt, emitted tokens)]`` of ``prompts`` served by a small engine
+    (eight slots, the cell's chunk) with ``variant`` wrong. Weights the
+    variant made in the place of ``params`` are deleted on the way out: the
+    chip holds the weights once, and the caller draws them again."""
+    import jax
+
+    from benchmarks.runners import serve_deepseek_v2 as runner
+    from distributed_tensorflow_tpu.models import deepseek_v2
+    from distributed_tensorflow_tpu.serve import CausalLMEngine, Client
+    from distributed_tensorflow_tpu.serve.batcher import BatcherConfig
+
+    serving = config["serving"]
+    with sabotaged(variant) as served:
+        weights = served(params)
+        engine = CausalLMEngine(
+            deepseek_v2.DeepseekV2(runner.model_config(config)), weights,
+            None, buckets=tuple(serving["buckets"]), slots=8, max_batch=1,
+            max_new_tokens=serving["max_new_tokens"],
+            prefill_chunk=serving["prefill_chunk"],
+        )
+        client = Client(engine, BatcherConfig(max_batch=1))
+        try:
+            futures = [
+                client.submit({"input_ids": p, "max_new_tokens": tokens})
+                for p in prompts
+            ]
+            return [(p, f.result(timeout=900)["tokens"])
+                    for p, f in zip(prompts, futures)]
+        finally:
+            client.close()
+            engine.release_cache()
+            if weights is not params:  # rounded in the caller's place: let go
+                for x in jax.tree.leaves(weights):
+                    x.delete()
 
 
 def main(argv=None) -> int:
@@ -135,19 +193,18 @@ def main(argv=None) -> int:
     from benchmarks.runners import serve_deepseek_v2 as runner
     from distributed_tensorflow_tpu.models import deepseek_v2
     from distributed_tensorflow_tpu.runtime import enable_compile_cache
-    from distributed_tensorflow_tpu.serve import CausalLMEngine, Client
-    from distributed_tensorflow_tpu.serve.batcher import BatcherConfig
 
     enable_compile_cache()
     config = json.loads(Path(args.config).read_text())
     check = json.loads(Path(args.workload).read_text())["check"]
     serving = config["serving"]
     model = deepseek_v2.DeepseekV2(runner.model_config(config))
-    params = jax.jit(
+    init = jax.jit(
         lambda key: deepseek_v2.deepseek_v2_init_params(
             model, key, jnp.dtype(config["run"]["weight_dtype"])
         )
-    )(jax.random.key(args.seed))
+    )
+    params = init(jax.random.key(args.seed))
     rng = np.random.default_rng(args.seed)
     chunk = serving["prefill_chunk"]
     # three and a half to four and a half chunks, the last one partial
@@ -158,29 +215,19 @@ def main(argv=None) -> int:
     ]
     scored = min(check["positions"], args.tokens)
     for variant in args.only.split(","):
+        gc.collect()  # the last variant's engine, and its programs
         t0 = time.monotonic()
-        with sabotaged(variant):
-            engine = CausalLMEngine(
-                deepseek_v2.DeepseekV2(runner.model_config(config)), params,
-                None, buckets=tuple(serving["buckets"]), slots=8, max_batch=1,
-                max_new_tokens=serving["max_new_tokens"], prefill_chunk=chunk,
-            )
-            client = Client(engine, BatcherConfig(max_batch=1))
-            try:
-                futures = [
-                    client.submit({"input_ids": p, "max_new_tokens": args.tokens})
-                    for p in prompts
-                ]
-                streams = [
-                    (p, f.result(timeout=900)["tokens"])
-                    for p, f in zip(prompts, futures)
-                ]
-            finally:
-                client.close()
-            engine.release_cache()
-        # against the reference, which no variant touches
-        gaps, routing = runner.reference_gaps(config, model, params, streams,
-                                              scored)
+        try:
+            streams = _serve(variant, config, params, prompts, args.tokens)
+            if variant == "precision_below":  # its weights were rounded in place
+                params = init(jax.random.key(args.seed))
+            # against the reference, which no variant touches
+            gaps, routing = runner.reference_gaps(config, model, params,
+                                                  streams, scored)
+        except Exception as e:  # noqa: BLE001 - reported, and the next one runs
+            print(json.dumps({"variant": variant, "error": repr(e)[:2000],
+                              "seconds": time.monotonic() - t0}), flush=True)
+            continue
         score = runner.score_gaps(gaps)
         print(json.dumps({
             "variant": variant, **score, "tolerance": check["logit_tolerance"],

@@ -5,7 +5,9 @@ The program names its device work with ``jax.named_scope`` (``flash_fwd``,
 ``optimizer`` in the training step; ``kv_write``, ``cached_attention``,
 ``lm_head``, ``sample`` in the decode and prefill cells, and a hybrid model's
 ``ssm_scan``, ``ssm_step``, ``gmu``, ``window_attention``, ``full_attention``,
-or ``delta_chunk``, ``delta_step``, ``short_conv``).
+or ``delta_chunk``, ``delta_step``, ``short_conv``, and DeepSeek-V2's
+``mla_project``, ``latent_attention``, ``mla_chunk_attention``, ``moe_route``,
+``moe_experts``, ``shared_experts``, ``dense_mlp``).
 The TPU profiler
 keeps an op's ``op_name`` in the *metadata* of its events, which
 ``jax.profiler.ProfileData`` does not expose (it gives an event's own stats
@@ -45,7 +47,12 @@ SCOPES = ("flash_fwd", "flash_dq", "flash_dkv", "mlm_head", "grad_reduce", "clip
           "ssm_scan", "ssm_step", "gmu", "window_attention", "full_attention",
           # models/olmo_hybrid.py: the delta rule over a prompt chunk's blocks
           # and one token a slot, and the convolution before both
-          "delta_chunk", "delta_step", "short_conv")
+          "delta_chunk", "delta_step", "short_conv",
+          # models/deepseek_v2.py: latent attention's projections, its absorbed
+          # decode read and a chunk's decompressed read; the router, the routed
+          # experts' grouped matmuls, the shared experts, the dense layer
+          "mla_project", "latent_attention", "mla_chunk_attention", "moe_route",
+          "moe_experts", "shared_experts", "dense_mlp")
 SCOPE_RX = re.compile(r"[/(](" + "|".join(SCOPES) + r")[/)]")
 
 

@@ -144,6 +144,12 @@ def test_published_size_is_15_71_billion_and_the_cut_4_01():
     assert kvcache.whole_reads(layout, 4608) == {"latent": 4608}
     assert kvcache.step_positions({"latent": 4608}, np.array([3, 0, 7])) == {
         "latent_positions_live": 10, "latent_positions_total": 3 * 4608}
+    # K/V tables read whole (split over ``model``, or int8) count no positions
+    from distributed_tensorflow_tpu.models.causal_lm import CausalLMConfig
+
+    split = CausalLMConfig(dtype=jnp.bfloat16, model_axis="model", model_parallel=2)
+    for kv in ("bfloat16", "int8"):
+        assert kvcache.whole_reads(kvcache.cache_layout(split, kv), 4608) == {}
 
 
 @pytest.mark.parametrize("lengths", [(13, 21), (4, 1)])
