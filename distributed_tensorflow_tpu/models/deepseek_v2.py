@@ -25,7 +25,8 @@ Two forms of the same attention:
 - decode, ABSORBED (:meth:`LatentAttention.absorbed`): ``q~_i = W_UK,i^T
   q_i^nope``, so that ``[q~_i | q_i^pe]`` scores the cached row itself, the
   latent context ``sum a_i c`` and then ``o_i = W_UV,i`` of it — one row a
-  position read for all heads (``kvcache.latent_attention``);
+  position read for all heads (``kvcache.latent_attention``: where the
+  table is whole blocks, a slot's blocks below its position, once);
 - prompt chunks, DECOMPRESSED (:meth:`LatentAttention.expanded`): the
   chunk's rows go into the table first, then ``W_kvb`` expands every cached
   row to per-head ``k^nope``, ``v`` for the chunk's queries, which spares
@@ -68,6 +69,7 @@ from flax import linen as nn
 from distributed_tensorflow_tpu.models import kvcache
 from distributed_tensorflow_tpu.models.kvcache import Leaf
 from distributed_tensorflow_tpu.models.olmo_hybrid import RMSNorm, _dense
+from distributed_tensorflow_tpu.ops import decode_attention
 from distributed_tensorflow_tpu.parallel.moe import moe_dropless
 
 _QUERY_BLOCK = 128  # a chunk's queries whose scores are made at a time
@@ -237,11 +239,13 @@ class LatentAttention(nn.Module):
     def _out(self, o):
         return self.o_proj(o.reshape(*o.shape[:-2], -1))
 
-    def absorbed(self, q_nope, q_pe, table, position, row):
-        """Decode: ``q [S, h, ..]`` against one layer's cached rows ``table
-        [S, L, row_width]`` as the step found them and the step's own ``row
-        [S, row_width]`` (encoded), ``W_UK`` folded into the query (its pad
-        lanes zero) and ``W_UV`` applied to the latent context."""
+    def absorbed(self, q_nope, q_pe, table, position, row, layer=None):
+        """Decode: ``q [S, h, ..]`` against the cached rows as the step found
+        them — one layer's ``table [S, L, row_width]``, or with ``layer``
+        that layer of the stacked table, which the read does not copy — and
+        the step's own ``row [S, row_width]`` (encoded), ``W_UK`` folded
+        into the query (its pad lanes zero) and ``W_UV`` applied to the
+        latent context."""
         cfg = self.cfg
         dt = cfg.dtype
         w = self.kv_b.astype(dt)
@@ -251,7 +255,7 @@ class LatentAttention(nn.Module):
         )
         q = _lanes(cfg, jnp.concatenate([q, q_pe], axis=-1)).astype(dt)
         ctx = kvcache.latent_attention(
-            q, table.astype(dt), position, row.astype(dt), softmax_scale(cfg)
+            q, table, position, row, softmax_scale(cfg), layer=layer
         )
         o = jnp.einsum(
             "shc,chd->shd", ctx[..., : cfg.kv_lora_rank].astype(dt),
@@ -446,7 +450,8 @@ class DeepseekV2(nn.Module):
         return {"latent": {"row": Leaf(
             (cfg.row_width,), jnp.dtype(kv_dtype), (None,),
             layers=cfg.num_layers, after=kvcache.POSITIONS, group="latent",
-            pages=False,
+            pages=False, prefix_readers=cfg.num_layers,
+            prefix_block=decode_attention.latent_block_for(cfg.row_width),
         )}}
 
     def decode_counters(self) -> dict[str, int]:
@@ -487,8 +492,7 @@ class DeepseekV2(nn.Module):
             # layers' rows go in once, below
             row = kvcache.encode(latent, {"row": row})
             h = x + layer.attn.absorbed(
-                q_nope, q_pe, kvcache.take_layer(latent, i)["row"], position,
-                row["row"],
+                q_nope, q_pe, latent["row"], position, row["row"], layer=i
             )
             rows.append(row)
             x, _ = layer.ffn(h)

@@ -136,10 +136,11 @@ class Leaf:
     after: int | str | None = POSITIONS
     group: str = "kv"
     # Layers whose decode step reads this leaf up to the slot's length —
-    # :func:`prefix_attention`, or :func:`cached_attention` given the step's
-    # rows — (0: every reader passes over all of it), and the positions such a
-    # read moves at a time where the kernel applies to the model's heads (0:
-    # it does not). What :func:`step_reads` counts from.
+    # :func:`prefix_attention`, :func:`cached_attention` given the step's
+    # rows, or :func:`latent_attention` given the layer — (0: every reader
+    # passes over all of it), and the positions such a read moves at a time
+    # where the kernel applies to the model's heads or rows (0: it does
+    # not). What :func:`step_reads` counts from.
     prefix_readers: int = 0
     prefix_block: int = 0
     # Whether a position of this group is a K/V page the host half (the
@@ -365,10 +366,9 @@ def step_reads(reads, lengths) -> dict[str, int]:
 
 
 def whole_reads(layout, cache_len: int) -> dict[str, int]:
-    """``{group: cache_len}`` of the latent tables (``Leaf.pages`` false),
-    whose decode-step read passes over every position of every slot (the
-    mask form of :func:`latent_attention`). What :func:`step_positions`
-    counts from."""
+    """``{group: cache_len}`` of the latent tables (``Leaf.pages`` false):
+    what :func:`step_positions` counts from, beside the blocks
+    :func:`step_reads` counts of the same tables."""
     return {
         group: cache_len for group, leaves in _by_group(layout).items()
         if leaves[0].after == POSITIONS and not leaves[0].pages
@@ -378,9 +378,11 @@ def whole_reads(layout, cache_len: int) -> dict[str, int]:
 def step_positions(whole, lengths) -> dict[str, int]:
     """Beside :func:`step_reads`, for the groups of :func:`whole_reads`:
     ``<group>_positions_live``, the positions ``lengths [S]`` hold (a lane's
-    position + 1, 0 for an idle lane), and ``<group>_positions_total``, what
-    the step passes over. Their ratio is the share a read that stopped at
-    each slot's length would touch."""
+    position + 1, 0 for an idle lane), and ``<group>_positions_total``, the
+    positions of every slot. Their ratio is the share of the table the
+    step's attention needs; ``<group>_blocks_read`` over ``_blocks_total``
+    is the share :func:`latent_attention` moved, which the tail of each
+    slot's last block and the mask form (every block) put above it."""
     out = {}
     for group, cache_len in whole.items():
         out[f"{group}_positions_live"] = int(np.sum(lengths))
@@ -648,20 +650,38 @@ def cached_attention(
         return _attend(q, cache, position, "sch,slc->shl", "shl,slc->shc")
 
 
-def latent_attention(q, table, position, row, scale: float):
+def latent_attention(
+    q, table, position, row, scale: float, layer: int | None = None
+):
     """One token per slot, every head over ONE cached row a position (the
     absorbed form of multi-head latent attention, models/deepseek_v2.py):
     ``q [S, h, r]`` the heads' queries in the row's own coordinates, ``table
-    [S, L, r]`` one layer's rows as the step found them, ``row [S, r]`` the
-    step's own, encoded and not in the table yet, seen at ``position [S]``
-    (``L`` or more on an idle lane: nothing is selected in, and what it
-    reads nobody uses). Scores ``scale * q . row`` over positions ``<=
+    [S, L, r]`` one layer's rows as the step found them — or with ``layer``
+    that layer of the stacked ``[nl, S, L, r]`` —, ``row [S, r]`` the step's
+    own, encoded and not in the table yet, seen at ``position [S]`` (``L``
+    or more on an idle lane: nothing is selected in, and what it reads
+    nobody uses). Scores ``scale * q . row`` over positions ``<=
     position``, float32 softmax, and the context over the whole row ``[S,
-    h, r]`` float32: the caller keeps the lanes that are values. The mask
-    form: the select fuses into both contractions' read, a pass over every
-    position of every slot."""
+    h, r]`` float32: the caller keeps the lanes that are values. Where the
+    stacked table admits it — whole blocks, a row of whole lane tiles
+    (``decode_attention.latent_block_for``) — ops/decode_attention.py reads
+    each slot's blocks below ``position`` where they lie, once, and takes
+    the row as an operand, in the table's dtype; any other table takes the
+    mask form, in ``q``'s: the select fuses into both contractions' read, a
+    pass over every position of every slot."""
     with jax.named_scope("latent_attention"):
-        table = select_rows(table, row, position, slot_axis=0)
+        if layer is not None and _kernel_block(
+            decode_attention.latent_block_for(table.shape[-1]),
+            table.shape[2],
+        ):
+            return decode_attention.latent_row_attention(
+                q, table, position, row, layer=layer, scale=scale
+            )
+        if layer is not None:
+            table = take_layer(table, layer)
+        table = select_rows(
+            table.astype(q.dtype), row.astype(q.dtype), position, slot_axis=0
+        )
         position = jnp.minimum(position, table.shape[1] - 1)
         s = jnp.einsum(
             "shr,slr->shl", q, table, preferred_element_type=jnp.float32

@@ -7,7 +7,7 @@ layer of the stacked ``[layers, S, L, c]``), never reshaped, sliced per head
 or copied. The XLA forms contract over the whole ``[S, L, c]`` rectangle and
 mask, so an idle slot and a short sequence cost what a full one does; here a
 slot moves ``ceil(length / block)`` blocks a side and an idle one nothing, and
-zeros come back for it. Two forms over one body (:func:`_over_live_blocks`):
+zeros come back for it. Three forms over one body (:func:`_over_live_blocks`):
 
 - :func:`table_attention`: ``kvcache.paired_attention`` for a prefix mask —
   grouped-query differential attention over a table that is written before it
@@ -18,7 +18,12 @@ zeros come back for it. Two forms over one body (:func:`_over_live_blocks`):
   row yet, given ``position [S]`` and the row: slot ``s`` sees the table's
   ``[0, position[s])`` and the row, which starts the online softmax (its
   score the first maximum, its weight 1, its values the context so far). A
-  select on its way into a custom call would copy the table.
+  select on its way into a custom call would copy the table;
+- :func:`latent_row_attention`: ``kvcache.latent_attention`` — the new-row
+  form over ONE table whose row is every head's key and value (multi-head
+  latent attention, absorbed): a block is one copy, and all heads are one
+  group that reads the whole row, one dot for the scores and one for the
+  context.
 
 Structure (the paged-attention pattern, without the pages):
 
@@ -36,7 +41,8 @@ Structure (the paged-attention pattern, without the pages):
   lane-tile slice of the block in VMEM, which costs nothing. The group's
   query is block-diagonal over that window only (built outside, ``[S, groups,
   8, window]``), so a K/V lane meets the heads that read it and the zeros of
-  a tile it is loaded into the MXU with anyway;
+  a tile it is loaded into the MXU with anyway. The latent form's heads all
+  read the whole row: one group of every head, one window, no zeros;
 - scores, the running maximum and sum and the context stay in VMEM, float32;
   the table's dtype goes into the MXU and float32 comes out, as in the mask
   forms. In the paired form both softmaxes are normalised each on its own
@@ -65,6 +71,14 @@ _GROUP = 8  # query heads a group: one float32 sublane tile of scores
 # PERF.md, PR 36: 32 / 64 / 128 / 256 / 512 read 0.93 / 0.58 / 0.49 / 0.53 /
 # 0.63 ms a reader at a quarter of the table live, 128 and up alike when full).
 BLOCK = 128
+# Positions a latent read moves at a time (:func:`latent_row_attention`). A
+# block passes through the MXU twice for 16 heads' rows, so a turn of the
+# loop costs about what its copy does, plus a fixed ~0.35 us: swept on a TPU
+# v5e at the DeepSeek-V2-Lite cell's geometry (scripts/table_attention_bench.py
+# --cell docqa; 128 x 4,608 x 640 lanes; PERF.md's Findings): 128 / 256 / 512
+# read 0.353 / 0.268 / 0.233 ms a layer with 24 slots live (the mask form
+# 2.155) and 2.421 / 1.611 / 1.236 with every slot full (2.143).
+LATENT_BLOCK = 512
 
 
 def window_lanes(n_q: int, d: int, lanes: int) -> int | None:
@@ -105,6 +119,13 @@ def block_for(n_q: int, d: int, lanes: int, paired: bool = True) -> int:
     return BLOCK if admit(n_q, d, lanes) else 0
 
 
+def latent_block_for(lanes: int) -> int:
+    """Positions a latent read of rows of ``lanes`` moves at a time, 0 where
+    :func:`latent_row_attention` does not apply: the row has to be whole
+    lane tiles."""
+    return LATENT_BLOCK if lanes > 0 and lanes % 128 == 0 else 0
+
+
 def _grouped(q, window: int, kv_of):
     """``q [S, h, d]`` as ``[S, ceil(h / 8), 8, window]``: row ``r`` of a
     group on the lanes of the window's K/V head ``kv_of[r]``, zeros elsewhere
@@ -129,16 +150,16 @@ def _grouped_query(q, window: int):
     return _grouped(q, window, 2 * (head // rows_a_pair) + head % 2)
 
 
-def _piece_of_row(keep: int, window: int):
-    """``[8, keep]``: which ``keep``-lane piece of its group's window a row's
-    head keeps — ``8 * keep / window`` consecutive rows a piece."""
-    row = jax.lax.broadcasted_iota(jnp.int32, (_GROUP, keep), 0)
-    return row // (_GROUP * keep // window)
+def _piece_of_row(group: int, keep: int, window: int):
+    """``[group, keep]``: which ``keep``-lane piece of its group's window a
+    row's head keeps — ``group * keep / window`` consecutive rows a piece."""
+    row = jax.lax.broadcasted_iota(jnp.int32, (group, keep), 0)
+    return row // (group * keep // window)
 
 
 def _own_piece(of, width: int, piece_of_row):
     """Each row's own piece of a group's window: ``of(lo, hi)`` gives the
-    lanes ``[lo, hi)`` of what the group's heads share, ``[8, ..]`` or
+    lanes ``[lo, hi)`` of what the group's heads share, ``[group, ..]`` or
     ``[1, ..]``, and a row keeps the piece its head's values lie in."""
     keep = piece_of_row.shape[1]
     mine = of(0, keep)
@@ -155,27 +176,32 @@ def _over_live_blocks(
     layer,
 ):
     """The online softmax ``(m, l, acc)`` of slot ``s`` over the table's
-    first ``lengths_ref[s]`` positions, from ``carry`` on: what both kernels
-    share. ``windows`` are the groups' ``(first lane, lanes)`` — ``window``
+    first ``lengths_ref[s]`` positions, from ``carry`` on: what every kernel
+    shares. ``windows`` are the groups' ``(first lane, lanes)`` — ``window``
     lanes each but a short last one — ``keep`` the lanes of a group's context
     a head keeps, ``layer`` the index into a
     stacked ``[layers, S, L, c]`` table or ``None`` for ``[S, L, c]``. A
-    length of 0 moves nothing and returns ``carry``."""
+    group is the query block's rows (``q_ref [1, groups, rows, window]``).
+    ``v_hbm`` ``None``: one table is the keys and the values, a copy a block.
+    A length of 0 moves nothing and returns ``carry``."""
     n_slots = pl.num_programs(0)
     length = lengths_ref[s]
     n_blocks = pl.cdiv(length, block)
     lead = () if layer is None else (layer,)
-    piece_of_row = _piece_of_row(keep, window)
+    group = q_ref.shape[2]
+    piece_of_row = _piece_of_row(group, keep, window)
+    sides = ((k_hbm, k_buf),) if v_hbm is None else (
+        (k_hbm, k_buf), (v_hbm, v_buf)
+    )
+    values = k_buf if v_buf is None else v_buf
 
     def fetch(slot, i, buf):
         rows = pl.ds(pl.multiple_of(i * block, block), block)
-        return (
+        return tuple(
             pltpu.make_async_copy(
-                k_hbm.at[(*lead, slot, rows)], k_buf.at[buf], sems.at[0, buf]
-            ),
-            pltpu.make_async_copy(
-                v_hbm.at[(*lead, slot, rows)], v_buf.at[buf], sems.at[1, buf]
-            ),
+                hbm.at[(*lead, slot, rows)], vmem.at[buf], sems.at[side, buf]
+            )
+            for side, (hbm, vmem) in enumerate(sides)
         )
 
     base = fetched_ref[0]  # blocks waited for so far: its parity, the buffer
@@ -206,7 +232,7 @@ def _over_live_blocks(
             for copy in fetch(following, 0, 1 - buf):
                 copy.start()
 
-        k_copy, v_copy = fetch(s, i, buf)
+        k_copy, *v_copy = fetch(s, i, buf)
         k_copy.wait()
         scores = jnp.concatenate(
             [
@@ -226,14 +252,15 @@ def _over_live_blocks(
         alpha = jnp.exp(m - m_new)
         e = jnp.exp(scores - m_new)  # exactly 0 past the length
         l = alpha * l + jnp.sum(e, axis=1, keepdims=True)
-        e = e.astype(v_buf.dtype)
-        v_copy.wait()
+        e = e.astype(values.dtype)
+        for copy in v_copy:
+            copy.wait()
         fresh = []
         for g, (lo, width) in enumerate(windows):
             ctx = jnp.dot(
-                e[g * _GROUP:(g + 1) * _GROUP], v_buf[buf, :, lo:lo + width],
+                e[g * group:(g + 1) * group], values[buf, :, lo:lo + width],
                 preferred_element_type=jnp.float32,
-            )  # [8, width]: each head keeps its own lanes of it
+            )  # [group, width]: each head keeps its own lanes of it
             fresh.append(_own_piece(
                 lambda a, b, ctx=ctx: ctx[:, a:b], width, piece_of_row
             ))
@@ -298,7 +325,9 @@ def _row_kernel(
         # the softmax starts at the step's own row, which no table holds yet:
         # its score is the running maximum, its weight 1, its values the
         # context so far
-        piece_of_row = _piece_of_row(geometry["keep"], geometry["window"])
+        piece_of_row = _piece_of_row(
+            q_ref.shape[2], geometry["keep"], geometry["window"]
+        )
         first, values = [], []
         for g, (lo, width) in enumerate(windows):
             first.append(jnp.sum(
@@ -322,13 +351,13 @@ def _row_kernel(
         o_ref[0] = acc / l
 
 
-def _scratch(block: int, k, v):
-    """Two blocks a side, their semaphores, and the count of blocks waited
-    for that tells the buffers apart across grid steps."""
+def _scratch(block: int, *tables):
+    """Two blocks a table (the keys and the values, or one latent table),
+    their semaphores, and the count of blocks waited for that tells the
+    buffers apart across grid steps."""
     return [
-        pltpu.VMEM((2, block, k.shape[-1]), k.dtype),
-        pltpu.VMEM((2, block, v.shape[-1]), v.dtype),
-        pltpu.SemaphoreType.DMA((2, 2)),
+        *(pltpu.VMEM((2, block, t.shape[-1]), t.dtype) for t in tables),
+        pltpu.SemaphoreType.DMA((len(tables), 2)),
         pltpu.SMEM((1,), jnp.int32),
     ]
 
@@ -482,3 +511,91 @@ def _row_call(q, k, v, position, k_new, v_new, layer, *, block, interpret):
     out = out[:, :n_q].reshape(n_slots, n_q, keep // d, d)
     head = np.arange(n_q)
     return out[:, head, head % (keep // d)]
+
+
+def _latent_kernel(
+    lengths_ref, live_ref, layer_ref, q_ref, row_ref, table_hbm, o_ref, buf,
+    sems, fetched_ref, **geometry,
+):
+    # the new-row kernel with the row as both the step's key and its value,
+    # and the one table as both sides
+    _row_kernel(
+        lengths_ref, live_ref, layer_ref, q_ref, row_ref, row_ref, table_hbm,
+        None, o_ref, buf, None, sems, fetched_ref, **geometry,
+    )
+
+
+def latent_row_attention(
+    q, table, position, row, *, layer: int, scale: float,
+    block: int = LATENT_BLOCK, interpret: bool | None = None,
+):
+    """Every head over ONE cached row a position, of one token a slot —
+    ``kvcache.latent_attention``'s mask form without its two passes over
+    every position: ``q [S, h, r]`` the heads' queries in the row's own
+    coordinates, ``table`` the STACKED rows ``[layers, S, L, r]`` of which
+    layer ``layer`` is read (slicing it out first copies it), ``position
+    [S]`` the index the token sits at (``>= L``: an idle lane, zeros come
+    back), ``row [S, r]`` the step's own row as the table will store it.
+    Slot ``s`` attends the table's ``[0, position[s])`` block by block and
+    its row from the operand, scores ``scale * q . row``; what the table
+    holds at ``position[s]`` and past it is masked. The row is each head's
+    key and its value: returns the context over the whole row, ``[S, h, r]``
+    float32. ``L`` is a multiple of ``block`` and ``r`` whole lane tiles."""
+    if interpret is None:
+        interpret = _use_interpret()
+    lanes = q.shape[-1]
+    cache_len = table.shape[2]
+    if not latent_block_for(lanes) or table.shape[-1] != lanes \
+            or cache_len % block:
+        raise ValueError(
+            f"latent_row_attention does not apply to rows of {lanes} lanes "
+            f"over a table of {table.shape[-1]} at {cache_len} positions in "
+            f"blocks of {block}"
+        )
+    return _latent_call(
+        q, table, position, row, jnp.full((1,), layer, jnp.int32),
+        scale=scale, block=block, interpret=interpret,
+    )
+
+
+@functools.partial(
+    jax.jit, static_argnames=("scale", "block", "interpret")
+)
+def _latent_call(q, table, position, row, layer, *, scale, block, interpret):
+    n_slots, n_q, lanes = q.shape
+    live = position < table.shape[2]
+    spec = lambda *shape: pl.BlockSpec(  # noqa: E731
+        (1, *shape), lambda s, *_: (s, *(0,) * len(shape))
+    )
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,
+        grid=(n_slots,),
+        in_specs=[
+            spec(1, n_q, lanes), spec(1, lanes),
+            pl.BlockSpec(memory_space=pl.ANY),
+        ],
+        out_specs=spec(n_q, lanes),
+        scratch_shapes=_scratch(block, table),
+    )
+    # named for benchmarks/layer_metrics/engine.latent_kernel_ms
+    with jax.named_scope("latent_row_attention"):
+        return pl.pallas_call(
+            functools.partial(
+                # every head one group, reading one window: the whole row
+                _latent_kernel, block=block, scale=scale, keep=lanes,
+                window=lanes, windows=((0, lanes),),
+            ),
+            out_shape=jax.ShapeDtypeStruct(
+                (n_slots, n_q, lanes), jnp.float32
+            ),
+            grid_spec=grid_spec,
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("arbitrary",)
+            ),
+            interpret=interpret,
+        )(
+            jnp.where(live, position, 0).astype(jnp.int32),
+            live.astype(jnp.int32), layer,
+            q[:, None].astype(table.dtype), row[:, None].astype(table.dtype),
+            table,
+        )

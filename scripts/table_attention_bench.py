@@ -1,8 +1,8 @@
 """Times the length-aware decode-attention kernels (ops/decode_attention.py)
 against the mask forms they replace (models/kvcache.py), on the chip:
 
-    python scripts/table_attention_bench.py [--cell reasoning|longdoc|chat]
-        [--blocks 64,128,256] [--seed N]
+    python scripts/table_attention_bench.py
+        [--cell reasoning|longdoc|chat|docqa] [--blocks 64,128,256] [--seed N]
 
 ``reasoning``: ``table_attention`` against ``paired_attention`` at the
 reasoning cell's geometry — 128 slots x 1,536 positions x 1,280 lanes in
@@ -13,7 +13,11 @@ slot idle. ``longdoc`` and ``chat``: ``row_attention`` against
 ``_attend(select_rows(..))`` over a stacked table, a reader a layer — four
 layers of 16 x 4,608 x 3,840 (30 heads of 128; a fifth idle, the rest a
 prompt of 512-4,096 plus a share of an answer of 128-512) and twelve of 128 x
-384 x 768 (12 heads of 64; ten slots live). Prints one JSON line a case: ms
+384 x 768 (12 heads of 64; ten slots live). ``docqa``:
+``latent_row_attention`` against ``latent_attention``'s mask form over the
+stacked latent table, a reader a layer — seven layers of 128 x 4,608 x 640
+(16 heads; 24 slots live, each a prompt of 512-4,096 plus a share of an
+answer of 64-512). Prints one JSON line a case: ms
 a call (median of 20 after a warm-up), the blocks moved and GB/s over them.
 Refuses to run off the TPU: a time from the interpreter is not a time.
 """
@@ -34,6 +38,7 @@ import numpy as np  # noqa: E402
 
 from distributed_tensorflow_tpu.models import kvcache  # noqa: E402
 from distributed_tensorflow_tpu.ops.decode_attention import (  # noqa: E402
+    latent_row_attention,
     row_attention,
     table_attention,
 )
@@ -41,6 +46,8 @@ from distributed_tensorflow_tpu.ops.decode_attention import (  # noqa: E402
 S, L, C, N_Q, D = 128, 1536, 1280, 40, 64
 # the new-row form's cells: layers, slots, positions, heads, head size
 ROW_CELLS = {"longdoc": (4, 16, 4608, 30, 128), "chat": (12, 128, 384, 12, 64)}
+# the latent form's: layers, slots, positions, heads, row lanes, live slots
+LATENT_CELL = (7, 128, 4608, 16, 640, 24)
 
 
 def _time(fn, *args, reps=20):
@@ -53,17 +60,19 @@ def _time(fn, *args, reps=20):
     return float(np.median(times)) * 1e3
 
 
-def _report(cases, forms, args, whole: int, row_bytes: int, readers: int):
+def _report(cases, forms, args, whole: int, row_bytes: int, readers: int,
+            sides: int = 2):
     """One line a case and form. ``cases``: name -> (what the read is given
     a slot, the table positions it has to move a slot); ``forms``: the jitted
-    chains of ``readers`` reads, the mask form first."""
+    chains of ``readers`` reads, the mask form first; ``sides``: tables a
+    block is read from (K and V, or one latent table)."""
     want = None
     for name, (given, held) in cases.items():
         n = jnp.asarray(given, jnp.int32)
         for form, fn in forms.items():
             ms = _time(fn, *args, n) / readers
             block = int(form[6:]) if form != "mask" else whole
-            moved = int(np.sum(-(-held // block))) * block * row_bytes * 2
+            moved = int(np.sum(-(-held // block))) * block * row_bytes * sides
             out = np.asarray(fn(*args, n), np.float32)[held > 0]
             if form == "mask":
                 want = out
@@ -131,10 +140,55 @@ def _row_cell(cell: str, blocks, seed: int) -> None:
     _report(cases, forms, (q, k, v, rows), l, n_q * d * 2, layers)
 
 
+def _latent_cell(blocks, seed: int) -> None:
+    """``latent_row_attention`` a layer against the mask form of
+    ``latent_attention`` over that layer."""
+    layers, s, l, n_q, r, live = LATENT_CELL
+    rng = np.random.default_rng(seed)
+    kq, kt, kr = jax.random.split(jax.random.PRNGKey(seed), 3)
+    q = jax.random.normal(kq, (s, n_q, r), jnp.bfloat16)
+    table = jax.random.normal(kt, (layers, s, l, r), jnp.bfloat16)
+    row = jax.random.normal(kr, (s, r), jnp.bfloat16)
+    scale = 0.114721  # DeepSeek-V2-Lite's softmax scale
+    mixed = np.full(s, l)
+    mixed[rng.choice(s, live, replace=False)] = np.minimum(
+        rng.integers(512, 4097, live)
+        + (rng.random(live) * rng.integers(64, 513, live)).astype(int), l - 1
+    )
+    cases = {
+        name: (at, np.where(at < l, at, 0))
+        for name, at in {
+            "mixed": mixed, "full": np.full(s, l - 1), "idle": np.full(s, l),
+        }.items()
+    }
+
+    def chain(read):
+        def run(q, table, row, position):
+            out = jnp.zeros((s, n_q, r), jnp.float32)
+            for layer in range(layers):
+                qi = q + out.astype(q.dtype) * 1e-3
+                out = read(qi, table, row, position, layer)
+            return out
+        return jax.jit(run)
+
+    forms = {"mask": chain(
+        lambda q, table, row, at, layer: kvcache.latent_attention(
+            q, table[layer], at, row, scale
+        )
+    )}
+    for block in blocks:
+        forms[f"kernel{block}"] = chain(
+            lambda q, table, row, at, layer, block=block: latent_row_attention(
+                q, table, at, row, layer=layer, scale=scale, block=block
+            )
+        )
+    _report(cases, forms, (q, table, row), l, r * 2, layers, sides=1)
+
+
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--cell", default="reasoning",
-                    choices=["reasoning", *ROW_CELLS])
+                    choices=["reasoning", *ROW_CELLS, "docqa"])
     ap.add_argument("--blocks", default="64,128,256")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
@@ -142,6 +196,9 @@ def main(argv=None) -> int:
         sys.exit("table_attention_bench measures the chip; this is "
                  f"{jax.default_backend()}")
     blocks = [int(b) for b in args.blocks.split(",")]
+    if args.cell == "docqa":
+        _latent_cell(blocks, args.seed)
+        return 0
     if args.cell != "reasoning":
         _row_cell(args.cell, blocks, args.seed)
         return 0

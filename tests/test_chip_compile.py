@@ -507,15 +507,16 @@ def test_delta_rule_hybrid_step_and_chunk_fit_the_chip(one_chip, monkeypatch):
 
 # ---------- a latent row a position and 64 routed experts, a step and a chunk
 
-def test_latent_attention_step_and_chunk_fit_the_chip(one_chip):
+def test_latent_attention_step_and_chunk_fit_the_chip(one_chip, monkeypatch):
     """benchmarks/workloads/deepseek_v2_lite.docqa_steady: DeepSeek-V2-Lite's
     first 7 layers at the published widths (4.01 B parameters in bf16), 128
     slots, cache 4,608. The decode step aliases the donated latent table —
     7 layers of one row a position, 576 lanes held as 640, 5.28 GB — and
-    makes nothing of its size: each layer's absorbed read fuses the step's
-    row into its two passes over the layer, and the rows go in as rows of
-    the flat table (a 576-lane row could not: the compiler copies the whole
-    table to write one, models/deepseek_v2.py::row_width). The routed
+    makes nothing of its size: each layer's absorbed read is the latent
+    kernel of ops/decode_attention.py, a custom call whose operands are the
+    stacked table where it lies and the step's row, and the rows go in as
+    rows of the flat table (a 576-lane row could not: the compiler copies
+    the whole table to write one, models/deepseek_v2.py::row_width). The routed
     experts are grouped matmuls whose FLOPs are the rows' own, not every row
     through every expert. The chunk program (one row of 512) reserves a
     quarter of a gigabyte; two rows reserve 3 GB, past what the chip has
@@ -530,11 +531,15 @@ def test_latent_attention_step_and_chunk_fit_the_chip(one_chip):
         DeepseekV2,
         deepseek_v2_init_params,
     )
+    from distributed_tensorflow_tpu.ops import decode_attention
     from distributed_tensorflow_tpu.serve.engine import (
         _make_causal_chunk_prefill,
         _make_causal_decode,
     )
 
+    # held to the CPU the kernel would be interpreted: compile it as the
+    # chip would
+    monkeypatch.setattr(decode_attention, "_use_interpret", lambda: False)
     config = json.loads((
         Path(__file__).resolve().parents[1]
         / "benchmarks/configs/deepseek_v2_lite.json"
@@ -571,6 +576,13 @@ def test_latent_attention_step_and_chunk_fit_the_chip(one_chip):
     assert set(made) <= {
         "parameter", "get-tuple-element", "tuple", "bitcast",
     }, made
+    # the seven layers' reads, each over the stacked table whole
+    kernels = re.findall(
+        r"^\s*%%(latent_row_attention[.\d]*) = f32\[%d,16,640\]\S* "
+        r"custom-call\((?=.*bf16\[7,%d,%d,640\])" % (slots, slots, cache_len),
+        step.as_text(), re.M,
+    )
+    assert len(kernels) == 7, kernels
     # 6 MoE layers x 768 rows x (2,048 x 2,816 + 1,408 x 2,048) x 2: the
     # rows' own experts; every row through all 64 would be 64 times that
     routed = 6 * 768 * 3 * 2048 * 1408 * 2

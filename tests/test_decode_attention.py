@@ -1,9 +1,10 @@
 """ops/decode_attention.py, interpreted on the CPU, against the plain
 statements it replaces: for a prefix ``kvcache.paired_attention`` with the
-mask ``arange(L) < lengths``, and for plain heads over a table that lacks the
+mask ``arange(L) < lengths``, for plain heads over a table that lacks the
 step's own row ``kvcache._attend`` over ``select_rows(table, rows,
-position)``. Times and the compile for the chip are elsewhere
-(scripts/table_attention_bench.py, tests/test_chip_compile.py)."""
+position)``, and for one latent row a position the mask form of
+``kvcache.latent_attention``. Times and the compile for the chip are
+elsewhere (scripts/table_attention_bench.py, tests/test_chip_compile.py)."""
 
 from __future__ import annotations
 
@@ -14,6 +15,7 @@ import pytest
 from distributed_tensorflow_tpu.models import kvcache
 from distributed_tensorflow_tpu.ops import decode_attention
 from distributed_tensorflow_tpu.ops.decode_attention import (
+    latent_row_attention,
     row_attention,
     table_attention,
 )
@@ -79,7 +81,9 @@ def test_the_kernel_applies_to_whole_groups_over_whole_lane_tiles(
     )
 
 
-@pytest.mark.parametrize("form", ["paired", "new_row"])
+@pytest.mark.parametrize(
+    "form", ["paired", "new_row", "latent", "latent_part_tiles"]
+)
 def test_a_table_of_part_blocks_is_refused_by_the_kernel_itself(form):
     n = jnp.zeros((2,), jnp.int32)
     with pytest.raises(ValueError, match="does not apply"):
@@ -87,10 +91,18 @@ def test_a_table_of_part_blocks_is_refused_by_the_kernel_itself(form):
             table = jnp.zeros((2, 48, 256))
             table_attention(jnp.zeros((2, 8, 64)), table, table, n, 0.5,
                             block=32)
-        else:
+        elif form == "new_row":
             table, row = jnp.zeros((1, 2, 48, 512)), jnp.zeros((2, 512))
             row_attention(jnp.zeros((2, 8, 64)), table, table, n, row, row,
                           layer=0, block=32)
+        else:
+            # part blocks, or whole blocks of a row that ends mid-tile
+            length, lanes = (48, 640) if form == "latent" else (64, 576)
+            table = jnp.zeros((1, 2, length, lanes))
+            latent_row_attention(
+                jnp.zeros((2, 16, lanes)), table, n, jnp.zeros((2, lanes)),
+                layer=0, scale=0.1, block=32,
+            )
 
 
 # ---------------- plain heads, the step's own row an operand of the kernel
@@ -238,3 +250,62 @@ def test_cached_attention_takes_the_kernel_where_the_table_admits_it(
     else:
         assert not calls
         np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+# ------------- one latent row a position: every head's key and its value
+
+# the DeepSeek-V2-Lite cell's 16 heads over its row of 576 lanes held as 640
+_LATENT_HEADS, _LATENT_LANES = 16, 640
+_LATENT_POSITIONS = {
+    # an idle lane, a first token, both sides of the first block's last row,
+    # the next block's first, the table's last, two mid-block
+    "ragged": [_ROW_LEN, 0, 126, 127, 128, _ROW_LEN - 1, 200, 61],
+    "first_block": [1, 64, 127],
+    "all_idle": [_ROW_LEN, _ROW_LEN + 3],
+}
+
+
+@pytest.mark.parametrize(
+    "lanes, block",
+    [(640, decode_attention.LATENT_BLOCK), (128, decode_attention.LATENT_BLOCK),
+     (576, 0), (48, 0), (0, 0)],
+)
+def test_the_latent_form_applies_to_rows_of_whole_lane_tiles(lanes, block):
+    assert decode_attention.latent_block_for(lanes) == block
+
+
+@pytest.mark.parametrize("positions", sorted(_LATENT_POSITIONS))
+@pytest.mark.parametrize("dtype", sorted(_TOLERANCE))
+def test_latent_row_attention_is_the_mask_form_of_latent_attention(
+    dtype, positions
+):
+    """Layer 1 of a stacked table of two layers x 256 positions. The mask
+    form reads a CLEAN layer; the kernel one whose every row at and past a
+    slot's position holds a prior occupant's large values (the stale row
+    too), and whose other layer is NaN: neither shows. Idle lanes come back
+    as zeros."""
+    position = np.asarray(_LATENT_POSITIONS[positions])
+    slots, lanes = len(position), _LATENT_LANES
+    scale = lanes ** -0.5
+    rng = np.random.default_rng(len(position))
+    q = jnp.asarray(rng.normal(size=(slots, _LATENT_HEADS, lanes)), dtype)
+    table = jnp.asarray(rng.normal(size=(slots, _ROW_LEN, lanes)), dtype)
+    row = jnp.asarray(rng.normal(size=(slots, lanes)), dtype)
+    want = np.asarray(kvcache.latent_attention(
+        q, table, jnp.asarray(position), row, scale
+    ))
+    stale = (np.arange(_ROW_LEN) >= position[:, None])[..., None]
+    stacked = jnp.stack(
+        [jnp.full_like(table, jnp.nan), jnp.where(stale, 3e4, table)]
+    )
+    got = np.asarray(latent_row_attention(
+        q, stacked, jnp.asarray(position, jnp.int32), row, layer=1,
+        scale=scale, block=_ROW_BLOCK,
+    ))
+    assert got.shape == (slots, _LATENT_HEADS, lanes)
+    assert got.dtype == np.float32 and np.isfinite(got).all()
+    live = position < _ROW_LEN
+    np.testing.assert_allclose(got[live], want[live], atol=_TOLERANCE[dtype])
+    assert not got[~live].any()
+    if live.any():
+        assert np.abs(want[live]).max() > 0.5

@@ -34,6 +34,7 @@ from distributed_tensorflow_tpu.models.deepseek_v2 import (
     yarn_range,
 )
 from distributed_tensorflow_tpu.obs.metrics import ServeMetrics
+from distributed_tensorflow_tpu.ops import decode_attention
 from distributed_tensorflow_tpu.obs.trace import Tracer
 from distributed_tensorflow_tpu.parallel.mesh import build_mesh
 from distributed_tensorflow_tpu.serve import (
@@ -193,6 +194,109 @@ def test_absorbed_decode_is_the_decompressed_form(tiny):
                                atol=1e-5)
 
 
+def test_a_step_counts_the_latent_blocks_it_reads():
+    """At the cell's size (7 layers, rows of 640 lanes, 4,608 positions) a
+    step's read moves each live lane's blocks below its position and the
+    block that holds it, once a layer (one table is both sides); a table of
+    part blocks takes the mask form, every position of every slot."""
+    block = decode_attention.LATENT_BLOCK
+    layout = DeepseekV2(DeepseekV2Config(num_layers=7)).cache_layout(
+        "bfloat16"
+    )
+    assert kvcache.prefix_reads(layout, 4608) == {
+        "latent": (block, 7, 4608 // block)}
+    assert kvcache.prefix_reads(layout, 4600) == {"latent": (4600, 7, 1)}
+    seen = np.array([3, 0, block + 1, 4608])  # position + 1; 0 is idle
+    assert kvcache.step_reads({"latent": (block, 7, 4608 // block)}, seen) == {
+        "latent_blocks_read": 7 * (1 + 0 + 2 + 4608 // block),
+        "latent_blocks_total": 7 * 4 * (4608 // block),
+    }
+    # the positions counters stay beside them
+    assert kvcache.whole_reads(layout, 4608) == {"latent": 4608}
+
+
+@pytest.mark.parametrize(
+    "case", ["kernel", "part_blocks", "part_tiles", "one_layer"]
+)
+def test_latent_attention_takes_the_kernel_where_the_table_admits_it(
+    case, monkeypatch
+):
+    """``kvcache.latent_attention`` given the layer of the stacked table:
+    the kernel for whole blocks of rows of whole lane tiles, equal to the
+    mask form; anything else the mask form, bit for bit."""
+    cache_len = 100 if case == "part_blocks" else 2 * decode_attention.LATENT_BLOCK
+    lanes = 48 if case == "part_tiles" else 128
+    slots, heads, layer, scale = 3, 4, 1, 0.125
+    rng = np.random.default_rng(11)
+    q = jnp.asarray(rng.normal(size=(slots, heads, lanes)), jnp.float32)
+    table = jnp.asarray(
+        rng.normal(size=(2, slots, cache_len, lanes)), jnp.float32
+    )
+    row = jnp.asarray(rng.normal(size=(slots, lanes)), jnp.float32)
+    position = jnp.asarray([5, cache_len, cache_len - 1])
+    want = kvcache.latent_attention(q, table[layer], position, row, scale)
+    calls = []
+    kernel = decode_attention.latent_row_attention
+    monkeypatch.setattr(
+        decode_attention, "latent_row_attention",
+        lambda *a, **kw: calls.append(kw) or kernel(*a, **kw),
+    )
+    if case == "one_layer":
+        got = kvcache.latent_attention(q, table[layer], position, row, scale)
+    else:
+        got = kvcache.latent_attention(
+            q, table, position, row, scale, layer=layer
+        )
+    assert got.shape == q.shape and got.dtype == jnp.float32
+    if case == "kernel":
+        assert calls == [{"layer": layer, "scale": scale}]
+        live = np.asarray(position) < cache_len
+        np.testing.assert_allclose(
+            np.asarray(got)[live], np.asarray(want)[live], atol=2e-6
+        )
+        assert not np.asarray(got)[~live].any()  # an idle lane: zeros
+    else:
+        assert not calls
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+def test_served_through_the_latent_kernel(devices8, tiny, monkeypatch):
+    """The toy model's rows are one lane tile, so a cache of whole blocks
+    sends its absorbed reads through the kernel (interpreted): a prompt in
+    chunks, then decode steps whose tokens top the reference's as the mask
+    form's do, and whose dispatch spans count the blocks read — one a live
+    lane a layer at these lengths, against two a slot."""
+    model, params = tiny
+    calls = []
+    kernel = decode_attention.latent_row_attention
+    monkeypatch.setattr(
+        decode_attention, "latent_row_attention",
+        lambda *a, **kw: calls.append(kw["layer"]) or kernel(*a, **kw),
+    )
+    cache_len = 2 * decode_attention.LATENT_BLOCK
+    engine = CausalLMEngine(
+        model, params, **{**_ENGINE, "buckets": (cache_len - _MAX_NEW,)}
+    )
+    assert engine.cache_len == cache_len
+    assert sorted(set(calls)) == [0, 1, 2]  # the decode step's three reads
+    assert engine._prefix_reads == {"latent": (cache_len // 2, 3, 2)}
+    prompt = _prompt(19, seed=19)
+    tracer = Tracer(1 << 12)
+    with ContinuousBatcher(
+        engine, BatcherConfig(max_batch=2), metrics=ServeMetrics(), tracer=tracer,
+    ) as batcher:
+        result = batcher.submit(
+            {"input_ids": prompt, "max_new_tokens": _MAX_NEW}
+        ).result(timeout=300)
+    assert result["n_tokens"] == _MAX_NEW
+    assert _worst_gap(params, prompt, np.asarray(result["tokens"])) <= 2e-5
+    steps = [sp for sp in tracer.drain() if sp.name == "engine.decode_dispatch"]
+    assert steps
+    for sp in steps:
+        assert sp.args["latent_blocks_total"] == 3 * _SLOTS * 2
+        assert sp.args["latent_blocks_read"] == 3 * sp.args["rows"]
+
+
 @pytest.mark.parametrize("length,chunks", [(11, 2), (8, 1), (30, 4)])
 def test_chunks_then_absorbed_decode_match_the_reference(served, length, chunks):
     """A prompt in chunks of 8 (the last one partial, or whole), then
@@ -222,6 +326,9 @@ def test_chunks_then_absorbed_decode_match_the_reference(served, length, chunks)
         assert sp.args["latent_positions_total"] == _SLOTS * engine.cache_len
         assert sp.args["rows"] <= sp.args["latent_positions_live"] \
             <= sp.args["rows"] * engine.cache_len
+        # a table of part blocks takes the mask form: a block is the slot
+        assert sp.args["latent_blocks_total"] == 3 * _SLOTS
+        assert sp.args["latent_blocks_read"] == 3 * sp.args["rows"]
 
 
 def test_streams_through_the_batcher_are_the_solo_streams(served):
